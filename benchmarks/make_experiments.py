@@ -14,8 +14,8 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "src"))
 
-from repro.analysis.roofline import (DCN_BW, HBM_BW, ICI_BW,  # noqa: E402
-                                     KERNEL_SCOPES, PEAK_FLOPS,
+from repro.analysis.roofline import (DCN_BW, ICI_BW,  # noqa: E402
+                                     KERNEL_SCOPES, PEAKS, TARGET_KIND,
                                      analyze_file, model_flops,
                                      roofline_row)
 from repro.configs.base import SHAPES_BY_NAME, shapes_for  # noqa: E402
@@ -174,7 +174,8 @@ HEADER = f"""# EXPERIMENTS
 All numbers derive from the multi-pod dry-run (``launch/dryrun.py``:
 lower + compile per cell on 512 forced host devices) and the HLO-level
 roofline analyzer (``analysis/roofline.py``). Hardware model (TPU v5e):
-{PEAK_FLOPS / 1e12:.0f} TFLOP/s bf16/chip, {HBM_BW / 1e9:.0f} GB/s HBM,
+{PEAKS[TARGET_KIND]["flops"] / 1e12:.0f} TFLOP/s bf16/chip, \
+{PEAKS[TARGET_KIND]["hbm_bw"] / 1e9:.0f} GB/s HBM,
 {ICI_BW / 1e9:.0f} GB/s/link ICI, {DCN_BW / 1e9:.1f} GB/s/chip DCN
 (cross-pod). ``compiled.cost_analysis()`` counts scan bodies once
 (verified) so the analyzer re-derives FLOPs/bytes with while-loop

@@ -343,7 +343,7 @@ def bench_backends():
            "checkpoint_every: 10\nlr: 0.1\noptimizer: sgd\nseed: 0\n"
            "batch_docs: 4\n"
            "data:\n  n_docs: 128\n  seq_len: 16\n"
-           "framework:\n  name: repro-lm\n  arch: stablelm-1.6b\n"
+           "framework:\n  name: repro-lm\n  arch: stablelm-1.6b-smoke\n"
            "  distribution: %s\n")
     out = {}
     for backend in ("software-ps", "pjit"):
@@ -379,7 +379,7 @@ def bench_backends():
                              "the same machine — compare within a file, "
                              "not across commits: container speed varies "
                              "several-fold between runs, and the jax "
-                             "persistent compile cache (DLAAS_JAX_CACHE) "
+                             "persistent compile cache (.jax_cache) "
                              "makes repeat invocations warm-start"),
                     "backends": out}, indent=1) + "\n")
 
@@ -403,7 +403,7 @@ def bench_ps_dataplane():
            "checkpoint_every: 1000000\nlr: 0.1\noptimizer: sgd\nseed: 0\n"
            "batch_docs: 4\n"
            "data:\n  n_docs: 128\n  seq_len: 16\n"
-           "framework:\n  name: repro-lm\n  arch: stablelm-1.6b\n"
+           "framework:\n  name: repro-lm\n  arch: stablelm-1.6b-smoke\n"
            "  distribution: software-ps\n  compression: %s\n")
     out = {}
     for comp in ("none", "int8"):
@@ -477,7 +477,7 @@ def bench_serving():
     rows = {}
     try:
         eid = core.deploy_endpoint(
-            arch="stablelm-1.6b", capacity=capacity,
+            arch="stablelm-1.6b-smoke", capacity=capacity,
             max_queue=max(64, n_req), max_new=max_new)["endpoint_id"]
         t0 = time.time()
         while core.endpoint_status(eid)["state"] != "READY":

@@ -21,8 +21,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from repro.configs.base import reduce_for_smoke  # noqa: E402
-from repro.configs.registry import get_arch  # noqa: E402
+from repro.configs.registry import resolve_arch  # noqa: E402
 from repro.distributed.sharding import Dist  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.optim.optimizers import OptConfig  # noqa: E402
@@ -30,7 +29,7 @@ from repro.runtime.trainer import Trainer, TrainerConfig  # noqa: E402
 
 
 def main():
-    cfg = reduce_for_smoke(get_arch("stablelm-1.6b"))
+    cfg = resolve_arch("stablelm-1.6b-smoke")
     ckpt = tempfile.mkdtemp(prefix="elastic_")
     tc = TrainerConfig(batch=8, seq=32, ckpt_every=10, ckpt_dir=ckpt)
     opt = OptConfig(name="adamw", lr=3e-3)

@@ -7,7 +7,7 @@ streams concurrent predict requests at it — finished sequences retire
 and queued requests join mid-flight into freed KV-cache slots — then
 prints the endpoint stats and drains it.
 
-  PYTHONPATH=src python examples/serve_batch.py --arch stablelm-1.6b \
+  PYTHONPATH=src python examples/serve_batch.py --arch stablelm-1.6b-smoke \
       --requests 8 --capacity 3 --max-new 8
 """
 import argparse
@@ -54,7 +54,7 @@ def wait_state(core, eid, want, timeout=300.0):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="stablelm-1.6b")
+    ap.add_argument("--arch", default="stablelm-1.6b-smoke")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--capacity", type=int, default=3)
     ap.add_argument("--prompt-len", type=int, default=12)

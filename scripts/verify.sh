@@ -110,7 +110,7 @@ from repro.service.core import DLaaSCore
 core = DLaaSCore(tempfile.mkdtemp(prefix="verify_serving_"),
                  tick_interval=0.005)
 try:
-    eid = core.deploy_endpoint(arch="stablelm-1.6b", capacity=2,
+    eid = core.deploy_endpoint(arch="stablelm-1.6b-smoke", capacity=2,
                                max_queue=16, max_new=4)["endpoint_id"]
     t0 = time.time()
     while core.endpoint_status(eid)["state"] != "READY":
@@ -254,8 +254,8 @@ GATE_TOLERANCE="${GATE_TOLERANCE:-0.5}" \
     python benchmarks/run.py gate
 
 echo "== backend-parity + manifest test groups =="
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q \
-    tests/test_backends.py tests/test_manifest.py
+JAX_PLATFORMS=cpu PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
+    python -m pytest -x -q tests/test_backends.py tests/test_manifest.py
 
 echo "== chaos drill: seeded kill/drain replay + 2-node node-kill for" \
      "both backends + serving-node kill (zero lost requests) =="
@@ -304,7 +304,7 @@ PS_MANIFEST = ("name: chaos-ps\nlearners: 2\ngpus: 1\nsteps: 40\n"
 PJIT_MANIFEST = ("name: chaos-pjit\nlearners: 1\ngpus: 2\nsteps: 40\n"
                  "batch_docs: 2\ncheckpoint_every: 10\n"
                  "data:\n  n_docs: 32\n  seq_len: 16\n"
-                 "framework:\n  name: repro-lm\n  arch: stablelm-1.6b\n"
+                 "framework:\n  name: repro-lm\n  arch: stablelm-1.6b-smoke\n"
                  "  distribution: pjit\n")
 
 
@@ -361,7 +361,7 @@ def serving_drill():
     core = DLaaSCore(tempfile.mkdtemp(prefix="verify_chaos_srv_"),
                      tick_interval=0.005, cluster=c)
     try:
-        eid = core.deploy_endpoint(arch="stablelm-1.6b", capacity=2,
+        eid = core.deploy_endpoint(arch="stablelm-1.6b-smoke", capacity=2,
                                    max_new=2)["endpoint_id"]
         wait_until(lambda: core.endpoint_status(eid)["state"] == "READY",
                    desc="endpoint READY")
@@ -556,7 +556,7 @@ MANIFEST = ("name: crash-drill\nlearners: 1\ngpus: 1\nsteps: 2000\n"
             "checkpoint_every: 100\nframework:\n  name: repro-mlp\n"
             "  d_in: 16\n  n_classes: 4\n")
 core = DLaaSCore(workdir, tick_interval=0.005)
-eid = core.deploy_endpoint(arch="stablelm-1.6b", max_new=2,
+eid = core.deploy_endpoint(arch="stablelm-1.6b-smoke", max_new=2,
                            idempotency_key="drill-ep")["endpoint_id"]
 t0 = time.time()
 while core.endpoint_status(eid)["state"] != "READY":
@@ -639,4 +639,5 @@ finally:
 EOF
 
 echo "== tier-1 tests (-rs: every skip must name its reason) =="
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q -rs "$@"
+JAX_PLATFORMS=cpu PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
+    python -m pytest -x -q -rs "$@"

@@ -15,11 +15,11 @@ rate into the ``status.perf`` payload::
      "pct_of_attainable": 12.3,
      "summary": "12.3% of attainable FLOPs, memory-bound"}
 
-The machine model is the TPU v5e roofline (PEAK_FLOPS/HBM_BW in
-analysis/roofline.py): the estimate describes the program the job would
-run on the accelerator, so the attainable rate is the accelerator
-ceiling — a CPU smoke job honestly reports a tiny ``pct_of_attainable``.
-Disable with ``DLAAS_PERF=0`` (the payload then reports
+The machine model is the peak table in analysis/roofline.py, looked up
+by the ``device_kind`` of the device the job runs on; the payload names
+that kind. A kind with no published peaks (the CPU, for one) gets the
+FLOP and byte counts and ``"peaks": "no peaks"``, never another chip's
+ceiling. Disable with ``DLAAS_PERF=0`` (the payload then reports
 ``{"state": "disabled"}``).
 """
 from __future__ import annotations
@@ -31,8 +31,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-from repro.analysis.roofline import (HBM_BW, KERNEL_SCOPES, PEAK_FLOPS,
-                                     analyze_hlo_text)
+from repro.analysis.roofline import KERNEL_SCOPES, analyze_hlo_text
 
 log = logging.getLogger("repro.perf")
 
@@ -110,7 +109,9 @@ class JobPerf:
                     return
                 with _lower_gate:
                     txt = lower_fn()
-                analysis = analyze_hlo_text(txt, self.kernel_scopes)
+                import jax
+                analysis = analyze_hlo_text(
+                    txt, self.kernel_scopes, jax.devices()[0].device_kind)
                 with self._lock:
                     self.analysis = analysis
                     self.state = "ready"
@@ -118,9 +119,10 @@ class JobPerf:
                     self.metrics.incr(self.job_id,
                                       "perf_estimates_total")
                     snap = self.snapshot()
-                    self.metrics.record(
-                        self.job_id, "perf_attainable_per_s", 0,
-                        snap.get("attainable_%ss_per_s" % self.unit, 0.0))
+                    att = snap.get("attainable_%ss_per_s" % self.unit)
+                    if att is not None:         # None: no peaks
+                        self.metrics.record(
+                            self.job_id, "perf_attainable_per_s", 0, att)
                     self.metrics.event(self.job_id, "perf_estimate", 0,
                                        bound=snap.get("bound"))
             except Exception as e:       # advisory: log, never crash a job
@@ -151,6 +153,18 @@ class JobPerf:
             out["error"] = error
         if analysis is None:
             return out
+        kind = analysis["device_kind"]
+        out.update({
+            "device_kind": kind,
+            "flops_per_step_per_device": analysis["flops_per_device"],
+            "hbm_gb_per_step": round(
+                analysis["hbm_bytes_per_device"] / 1e9, 6),
+        })
+        if analysis["compute_s"] is None:
+            out["peaks"] = "no peaks"
+            out["summary"] = f"no peaks for {kind}: counts only"
+            return out
+        out["peaks"] = kind
         terms = {"compute": analysis["compute_s"],
                  "memory": analysis["memory_s"],
                  "collective": analysis["collective_s"]}
@@ -159,9 +173,6 @@ class JobPerf:
         attainable = 1.0 / bound_s if bound_s > 0 else float("inf")
         out.update({
             "bound": f"{dominant}-bound",
-            "flops_per_step_per_device": analysis["flops_per_device"],
-            "hbm_gb_per_step": round(
-                analysis["hbm_bytes_per_device"] / 1e9, 6),
             "compute_s": analysis["compute_s"],
             "memory_s": analysis["memory_s"],
             "collective_s": analysis["collective_s"],
@@ -177,7 +188,7 @@ class JobPerf:
         else:
             out["summary"] = (f"{dominant}-bound, attainable "
                               f"{attainable:.1f} {self.unit}s/s "
-                              f"on the accelerator roofline")
+                              f"on the {kind} roofline")
         return out
 
 

@@ -13,9 +13,10 @@ module re-derives the three roofline terms by walking the HLO call graph:
   * Collective bytes: per-device ring-algorithm wire bytes per op kind,
     split ICI vs DCN by whether the replica group crosses a pod boundary.
 
-Hardware constants (TPU v5e, per assignment): 197 TFLOP/s bf16, 819 GB/s
-HBM, ~50 GB/s/link ICI; DCN is modelled at 2.5 GB/s per chip for
-pod-crossing collectives (documented assumption).
+Hardware constants: per-chip compute and HBM peaks live in ``PEAKS``,
+keyed by the ``device_kind`` JAX reports; ICI is modelled at ~50 GB/s per
+link and DCN at 2.5 GB/s per chip for pod-crossing collectives
+(documented assumptions).
 
 Kernel-scope accounting: regions tagged with ``jax.named_scope`` that lower
 to single Pallas kernels on the TPU target (flash attention, SSD scan, PS
@@ -38,8 +39,13 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
+# Published per-chip peaks, keyed by jax's ``device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9},
+}
+# the chip the dry-run and the kernel autotuner model
+TARGET_KIND = "TPU v5 lite"
 ICI_BW = 50e9                # bytes/s / link (we model 1 effective link)
 DCN_BW = 2.5e9               # bytes/s / chip for cross-pod traffic
 
@@ -491,18 +497,25 @@ def comp_cost(comps: Dict[str, Computation], name: str,
 # ---------------------------------------------------------------------------
 
 
-def analyze_hlo_text(txt: str, kernel_scopes: Tuple[str, ...] = ()) -> Dict:
+def analyze_hlo_text(txt: str, kernel_scopes: Tuple[str, ...] = (),
+                     device_kind: str = TARGET_KIND) -> Dict:
+    """Per-device FLOPs, HBM and collective bytes of a compiled program,
+    and the roofline times against ``device_kind``'s peaks. A kind with
+    no entry in ``PEAKS`` gets the counts only (times are None)."""
     comps = parse_module(txt)
     cost = comp_cost(comps, "__entry__", False, {}, kernel_scopes)
+    peaks = PEAKS.get(device_kind)
     return {
+        "device_kind": device_kind,
         "flops_per_device": cost.flops,
         "hbm_bytes_per_device": cost.bytes,
         "ici_bytes_per_device": cost.ici_bytes,
         "dcn_bytes_per_device": cost.dcn_bytes,
         "collective_bytes_by_kind": dict(cost.coll),
-        "compute_s": cost.flops / PEAK_FLOPS,
-        "memory_s": cost.bytes / HBM_BW,
-        "collective_s": cost.ici_bytes / ICI_BW + cost.dcn_bytes / DCN_BW,
+        "compute_s": cost.flops / peaks["flops"] if peaks else None,
+        "memory_s": cost.bytes / peaks["hbm_bw"] if peaks else None,
+        "collective_s": (cost.ici_bytes / ICI_BW + cost.dcn_bytes / DCN_BW
+                         if peaks else None),
     }
 
 
@@ -577,7 +590,7 @@ def roofline_row(rec: Dict, hlo_analysis: Dict, cfg, shape,
     }
     dom = max(terms, key=terms.get)
     bound_s = max(terms.values())
-    ideal_s = mf / n_chips / PEAK_FLOPS
+    ideal_s = mf / n_chips / PEAKS[TARGET_KIND]["flops"]
     return {
         **{k: round(v, 6) for k, v in terms.items()},
         "dominant": dom.replace("_s", ""),

@@ -73,11 +73,12 @@ def wire_bytes(n: int, block: int = BLOCK) -> int:
 
 
 def make_compressor(block: int = BLOCK, use_tpu: bool = None):
-    """Build the push-path compressor ``fn(x, err) -> (q, scales,
-    new_err)``: the fused Pallas kernel when running on a TPU backend,
-    the jit'd jnp reference otherwise (the two are validated against
-    each other in tests/test_compression.py). ``x`` must be a multiple
-    of ``block`` long — the PS shard layout guarantees this."""
+    """Build the push-path compressor ``(fn, path)`` with ``fn(x, err)
+    -> (q, scales, new_err)``: the fused Pallas kernel when running on a
+    TPU backend (``path`` "pallas"), the jit'd jnp reference otherwise
+    ("jnp"; the two are validated against each other in
+    tests/test_compression.py). ``x`` must be a multiple of ``block``
+    long — the PS shard layout guarantees this."""
     if use_tpu is None:
         use_tpu = jax.default_backend() == "tpu"
     if use_tpu:
@@ -91,8 +92,8 @@ def make_compressor(block: int = BLOCK, use_tpu: bool = None):
             # tuned grid block resolved outside the jit (cached per shape)
             blk = tuned_quantize_block(int(x.shape[0]), block, x.dtype)
             return jfn(x, e, blk)
-        return compress
+        return compress, "pallas"
     # one definition of the scheme: drop the wire view (its math is part
     # of the residual anyway, so nothing extra is computed under jit)
     return jax.jit(
-        lambda x, e: compress_with_feedback(x, e, block)[:3])
+        lambda x, e: compress_with_feedback(x, e, block)[:3]), "jnp"

@@ -26,7 +26,6 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -59,8 +58,8 @@ def pull(center, ctx: PSContext):
     def body(c):
         return jax.lax.all_gather(c, ctx.axis, axis=0, tiled=True)
 
-    return shard_map(body, mesh=ctx.mesh, in_specs=P(ctx.axis),
-                     out_specs=P(None), check_rep=False)(center)
+    return jax.shard_map(body, mesh=ctx.mesh, in_specs=P(ctx.axis),
+                         out_specs=P(None), check_vma=False)(center)
 
 
 # ---------------------------------------------------------------------------
@@ -80,15 +79,16 @@ def push_mean(vstack, mode: str, ctx: PSContext):
             chunk = jax.lax.psum_scatter(v[0], ctx.axis, scatter_dimension=0,
                                          tiled=True) / nl
             return jax.lax.all_gather(chunk, ctx.axis, axis=0, tiled=True)
-        return shard_map(body, mesh=ctx.mesh, in_specs=P(ctx.axis, None),
-                         out_specs=P(None), check_rep=False)(vstack)
+        return jax.shard_map(body, mesh=ctx.mesh,
+                             in_specs=P(ctx.axis, None),
+                             out_specs=P(None), check_vma=False)(vstack)
 
     # broadcast: every learner receives every other learner's FULL vector
     def body(v):
         allv = jax.lax.all_gather(v[0], ctx.axis, axis=0)   # (NL, F) each!
         return jnp.mean(allv, axis=0)
-    return shard_map(body, mesh=ctx.mesh, in_specs=P(ctx.axis, None),
-                     out_specs=P(None), check_rep=False)(vstack)
+    return jax.shard_map(body, mesh=ctx.mesh, in_specs=P(ctx.axis, None),
+                         out_specs=P(None), check_vma=False)(vstack)
 
 
 # ---------------------------------------------------------------------------
@@ -121,11 +121,11 @@ def push_update_pull(gstack, center, opt_state, update_fn, mode: str,
         opt_leaves, opt_def = jax.tree.flatten(opt_state)
         opt_specs = tuple(P(ctx.axis) if getattr(l, "ndim", 0) > 0 else P()
                           for l in opt_leaves)
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(P(ctx.axis, None), P(ctx.axis)) + opt_specs,
             out_specs=(P(ctx.axis), P(None)) + opt_specs,
-            check_rep=False)(gstack, center, *opt_leaves)
+            check_vma=False)(gstack, center, *opt_leaves)
         new_center, full = out[0], out[1]
         new_opt = jax.tree.unflatten(opt_def, out[2:])
         return new_center, new_opt, full
@@ -185,11 +185,11 @@ def downpour_round(gstack, center, opt_state, update_fn, ctx: PSContext):
     opt_leaves, opt_def = jax.tree.flatten(opt_state)
     opt_specs = tuple(P(ctx.axis) if getattr(l, "ndim", 0) > 0 else P()
                       for l in opt_leaves)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(ctx.axis, None), P(ctx.axis)) + opt_specs,
         out_specs=(P(ctx.axis), P(ctx.axis, None)) + opt_specs,
-        check_rep=False)(gstack, center, *opt_leaves)
+        check_vma=False)(gstack, center, *opt_leaves)
     new_center, prefixes = out[0], out[1]
     new_opt = jax.tree.unflatten(opt_def, out[2:])
     return new_center, new_opt, prefixes

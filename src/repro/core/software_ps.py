@@ -51,8 +51,8 @@ from typing import Dict, List, Optional, Union
 
 import numpy as np
 
-from repro.core.compression import (BLOCK, CompressedPush,
-                                    make_compressor, pad_to_block)
+from repro.core.compression import BLOCK, CompressedPush, make_compressor
+from repro.kernels.grid import TILE, pad_to
 
 log = logging.getLogger("repro.ps")
 
@@ -88,17 +88,19 @@ _FUSED_SOLVER = {"sgd": "sgd", "momentum": "momentum", "adam": "adam",
 class ShardLayout:
     """Even partition of the flat model by shard ID, fixed at server
     construction (every learner follows the same scheme). ``shard_len``
-    is rounded up to the compression block so a compressed push splits
-    into per-shard views without re-blocking."""
+    is rounded up to whole (8, 128) f32 tiles (``TILE``, a multiple of
+    the compression block), so every shard is a legal operand of the
+    aggregation kernel and a compressed push splits into per-shard views
+    without re-blocking."""
     size: int               # true (unpadded) model size
     n_shards: int
-    shard_len: int          # multiple of compression BLOCK
+    shard_len: int          # multiple of TILE
     padded: int             # n_shards * shard_len
 
     @classmethod
     def build(cls, size: int, n_shards: int) -> "ShardLayout":
         per = max(1, -(-size // n_shards))
-        shard_len = pad_to_block(per)
+        shard_len = pad_to(per, TILE)
         return cls(size=size, n_shards=n_shards, shard_len=shard_len,
                    padded=shard_len * n_shards)
 
@@ -145,7 +147,8 @@ class SoftwareParameterServer:
         # zero-copy receive: learner i owns row [i]; rows are written
         # outside the round lock so receives overlap across learners
         self._recv = np.zeros((n_learners, lay.padded), np.float32)
-        self._agg = self._make_agg_fn()
+        self._agg, self.agg_path = self._make_agg_fn()
+        self.quantize_path: Optional[str] = None  # set by int8 clients
         self._pool: Optional[ThreadPoolExecutor] = None
         if n_shards > 1 and lay.padded >= PARALLEL_AGG_MIN_ELEMS:
             self._pool = _agg_pool()
@@ -174,10 +177,11 @@ class SoftwareParameterServer:
 
     # ---- fused aggregation ------------------------------------------------
     def _make_agg_fn(self):
-        """``agg(grads (NL, L), params/m/v (L,) views, step)``: one fused
-        mean+solver pass, updating the state views in place. Pallas
-        kernel on TPU, the in-place numpy twin elsewhere (both validated
-        against kernels/ref.py:ps_aggregate_ref)."""
+        """``(agg, path)``: ``agg(grads (NL, L), params/m/v (L,) views,
+        step)`` is one fused mean+solver pass, updating the state views
+        in place — the Pallas kernel on TPU, the in-place numpy twin
+        elsewhere (both validated against kernels/ref.py:ps_aggregate_ref).
+        ``path`` names which one (``pallas`` | ``numpy``)."""
         import jax
         kw = dict(solver=_FUSED_SOLVER[self.optimizer], lr=self.lr,
                   b1=self.b1, b2=self.b2, eps=self.eps,
@@ -197,9 +201,9 @@ class SoftwareParameterServer:
                 np.copyto(p, np.asarray(pn))
                 np.copyto(m, np.asarray(mn))
                 np.copyto(v, np.asarray(vn))
-            return agg
+            return agg, "pallas"
         from repro.kernels.ref import ps_aggregate_np
-        return functools.partial(ps_aggregate_np, **kw)
+        return functools.partial(ps_aggregate_np, **kw), "numpy"
 
     def _apply_shard(self, s: int, rows: np.ndarray, step: int):
         lay = self.layout
@@ -282,9 +286,12 @@ class SoftwareParameterServer:
         lay = self.layout
         row = self._recv[slot]
         if isinstance(payload, CompressedPush):
+            # the payload covers the model rounded up to whole blocks;
+            # the row's tile padding beyond it stays zero
+            n = payload.q.size
             np.multiply(payload.q.reshape(-1, BLOCK),
                         payload.scales[:, None],
-                        out=row.reshape(-1, BLOCK))
+                        out=row[:n].reshape(-1, BLOCK))
             return payload.wire_nbytes, payload.dense_nbytes
         flat = np.asarray(payload, np.float32).ravel()
         assert flat.size in (self.size, lay.padded), flat.size
@@ -453,6 +460,10 @@ class SoftwareParameterServer:
                 "bytes_pushed_dense": dense,
                 "bytes_pulled": self.bytes_pulled,
                 "agg_rounds": rounds,
+                # which implementation ran: aggregation pallas|numpy,
+                # int8 quantization pallas|jnp (None: no compression)
+                "agg_path": self.agg_path,
+                "quantize_path": self.quantize_path,
                 "slow_slots": sorted(self._slow),
             }
         out["compression_ratio"] = round(dense / wire, 3) if wire else None
@@ -465,7 +476,8 @@ class PSClient:
     """Per-learner push/pull endpoint: owns the padded staging buffer and
     (under int8 compression) the error-feedback buffer, so quantization
     is unbiased over time (Seide et al. style). Compression runs through
-    the Pallas kernel on TPU and the jit'd jnp reference elsewhere."""
+    the Pallas kernel on TPU and the jit'd jnp reference elsewhere; the
+    server's ``quantize_path`` records which."""
 
     def __init__(self, ps: SoftwareParameterServer, learner_id: int,
                  compression: str = "none"):
@@ -477,7 +489,7 @@ class PSClient:
             import jax.numpy as jnp
             self._stage = np.zeros(ps.layout.padded, np.float32)
             self._err = jnp.zeros(ps.layout.padded, jnp.float32)
-            self._compress = make_compressor()
+            self._compress, ps.quantize_path = make_compressor()
 
     def push(self, flat: np.ndarray, timeout: float = 30.0) -> bool:
         if self.compression == "none":
@@ -485,9 +497,13 @@ class PSClient:
         flat = np.asarray(flat, np.float32).ravel()
         self._stage[: flat.size] = flat
         q, scales, self._err = self._compress(self._stage, self._err)
+        # only whole blocks that hold model values go on the wire; the
+        # rest of the staging buffer is tile padding and quantizes to 0
+        n = -(-flat.size // BLOCK) * BLOCK
         ok = self.ps.push(
             self.learner_id,
-            CompressedPush(q=np.asarray(q), scales=np.asarray(scales),
+            CompressedPush(q=np.asarray(q)[:n],
+                           scales=np.asarray(scales)[: n // BLOCK],
                            dense_nbytes=flat.nbytes),
             timeout=timeout)
         if not ok:

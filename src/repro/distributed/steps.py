@@ -142,7 +142,9 @@ def jit_train_step(model: Model, opt_cfg: OptConfig, shape: ShapeSpec,
     bspecs = model.input_sharding_specs(shape)
     fn = build_train_step(model, opt_cfg, grad_accum)
     if not dist.has_mesh:
-        return jax.jit(fn)
+        # the step replaces params and optimizer state: donating them
+        # keeps one copy of each on the device, not two
+        return jax.jit(fn, donate_argnums=(0, 1))
     return jax.jit(
         fn,
         in_shardings=(_ns(dist, pspecs), _ns(dist, ospecs),

@@ -21,9 +21,10 @@ process.
 Measurement only runs on a real accelerator backend (or when forced via
 ``DLAAS_AUTOTUNE_MEASURE=1``): interpret-mode timings on CPU are
 Python-loop artifacts that would mislead the choice, so CPU keeps the
-best *predicted* candidate. ``DLAAS_AUTOTUNE=0`` disables the tuner
-entirely (callers fall back to ``fit_block``); ``DLAAS_AUTOTUNE_CACHE``
-overrides the cache path.
+best *predicted* candidate. A candidate that fails while it is measured
+is an error, never a silent fallback. ``DLAAS_AUTOTUNE=0`` disables the
+tuner entirely (callers fall back to ``fit_block``);
+``DLAAS_AUTOTUNE_CACHE`` overrides the cache path.
 """
 from __future__ import annotations
 
@@ -35,16 +36,17 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-from repro.kernels.grid import fit_block
+from repro.analysis.roofline import PEAKS, TARGET_KIND
+from repro.kernels.grid import TILE, fit_block, pad_to
 
 log = logging.getLogger("repro.autotune")
 
-# Machine-model terms (TPU v5e class, matching analysis/roofline.py).
+# Machine-model terms of the target chip (analysis/roofline.py PEAKS).
 # Absolute values only set the overhead/bandwidth balance; the ranking is
 # what matters and it is stable across a wide range of either constant.
-HBM_BW = 819e9             # bytes/s
+HBM_BW = PEAKS[TARGET_KIND]["hbm_bw"]
 GRID_STEP_US = 1.0         # per-grid-step dispatch overhead
-VMEM_BUDGET = 12 << 20     # usable VMEM per core (16 MB minus headroom)
+VMEM_BUDGET = 12 << 20     # of the 16 MiB scoped VMEM, minus headroom
 
 MEASURE_REPS = 3           # timed repetitions per measured candidate
 TOP_K = 3                  # measured survivors of the predicted ranking
@@ -200,12 +202,13 @@ def tune(kernel: str, shape: Sequence[int], dtype, *,
          default, top_k: int = TOP_K, extra_key: str = ""):
     """Generic predict -> rank -> measure-top-K flow.
 
-    ``candidates`` are opaque configs (ints or tuples). ``predict_us``
-    maps a candidate to a modelled time (``inf`` = infeasible).
-    ``measure_s``, when given, maps a candidate to measured seconds; when
-    None the best *predicted* candidate wins. Returns the chosen config;
-    ``default`` is returned on empty/failed sweeps and when tuning is
-    disabled."""
+    ``candidates`` are opaque configs (ints or tuples), all legal for
+    the kernel. ``predict_us`` maps a candidate to a modelled time
+    (``inf`` = infeasible). ``measure_s``, when given, maps a candidate
+    to measured seconds; when None the best *predicted* candidate wins.
+    Returns the chosen config; ``default`` is returned when no candidate
+    is feasible and when tuning is disabled. A cached choice that is no
+    longer a candidate is tuned again."""
     if not enabled() or not candidates:
         return default
     cache = get_cache()
@@ -213,7 +216,9 @@ def tune(kernel: str, shape: Sequence[int], dtype, *,
     rec = cache.get(key)
     if rec is not None:
         choice = rec.get("choice", default)
-        return tuple(choice) if isinstance(choice, list) else choice
+        choice = tuple(choice) if isinstance(choice, list) else choice
+        if choice in candidates:
+            return choice
 
     ranked = sorted(candidates, key=predict_us)
     predicted = {str(c): round(predict_us(c), 3) for c in ranked}
@@ -225,15 +230,10 @@ def tune(kernel: str, shape: Sequence[int], dtype, *,
     measured: Dict[str, float] = {}
     source = "predicted"
     if measure_s is not None and len(feasible) > 1:
-        try:
-            for c in feasible[:top_k]:
-                measured[str(c)] = round(measure_s(c) * 1e6, 3)
-            choice = min(feasible[:top_k],
-                         key=lambda c: measured[str(c)])
-            source = "measured"
-        except Exception as e:   # never fail the job over a tuning probe
-            log.warning("autotune measurement failed for %s: %s", key, e)
-            choice, source = default, "default"
+        for c in feasible[:top_k]:
+            measured[str(c)] = round(measure_s(c) * 1e6, 3)
+        choice = min(feasible[:top_k], key=lambda c: measured[str(c)])
+        source = "measured"
     cache.put(key, {"choice": choice, "source": source,
                     "predicted_us": predicted, "measured_us": measured})
     log.info("autotune %s -> %s (%s)", key, choice, source)
@@ -254,28 +254,28 @@ def _dtype_bytes(dtype) -> int:
 
 
 def _under_trace() -> bool:
-    """True while tracing a jit — measurement there would run eager
-    probes mid-trace; prediction stays safe either way."""
-    try:
-        import jax
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return False
+    """True while tracing (jit, vmap, eval_shape) — measurement there
+    would run eager probes mid-trace; prediction stays safe either way."""
+    import jax
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def tuned_ps_block(nl: int, f: int, dtype="float32", *,
-                   default_block: int = 1024) -> int:
-    """Block size for the fused PS aggregation over (nl, f) grads."""
-    default = fit_block(f, default_block)
+                   default_block: int = TILE) -> int:
+    """Block size (elements, whole (8, 128) tiles) for the fused PS
+    aggregation over (nl, f) grads; f is padded to a whole tile."""
+    fp = pad_to(f, TILE)
+    default = fit_block(fp, default_block, multiple=TILE)
     ib = _dtype_bytes(dtype)
 
     def predict_us(block: int) -> float:
-        # per grid step: (nl+3) block reads + 3 block writes in VMEM
-        vmem = (nl + 6) * block * 4
+        # per grid step: (nl+3) block reads + 3 block writes, each
+        # double-buffered in VMEM, plus the f32 mean and new m/v values
+        vmem = (2 * (nl + 6) + 3) * block * 4
         if vmem > VMEM_BUDGET:
             return float("inf")
-        steps = f // block
-        bytes_moved = f * (nl + 6) * ib
+        steps = fp // block
+        bytes_moved = fp * (nl + 6) * ib
         return bytes_moved / HBM_BW * 1e6 + steps * GRID_STEP_US
 
     measure_s = None
@@ -294,24 +294,27 @@ def tuned_ps_block(nl: int, f: int, dtype="float32", *,
                 lambda: jax.block_until_ready(fn(g, p)))
 
     return tune("ps_aggregate", (nl, f), dtype,
-                candidates=divisor_blocks(f, multiple=256, cap=1 << 15)
-                or [default],
+                candidates=divisor_blocks(fp, multiple=TILE, cap=1 << 17),
                 predict_us=predict_us, measure_s=measure_s,
                 default=default)
 
 
-def tuned_quantize_block(f: int, qblock: int = 256, dtype="float32", *,
-                         default_block: int = 4096) -> int:
-    """Block size for the int8 quantize/dequantize pass over (f,)."""
-    default = fit_block(f, default_block, multiple=qblock)
+def tuned_quantize_block(f: int, qblock: int = 256, dtype="float32") \
+        -> int:
+    """Block size (elements, 128 quantization blocks per unit) for the
+    int8 quantize/dequantize pass over (f,); f is padded to a unit."""
+    unit = 128 * qblock
+    fp = pad_to(f, unit)
+    default = unit
     ib = _dtype_bytes(dtype)
 
     def predict_us(block: int) -> float:
-        vmem = 4 * block * 4            # x, err, q, new_err working set
+        # x, err, q, new_err double-buffered, plus the f32 temporaries
+        vmem = 2 * (3 * ib + 1) * block + 3 * 4 * block
         if vmem > VMEM_BUDGET:
             return float("inf")
-        steps = f // block
-        bytes_moved = f * (3 * ib + 1) + 4 * (f // qblock)
+        steps = fp // block
+        bytes_moved = fp * (3 * ib + 1) + 4 * (fp // qblock)
         return bytes_moved / HBM_BW * 1e6 + steps * GRID_STEP_US
 
     measure_s = None
@@ -328,8 +331,7 @@ def tuned_quantize_block(f: int, qblock: int = 256, dtype="float32", *,
                 lambda: jax.block_until_ready(fn(x, x)))
 
     return tune("quantize_ef", (f,), dtype,
-                candidates=divisor_blocks(f, multiple=qblock, cap=1 << 16)
-                or [default],
+                candidates=divisor_blocks(fp, multiple=unit, cap=1 << 20),
                 predict_us=predict_us, measure_s=measure_s,
                 default=default, extra_key=f"q{qblock}")
 
@@ -338,14 +340,16 @@ def tuned_flash_blocks(bh: int, sq: int, sk: int, hd: int,
                        dtype="float32", *,
                        default: Tuple[int, int] = (128, 128)) \
         -> Tuple[int, int]:
-    """(block_q, block_k) for flash attention over (bh, sq|sk, hd)."""
-    dflt = (fit_block(sq, min(default[0], sq)),
-            fit_block(sk, min(default[1], sk)))
+    """(block_q, block_k) for flash attention over (bh, sq|sk, hd).
+    Blocks are whole lane tiles (128, 256 or 512) dividing the sequence,
+    or the whole sequence when it is shorter than a tile."""
+    def legal(s: int) -> List[int]:
+        return [b for b in (128, 256, 512) if s % b == 0] or [s]
+
+    dflt = (fit_block(sq, default[0], 128) if sq % 128 == 0 else sq,
+            fit_block(sk, default[1], 128) if sk % 128 == 0 else sk)
     ib = _dtype_bytes(dtype)
-    cand_q = [b for b in (32, 64, 128, 256, 512) if b <= sq and sq % b == 0]
-    cand_k = [b for b in (32, 64, 128, 256, 512) if b <= sk and sk % b == 0]
-    cands = [(bq, bk) for bq in (cand_q or [dflt[0]])
-             for bk in (cand_k or [dflt[1]])]
+    cands = [(bq, bk) for bq in legal(sq) for bk in legal(sk)]
 
     def predict_us(c: Tuple[int, int]) -> float:
         bq, bk = c

@@ -1,72 +1,29 @@
-"""Shared grid-sizing helper for the 1-D Pallas kernels.
+"""Shared tiling rules for the Pallas kernels over flat vectors.
 
-The PS shard layout guarantees lengths that are multiples of the
-quantization block (256); kernels want the largest block <= the
-requested one that divides the full length (and, where scales are
-per-block, is itself a multiple of that quantization block).
-
-For awkward sizes (F divisible only by small powers of two) the halving
-search can land on a tiny block, which wrecks grid efficiency: the
-kernel spends its time on dispatch, not math. That degradation used to
-be silent — now each distinct (f, requested, chosen) signature below
-SMALL_BLOCK_FLOOR logs one warning and notifies registered observers
-(DLaaSCore wires these into MetricsService as the
-``kernels_small_block_total`` counter).
+On TPU a flat f32 vector is handed to the kernels as a lane-dense
+(rows, 128) array, and a block must cover whole (8, 128) tiles: a
+smaller or unaligned block does not lower. ``fit_block`` therefore only
+returns blocks that are multiples of the caller's tile and divide the
+(padded) length, and ``pad_to`` gives the padded length.
 """
 from __future__ import annotations
 
-import logging
-import threading
-from typing import Callable, List, Tuple
-
-log = logging.getLogger("repro.kernels.grid")
-
-SMALL_BLOCK_FLOOR = 256
-
-_lock = threading.Lock()
-_warned: set = set()
-_events: List[Tuple[int, int, int]] = []     # (f, requested, chosen)
-_observers: List[Callable[[int, int, int], None]] = []
+LANES = 128                 # lane width of a vector register
+TILE = 8 * LANES            # elements in one (8, 128) f32 tile
 
 
-def on_small_block(cb: Callable[[int, int, int], None]) -> None:
-    """Register ``cb(f, requested, chosen)`` to fire on every small-block
-    degradation (used to surface a metric without a module-level
-    MetricsService dependency)."""
-    with _lock:
-        _observers.append(cb)
-
-
-def small_block_events() -> List[Tuple[int, int, int]]:
-    with _lock:
-        return list(_events)
-
-
-def _note_small_block(f: int, requested: int, chosen: int) -> None:
-    with _lock:
-        _events.append((f, requested, chosen))
-        observers = list(_observers)
-        first = (f, requested, chosen) not in _warned
-        _warned.add((f, requested, chosen))
-    if first:
-        log.warning(
-            "fit_block degraded to block=%d (< %d) for f=%d "
-            "(requested %d): grid is dispatch-bound; consider padding "
-            "the buffer to a friendlier multiple", chosen,
-            SMALL_BLOCK_FLOOR, f, requested)
-    for cb in observers:
-        cb(f, requested, chosen)
+def pad_to(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
 
 
 def fit_block(f: int, block: int, multiple: int = 1) -> int:
-    """Largest usable grid block: <= ``block``, divides ``f``, and is a
-    multiple of ``multiple``. ``f`` must itself be a multiple of
-    ``multiple`` (asserted) so halving toward it always terminates."""
-    assert multiple >= 1 and f % multiple == 0, (f, multiple)
-    requested = block
-    block = max(multiple, min(block, f))
-    while f % block or block % multiple:
-        block = max(multiple, block // 2)
-    if block < SMALL_BLOCK_FLOOR <= f and block < requested:
-        _note_small_block(f, requested, block)
-    return block
+    """Largest block that is a multiple of ``multiple``, divides ``f``
+    and is at most ``block`` (never less than ``multiple``). ``f`` must
+    itself be a multiple of ``multiple``."""
+    if multiple < 1 or f % multiple:
+        raise ValueError(f"length {f} is not a multiple of {multiple}")
+    units = f // multiple
+    want = max(1, min(block // multiple, units))
+    while units % want:
+        want -= 1
+    return want * multiple

@@ -7,8 +7,11 @@ fusing mean-aggregation with the (elementwise) solver update makes it a
 single read-modify-write pass over the shard instead of several.
 
 Supports the DLaaS solver updates: sgd, momentum, adam (bias-corrected),
-and the EASGD center rule. Blocks of (n_learners, block) are reduced over
-learners in VMEM.
+and the EASGD center rule. The flat vectors are viewed as (rows, 128)
+lane-dense tiles; blocks of (n_learners, rows, 128) are reduced over
+learners in VMEM. Adam's bias corrections are step-dependent scalars: they
+are computed outside the kernel and read from SMEM (the TPU lowering has
+no ``powf``).
 
 Oracle: kernels/ref.py:ps_aggregate_ref.
 """
@@ -21,14 +24,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.grid import fit_block
+from repro.kernels.grid import LANES, TILE, fit_block, pad_to
 
 
-def _agg_kernel(g_ref, p_ref, m_ref, v_ref, step_ref,
+def _agg_kernel(bc_ref, g_ref, p_ref, m_ref, v_ref,
                 po_ref, mo_ref, vo_ref, *,
                 solver: str, lr: float, b1: float, b2: float, eps: float,
                 momentum: float, beta: float):
-    g = jnp.mean(g_ref[...].astype(jnp.float32), axis=0)     # (blk,)
+    g = jnp.mean(g_ref[...].astype(jnp.float32), axis=0)     # (rows, 128)
     p = p_ref[...].astype(jnp.float32)
     if solver == "sgd":
         po_ref[...] = (p - lr * g).astype(po_ref.dtype)
@@ -40,11 +43,10 @@ def _agg_kernel(g_ref, p_ref, m_ref, v_ref, step_ref,
         mo_ref[...] = m.astype(mo_ref.dtype)
         vo_ref[...] = v_ref[...]
     elif solver == "adam":
-        step = step_ref[0].astype(jnp.float32)
         m = b1 * m_ref[...].astype(jnp.float32) + (1 - b1) * g
         v = b2 * v_ref[...].astype(jnp.float32) + (1 - b2) * g * g
-        mh = m / (1 - b1 ** step)
-        vh = v / (1 - b2 ** step)
+        mh = m / bc_ref[0]
+        vh = v / bc_ref[1]
         po_ref[...] = (p - lr * mh / (jnp.sqrt(vh) + eps)).astype(
             po_ref.dtype)
         mo_ref[...] = m.astype(mo_ref.dtype)
@@ -66,39 +68,41 @@ def _agg_kernel(g_ref, p_ref, m_ref, v_ref, step_ref,
 def ps_aggregate(grads, params, m, v, step, *, solver: str = "adam",
                  lr: float = 1e-3, b1: float = 0.9, b2: float = 0.999,
                  eps: float = 1e-8, momentum: float = 0.9,
-                 beta: float = 0.9, block: int = 1024,
+                 beta: float = 0.9, block: int = 8 * TILE,
                  interpret: bool = False):
-    """grads (NL, F); params/m/v (F,); step scalar int32 (1-based).
+    """grads (NL, F); params/m/v (F,); step scalar (1-based).
 
     Returns (new_params, new_m, new_v): one fused aggregation+update pass.
+    ``block`` is in elements, a multiple of ``TILE`` (one (8, 128) f32
+    tile); F is zero-padded to a whole tile when it is not one already
+    (software-PS shards always are).
     """
     nl, f = grads.shape
-    block = fit_block(f, block)
-    nb = f // block
+    fp = pad_to(f, TILE)
+    if fp != f:
+        pad = ((0, 0), (0, fp - f))
+        grads = jnp.pad(grads, pad)
+        params, m, v = (jnp.pad(a, pad[1]) for a in (params, m, v))
+    block = fit_block(fp, block, multiple=TILE)
+    rows, brows = fp // LANES, block // LANES
+    step = jnp.asarray(step, jnp.float32)
+    bias = jnp.stack([1 - b1 ** step, 1 - b2 ** step])
     kernel = functools.partial(
         _agg_kernel, solver=solver, lr=lr, b1=b1, b2=b2, eps=eps,
         momentum=momentum, beta=beta)
-    step_arr = jnp.broadcast_to(
-        jnp.asarray(step, jnp.float32).reshape(1), (1,))
-    return pl.pallas_call(
+    vec = pl.BlockSpec((brows, LANES), lambda i: (i, 0))
+    outs = pl.pallas_call(
         kernel,
-        grid=(nb,),
+        grid=(rows // brows,),
         in_specs=[
-            pl.BlockSpec((nl, block), lambda i: (0, i)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((nl, brows, LANES), lambda i: (0, i, 0)),
+            vec, vec, vec,
         ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((f,), params.dtype),
-            jax.ShapeDtypeStruct((f,), m.dtype),
-            jax.ShapeDtypeStruct((f,), v.dtype),
-        ],
+        out_specs=[vec, vec, vec],
+        out_shape=[jax.ShapeDtypeStruct((rows, LANES), a.dtype)
+                   for a in (params, m, v)],
         interpret=interpret,
-    )(grads, params, m, v, step_arr)
+    )(bias, grads.reshape(nl, rows, LANES),
+      *(a.reshape(rows, LANES) for a in (params, m, v)))
+    return tuple(o.reshape(-1)[:f] for o in outs)

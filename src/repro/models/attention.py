@@ -142,8 +142,6 @@ def sp_flash_attention(q, k, v, dist, *, causal: bool,
 
     q (B, S, H, hd); k/v (B, S, KV, hd); S % model-axis == 0.
     """
-    from jax.experimental.shard_map import shard_map
-
     bt = dist.batch_axes
     mesh = dist.mesh
     n_heads = q.shape[2]
@@ -160,8 +158,8 @@ def sp_flash_attention(q, k, v, dist, *, causal: bool,
                               min(k_chunk, kf.shape[1]), kf.shape[1])
 
     spec = P(bt, "model", None, None)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _flash_fwd_lse(q, k, v, causal, q_offset, q_chunk, k_chunk, sk_valid):

@@ -41,7 +41,8 @@ def encdec_param_defs(cfg: ArchConfig, dist: Dist) -> dict:
         "mlp": mlp_param_defs(cfg, (L,)),
     }
     return {
-        "embed": ParamDef((cfg.vocab_size, cfg.d_model), ("vocab", "embed")),
+        "embed": ParamDef((cfg.vocab_size, cfg.d_model), ("vocab", "embed"),
+                          fan_in=1),
         "enc_blocks": enc_block,
         "enc_norm": norm_param_defs(cfg),
         "dec_blocks": dec_block,

@@ -27,6 +27,10 @@ class ParamDef:
     dims: Tuple[str, ...]        # logical names, see distributed/sharding.py
     init: str = "normal"         # normal | zeros | ones | const:<v>
     scale: float = 1.0           # fan-in style scale multiplier
+    # elements each output sums over; 0 = shape[-2], the input dim of a
+    # plain matmul weight (set it where the contraction spans other
+    # dims, and to 1 for a lookup table)
+    fan_in: int = 0
 
     def __post_init__(self):
         assert len(self.shape) == len(self.dims), (self.shape, self.dims)
@@ -56,7 +60,8 @@ def init_params(defs, rng, dtype) -> dict:
         elif d.init.startswith("const:"):
             a = jnp.full(d.shape, float(d.init[6:]), dtype)
         else:
-            fan_in = d.shape[-2] if len(d.shape) >= 2 else d.shape[-1]
+            fan_in = d.fan_in or (d.shape[-2] if len(d.shape) >= 2
+                                  else d.shape[-1])
             std = d.scale / math.sqrt(max(1, fan_in))
             a = (jax.random.normal(k, d.shape, jnp.float32) * std).astype(dtype)
         out.append(a)
@@ -183,11 +188,10 @@ def embed_tokens(tokens, table, dist: Dist, vocab_sharded: bool = True):
         del nshard
         return jax.lax.psum(got, "model")
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(bt, None), P("model", None)),
-        out_specs=P(bt, None, None), check_rep=False)
+        out_specs=P(bt, None, None), check_vma=False)
     return fn(tokens, table)
 
 

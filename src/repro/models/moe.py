@@ -21,7 +21,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, MoEConfig
@@ -189,11 +188,11 @@ def moe_block(x, params, cfg: ArchConfig, dist: Dist):
             out = _combine(y, tos, gos, t)
             return out.reshape(bl, sl, d).astype(xl.dtype)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(bt, "model", None), P(None, None),
                       wspec_g, wspec_g, wspec_d),
-            out_specs=P(bt, "model", None), check_rep=False)
+            out_specs=P(bt, "model", None), check_vma=False)
         return fn(x, params["router"], params["wg"], params["wu"],
                   params["wd"])
 
@@ -220,9 +219,9 @@ def moe_block(x, params, cfg: ArchConfig, dist: Dist):
         out = jax.lax.psum(out, "model")
         return out.reshape(bl, sl, d).astype(xl.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body_dec, mesh=mesh,
         in_specs=(P(bt, None, None), P(None, None),
                   wspec_g, wspec_g, wspec_d),
-        out_specs=P(bt, None, None), check_rep=False)
+        out_specs=P(bt, None, None), check_vma=False)
     return fn(x, params["router"], params["wg"], params["wu"], params["wd"])

@@ -62,10 +62,14 @@ def attn_param_defs(cfg: ArchConfig, scan_dims=()) -> dict:
     d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     ld = tuple("layers" for _ in scan_dims)
     defs = {
-        "wq": ParamDef(scan_dims + (d, h, hd), ld + ("embed", "heads", "hd")),
-        "wk": ParamDef(scan_dims + (d, kv, hd), ld + ("embed", "kv", "hd")),
-        "wv": ParamDef(scan_dims + (d, kv, hd), ld + ("embed", "kv", "hd")),
-        "wo": ParamDef(scan_dims + (h, hd, d), ld + ("heads", "hd", "embed")),
+        "wq": ParamDef(scan_dims + (d, h, hd), ld + ("embed", "heads", "hd"),
+                       fan_in=d),
+        "wk": ParamDef(scan_dims + (d, kv, hd), ld + ("embed", "kv", "hd"),
+                       fan_in=d),
+        "wv": ParamDef(scan_dims + (d, kv, hd), ld + ("embed", "kv", "hd"),
+                       fan_in=d),
+        "wo": ParamDef(scan_dims + (h, hd, d), ld + ("heads", "hd", "embed"),
+                       fan_in=h * hd),
     }
     if cfg.qkv_bias:
         defs["bq"] = ParamDef(scan_dims + (h, hd), ld + ("heads", "hd"),
@@ -111,12 +115,12 @@ def decoder_param_defs(cfg: ArchConfig, dist: Dist) -> dict:
     }
     if cfg.frontend == "none":
         defs["embed"] = ParamDef((cfg.vocab_size, cfg.d_model),
-                                 ("vocab", "embed"))
+                                 ("vocab", "embed"), fan_in=1)
     else:
         # stub frontends feed precomputed embeddings; keep a (tiny) text
         # embedding for decode steps over generated tokens.
         defs["embed"] = ParamDef((cfg.vocab_size, cfg.d_model),
-                                 ("vocab", "embed"))
+                                 ("vocab", "embed"), fan_in=1)
     return defs
 
 
@@ -135,7 +139,8 @@ def _hybrid_param_defs(cfg: ArchConfig, dist: Dist) -> dict:
         "mlp": mlp_param_defs(cfg, (np_, n_mlp)),
     }
     return {
-        "embed": ParamDef((cfg.vocab_size, cfg.d_model), ("vocab", "embed")),
+        "embed": ParamDef((cfg.vocab_size, cfg.d_model), ("vocab", "embed"),
+                          fan_in=1),
         "blocks": block,
         "final_norm": norm_param_defs(cfg),
         "head": ParamDef((cfg.d_model, cfg.vocab_size), ("embed", "vocab")),

@@ -47,6 +47,29 @@ from repro.platform.storage import StorageManager
 from repro.platform.zookeeper import ZooKeeper
 
 
+def flat_npy(tree) -> bytearray:
+    """A parameter tree as ``.npy`` bytes of one flat f32 vector (the
+    results-store layout endpoints load, leaves in ``jax.tree`` order).
+    Leaves are copied to the host and widened one at a time straight into
+    the output buffer, so the host holds one copy of the model."""
+    import jax
+    leaves = jax.tree.leaves(tree)
+    n = sum(int(np.prod(l.shape, dtype=np.int64)) for l in leaves)
+    head = io.BytesIO()
+    np.lib.format.write_array_header_1_0(
+        head, {"descr": np.lib.format.dtype_to_descr(np.dtype(np.float32)),
+               "fortran_order": False, "shape": (n,)})
+    out = bytearray(head.getbuffer().nbytes + 4 * n)
+    out[: head.getbuffer().nbytes] = head.getbuffer()
+    flat = np.frombuffer(out, np.float32, offset=head.getbuffer().nbytes)
+    off = 0
+    for leaf in leaves:
+        k = int(np.prod(leaf.shape, dtype=np.int64))
+        flat[off: off + k] = np.asarray(leaf).reshape(-1)
+        off += k
+    return out
+
+
 @dataclass
 class BackendContext:
     """Platform services a backend may wire into its task bodies."""
@@ -252,8 +275,7 @@ class PjitBackend(ExecutionBackend):
 
     def plan(self, spec: JobSpec, manifest: Dict,
              ctx: BackendContext) -> ExecutionPlan:
-        from repro.configs.base import reduce_for_smoke
-        from repro.configs.registry import get_arch
+        from repro.configs.registry import DEFAULT_ARCH, resolve_arch
         from repro.core.cursor import GlobalCursor
         from repro.data.pipeline import DatasetSpec
 
@@ -264,8 +286,8 @@ class PjitBackend(ExecutionBackend):
                 f"distribution 'pjit' requires a model-zoo framework "
                 f"('repro-lm'); got {fw_name!r} — use "
                 f"'software-ps' for plugin frameworks")
-        arch = fw_cfg.get("arch", "stablelm-1.6b")
-        cfg = reduce_for_smoke(get_arch(arch))
+        arch = fw_cfg.get("arch", DEFAULT_ARCH)
+        cfg = resolve_arch(arch)
         data_cfg = manifest.get("data", {}) or {}
         dspec = DatasetSpec(n_docs=int(data_cfg.get("n_docs", 512)),
                             seq_len=int(data_cfg.get("seq_len", 32)),
@@ -326,8 +348,8 @@ def _make_pjit_body(*, job_id, cfg, dspec, cursor, ctx, control, results,
     (or the gang is preempted/killed)."""
 
     def leader(wd):
+        import jax
         import jax.numpy as jnp
-        from jax.flatten_util import ravel_pytree
         from repro.data.pipeline import SyntheticCorpus
         from repro.distributed.sharding import Dist
         from repro.optim.optimizers import OptConfig
@@ -353,12 +375,15 @@ def _make_pjit_body(*, job_id, cfg, dspec, cursor, ctx, control, results,
                      metrics=ctx.metrics).init(seed)
         perf = meta.get("perf")
         if perf is not None:
-            zeros = np.zeros((batch_docs, dspec.seq_len), np.int32)
-            batch0 = {"tokens": jnp.asarray(zeros),
-                      "labels": jnp.asarray(zeros)}
+            # shapes, not arrays: the step donates params and opt state
+            tok = jax.ShapeDtypeStruct((batch_docs, dspec.seq_len),
+                                       jnp.int32)
+            args = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                (tr.params, tr.opt_state))
             # idempotent across incarnations (start_async runs once)
             perf.start_async(lambda: tr._step_fn.lower(
-                tr.params, tr.opt_state, batch0).compile().as_text())
+                *args, {"tokens": tok, "labels": tok}).compile().as_text())
         last = tr.ckpt.latest_valid()
         if last is not None:
             extra = tr.restore(last)
@@ -412,14 +437,10 @@ def _make_pjit_body(*, job_id, cfg, dspec, cursor, ctx, control, results,
             if tr.step % ckpt_every == 0:
                 save_ckpt()
         # store.sh analogue: upload the trained model
-        pflat, _ = ravel_pytree(tr.params)
-        buf = io.BytesIO()
-        np.save(buf, np.asarray(pflat))
         ctx.storage.upload("results", job_id, "trained_model.npy",
-                           buf.getvalue())
+                           flat_npy(tr.params))
         if loss is not None:
             results["final_loss"] = float(loss)
-        results["params"] = np.asarray(pflat)
         tr.ckpt.wait()
         state["done"].set()
 

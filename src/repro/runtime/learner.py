@@ -75,15 +75,14 @@ def register_plugin(name: str):
 
 @register_plugin("repro-lm")
 class LMPlugin:
-    """Tiny decoder LM from the model zoo (smoke-scale family configs)."""
+    """Decoder LM from the model zoo (``framework.arch``: any arch id,
+    see configs/registry.py)."""
 
     def __init__(self, framework_cfg: Dict):
-        from repro.configs.base import reduce_for_smoke
-        from repro.configs.registry import get_arch
+        from repro.configs.registry import DEFAULT_ARCH, resolve_arch
         from repro.distributed.sharding import Dist
         from repro.models import make_model
-        arch = framework_cfg.get("arch", "stablelm-1.6b")
-        cfg = reduce_for_smoke(get_arch(arch))
+        cfg = resolve_arch(framework_cfg.get("arch", DEFAULT_ARCH))
         self.cfg = cfg
         self.model = make_model(cfg, Dist(), {"remat": "none",
                                               "xent_chunk": 64,

@@ -4,7 +4,8 @@ adaptation; the paper-architecture software-PS path is runtime/learner.py).
 Features required at 1000-node scale, exercised here at host scale:
   * sharded params/optimizer per distributed/sharding.py policies,
   * periodic async checkpointing + restore-from-latest-valid,
-  * step-retry on transient executor failure (with re-restore),
+  * step-retry on executor failure, restoring the latest checkpoint
+    first (with no checkpoint to restore, the error surfaces at once),
   * ELASTIC restart: ``Trainer.resume(new_dist)`` rebuilds the step on a
     different mesh/learner count and restores the same checkpoint with the
     new shardings (resharding via device_put),
@@ -111,10 +112,12 @@ class Trainer:
 
     # ---- loop -----------------------------------------------------------------
     def step_once(self, batch):
-        """One supervised step (with transient-failure retry + restore);
-        records metrics and advances ``self.step``. This is the seam the
-        pjit execution backend drives with its own data pipeline and
-        watchdog hooks."""
+        """One supervised step (a failed step is retried from the latest
+        checkpoint); records metrics and advances ``self.step``. This is
+        the seam the pjit execution backend drives with its own data
+        pipeline and watchdog hooks. A step that fails with no
+        checkpoint to restore (a compile or out-of-memory error at step
+        0, say) raises at once: retrying it would only fail again."""
         tries = 0
         while True:
             try:
@@ -123,7 +126,8 @@ class Trainer:
                 break
             except Exception:
                 tries += 1
-                if tries > self.tc.max_step_retries:
+                if tries > self.tc.max_step_retries or \
+                        self.ckpt.latest_valid() is None:
                     raise
                 self._restore_latest()
         loss = float(loss)

@@ -25,13 +25,14 @@ import itertools
 import json
 import logging
 import os
-import tempfile
 import threading
 import time
 import uuid
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import Any, Dict, List, Optional
 
+from repro.configs.registry import DEFAULT_ARCH, resolve_arch
 from repro.observability.export import prometheus_text as _prom_text
 from repro.observability.log import (JobLogHub, register_hub,
                                      setup_logging, unregister_hub)
@@ -54,6 +55,8 @@ from repro.serving.endpoint import ModelEndpoint
 
 log = logging.getLogger("repro.core")
 
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
 
 def default_cluster(n_nodes: int = 8, gpus_per_node: int = 4) -> Cluster:
     return Cluster([Node(f"node-{i}",
@@ -63,24 +66,17 @@ def default_cluster(n_nodes: int = 8, gpus_per_node: int = 4) -> Cluster:
 
 
 def _enable_jax_compile_cache():
-    """Point jax's persistent compilation cache at a stable directory:
-    XLA compile time dominates a smoke job's wall clock, and the cache
-    (keyed by HLO hash, safe across tenants) lets repeat jobs and
-    service restarts skip it entirely. Opt out with
-    ``DLAAS_JAX_CACHE=0``; override the path with ``DLAAS_JAX_CACHE``."""
-    cache = os.environ.get(
-        "DLAAS_JAX_CACHE",
-        os.path.join(tempfile.gettempdir(), "dlaas-jax-cache"))
-    if not cache or cache == "0":
-        return
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
-    except Exception as e:                     # cache is best-effort
-        log.warning("jax compile cache unavailable: %s: %s",
-                    type(e).__name__, e)
+    """Keep jax's persistent compilation cache at a stable directory: XLA
+    compile time dominates a job's set-up, and the cache (keyed by HLO
+    hash, safe across tenants) lets repeat jobs and service restarts skip
+    it. Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already uses it
+    and nothing is overridden; otherwise the cache lives in ``.jax_cache``
+    at the root of the checkout (a fixed path: the path is part of the
+    cache key)."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(_CHECKOUT_CACHE))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 class DLaaSCore:
@@ -158,19 +154,6 @@ class DLaaSCore:
         self._ticker = threading.Thread(target=self._tick_loop,
                                         args=(tick_interval,), daemon=True)
         self._ticker.start()
-        # kernel-grid degradations surface as a platform counter
-        # (kernels/grid.py warns once per signature; the metric counts
-        # every occurrence). Weakly bound: cores come and go in tests.
-        import weakref
-
-        from repro.kernels import grid as _grid
-        wself = weakref.ref(self)
-
-        def _small_block(f, requested, chosen):
-            c = wself()
-            if c is not None:
-                c.metrics.incr("platform", "kernels_small_block_total")
-        _grid.on_small_block(_small_block)
 
     def close(self):
         self._stop.set()
@@ -1043,11 +1026,10 @@ class DLaaSCore:
                 raise ValueError(
                     f"only model-zoo ('repro-lm') trainings can be "
                     f"served; {from_training} used {fw_name!r}")
-            arch = fw_cfg.get("arch", "stablelm-1.6b")
+            arch = fw_cfg.get("arch", DEFAULT_ARCH)
         elif arch is not None:
-            from repro.configs.registry import get_arch
             try:
-                get_arch(arch)
+                resolve_arch(arch)
             except KeyError as e:
                 raise ValueError(str(e)) from None
         else:

@@ -30,8 +30,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from repro.checkpoint.checkpoint import CheckpointManager
-from repro.configs.base import reduce_for_smoke
-from repro.configs.registry import get_arch
+from repro.configs.registry import DEFAULT_ARCH, resolve_arch
 from repro.observability.trace import maybe_span
 from repro.platform.cluster import Resources
 from repro.platform.lcm import (COMPLETED, ExecutionPlan, FAILED_J,
@@ -115,8 +114,8 @@ class ServingBackend(ExecutionBackend):
              ctx: BackendContext) -> ExecutionPlan:
         fw = manifest.get("framework") or {}
         srv = manifest.get("serving") or {}
-        arch = fw.get("arch", "stablelm-1.6b")
-        cfg = reduce_for_smoke(get_arch(arch))
+        arch = fw.get("arch", DEFAULT_ARCH)
+        cfg = resolve_arch(arch)
         max_new = int(srv.get("max_new", 16))
         max_seq = srv.get("max_seq")
         if max_seq is None:

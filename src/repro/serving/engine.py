@@ -45,9 +45,9 @@ from repro.runtime.learner import _flat_io
 log = logging.getLogger("repro.serving")
 job_log = logging.getLogger("repro.job")
 
-# decode-friendly jit options (smoke-scale: tiny chunks, no remat)
-ENGINE_OPTS = {"remat": "none", "xent_chunk": 32, "q_chunk": 32,
-               "k_chunk": 32}
+# inference keeps no activations for a backward pass; attention chunks
+# are the model defaults, clipped to the prompt length
+ENGINE_OPTS = {"remat": "none"}
 
 # request states
 R_QUEUED, R_RUNNING, R_DONE, R_REJECTED, R_EXPIRED, R_FAILED = (
@@ -102,7 +102,7 @@ class InferenceEngine:
         if cfg.family == "encdec":
             raise UserError(
                 "serving supports decoder-family archs only (dense/moe/"
-                f"ssm/hybrid/vlm); {cfg.name!r} is encoder-decoder")
+                f"ssm/hybrid/vlm); {cfg.arch_id!r} is encoder-decoder")
         if capacity < 1 or max_queue < 1 or max_seq < 2:
             raise UserError("capacity/max_queue must be >= 1, max_seq >= 2")
         self.cfg = cfg
@@ -190,7 +190,7 @@ class InferenceEngine:
             if flat_params.size != size:
                 raise UserError(
                     f"weights size {flat_params.size} does not match "
-                    f"arch {self.cfg.name!r} ({size} params)")
+                    f"arch {self.cfg.arch_id!r} ({size} params)")
             self.params = unravel(jnp.asarray(flat_params))
         else:
             self.params = self.model.init(jax.random.PRNGKey(self.seed))

@@ -1,5 +1,7 @@
+import os
 import random
 import sys
+import tempfile
 import zlib
 from pathlib import Path
 
@@ -8,6 +10,12 @@ import pytest
 SRC = Path(__file__).resolve().parents[1] / "src"
 if str(SRC) not in sys.path:
     sys.path.insert(0, str(SRC))
+
+# test runs keep JAX's persistent compile cache outside the checkout
+# (set before any test module imports jax, which reads it at import)
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                      os.path.join(tempfile.gettempdir(),
+                                   "dlaas-test-jax-cache"))
 
 # NOTE: no XLA_FLAGS here — smoke tests and benches must see the real
 # single device; only launch/dryrun.py forces 512 host devices, and

@@ -14,7 +14,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import autotune, ops, ref
-from repro.kernels.grid import fit_block
+from repro.kernels.grid import TILE, fit_block
+from repro.kernels.quantize import QTILE
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -61,7 +62,7 @@ def test_cache_round_trip_across_processes(tuner_cache):
     # from re-tuning to the same deterministic answer
     data = json.loads(tuner_cache.read_text())
     (key, rec), = data.items()
-    poison = 512 if blk != 512 else 1024
+    poison = 1024 if blk != 1024 else 2048
     rec["choice"], rec["source"] = poison, "poisoned"
     tuner_cache.write_text(json.dumps(data))
     env = dict(os.environ,
@@ -101,21 +102,44 @@ def test_flash_choice_tuple_survives_disk_round_trip(tuner_cache):
 
 def test_disabled_falls_back_to_fit_block(tuner_cache, monkeypatch):
     monkeypatch.setenv("DLAAS_AUTOTUNE", "0")
-    assert autotune.tuned_ps_block(4, 1 << 14) == fit_block(1 << 14, 1024)
-    assert autotune.tuned_quantize_block(1 << 13) == \
-        fit_block(1 << 13, 4096, multiple=256)
+    assert autotune.tuned_ps_block(4, 1 << 14) == \
+        fit_block(1 << 14, TILE, multiple=TILE)
+    assert autotune.tuned_quantize_block(1 << 13) == QTILE
     assert not tuner_cache.exists()
 
 
 def test_forced_measurement_keeps_a_measured_choice(tuner_cache,
                                                     monkeypatch):
     monkeypatch.setenv("DLAAS_AUTOTUNE_MEASURE", "1")
-    blk = autotune.tuned_ps_block(2, 1024)
-    assert blk in (256, 512, 1024)
+    blk = autotune.tuned_ps_block(2, 4 * TILE)
+    assert blk in (TILE, 2 * TILE, 4 * TILE)
     (_, rec), = json.loads(tuner_cache.read_text()).items()
     assert rec["source"] == "measured"
     assert rec["measured_us"]          # top-K candidates were timed
     assert str(blk) in rec["measured_us"]
+
+
+def test_failing_measurement_is_an_error(tuner_cache):
+    def measure_s(c):
+        raise RuntimeError(f"candidate {c} did not compile")
+
+    with pytest.raises(RuntimeError, match="did not compile"):
+        autotune.tune("probe", (8,), "float32", candidates=[1, 2, 4],
+                      predict_us=float, measure_s=measure_s, default=1)
+    assert not tuner_cache.exists()             # nothing cached
+
+
+def test_under_trace_sees_jit_tracing():
+    seen = []
+
+    @jax.jit
+    def f(x):
+        seen.append(autotune._under_trace())
+        return x + 1
+
+    assert not autotune._under_trace()
+    f(jnp.zeros(2))
+    assert seen == [True]
 
 
 # ---------------------------------------------------------------------------

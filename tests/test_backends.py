@@ -30,7 +30,7 @@ data:
   seq_len: 16
 framework:
   name: repro-lm
-  arch: stablelm-1.6b
+  arch: stablelm-1.6b-smoke
 """
 
 
@@ -82,15 +82,14 @@ def test_backend_parity_same_manifest_same_seed(tmp_path):
                 assert tree["flat"].shape == params.shape
             else:
                 # restore through the real elastic path: a fresh Trainer
-                from repro.configs.base import reduce_for_smoke
-                from repro.configs.registry import get_arch
+                from repro.configs.registry import resolve_arch
                 from repro.distributed.sharding import Dist
                 from repro.optim.optimizers import OptConfig
                 from repro.runtime.trainer import Trainer, TrainerConfig
                 tc = TrainerConfig(batch=4, seq=16,
                                    ckpt_dir=f"{core.workdir}/ckpt/{tid}",
                                    job_id="probe")
-                tr = Trainer(reduce_for_smoke(get_arch("stablelm-1.6b")),
+                tr = Trainer(resolve_arch("stablelm-1.6b-smoke"),
                              Dist(), OptConfig(name="sgd", lr=0.1),
                              tc).init(0)
                 tr._restore_latest()
@@ -215,7 +214,7 @@ data:
   seq_len: 16
 framework:
   name: repro-lm
-  arch: stablelm-1.6b
+  arch: stablelm-1.6b-smoke
   distribution: pjit
 """
 

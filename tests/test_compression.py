@@ -86,7 +86,8 @@ def test_make_compressor_matches_quantize_ref():
     """The push-path compressor (jit'd reference on CPU) returns
     exactly what kernels/ref.py:quantize_ref defines."""
     from repro.kernels.ref import quantize_ref
-    fn = make_compressor()
+    fn, path = make_compressor()
+    assert path == "jnp"
     x = jax.random.normal(jax.random.PRNGKey(0), (2048,))
     e = jax.random.normal(jax.random.PRNGKey(1), (2048,)) * 0.1
     q, s, err = fn(x, e)

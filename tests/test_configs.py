@@ -3,7 +3,8 @@ import pytest
 
 from repro.configs.base import (ALL_SHAPES, reduce_for_smoke, shapes_for,
                                 skip_reason)
-from repro.configs.registry import ARCH_IDS, REGISTRY, get_arch
+from repro.configs.registry import (ARCH_IDS, DEFAULT_ARCH, REGISTRY,
+                                    get_arch, resolve_arch)
 
 ASSIGNED = {
     "kimi-k2-1t-a32b": dict(n_layers=61, d_model=7168, n_heads=64,
@@ -85,3 +86,14 @@ def test_smoke_reduction_small():
         sc = reduce_for_smoke(get_arch(aid))
         assert sc.n_params() < 3e6, (aid, sc.n_params())
         assert sc.family == get_arch(aid).family
+
+
+def test_resolve_arch_published_and_smoke_ids():
+    for aid in ARCH_IDS:
+        assert resolve_arch(aid) == get_arch(aid)
+        smoke = resolve_arch(aid + "-smoke")
+        assert smoke == reduce_for_smoke(get_arch(aid))
+        assert smoke.arch_id == aid + "-smoke"
+    assert resolve_arch(DEFAULT_ARCH).d_model == 64
+    with pytest.raises(KeyError):
+        resolve_arch("no-such-arch-smoke")

@@ -187,7 +187,7 @@ data:
   seq_len: 16
 framework:
   name: repro-lm
-  arch: stablelm-1.6b
+  arch: stablelm-1.6b-smoke
   distribution: pjit
 """
 
@@ -239,7 +239,7 @@ def test_chaos_kill_serving_node_mid_request(tmp_path):
                        for i in range(2)])
     core = DLaaSCore(str(tmp_path), cluster=cluster)
     try:
-        eid = core.deploy_endpoint(arch="stablelm-1.6b")["endpoint_id"]
+        eid = core.deploy_endpoint(arch="stablelm-1.6b-smoke")["endpoint_id"]
         assert wait_until(
             lambda: core.endpoint_status(eid)["state"] == "READY",
             timeout=120), "endpoint never became READY"

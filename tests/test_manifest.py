@@ -48,7 +48,7 @@ def test_unknown_distribution_rejected_with_usererror():
 
 def test_json_manifest_roundtrip():
     m = {"name": "json-model", "learners": 2,
-         "framework": {"name": "repro-lm", "arch": "stablelm-1.6b",
+         "framework": {"name": "repro-lm", "arch": "stablelm-1.6b-smoke",
                        "distribution": "pjit"},
          "data": {"n_docs": 64, "seq_len": 16}}
     parsed = parse_manifest(json.dumps(m))

@@ -81,6 +81,21 @@ def test_decode_matches_prefill(arch_id):
     assert err < 2e-2, (arch_id, err)
 
 
+def test_init_scales_weights_by_their_fan_in():
+    """Each weight's init std is 1/sqrt(the elements each output sums
+    over): d_model for q/k/v, heads x head_dim for the attention output,
+    one row for the embedding table (a lookup, not a sum)."""
+    sc = reduce_for_smoke(get_arch("stablelm-1.6b"))
+    p = make_model(sc, Dist(), OPTS).init(jax.random.PRNGKey(0))
+    attn = p["blocks"]["attn"]
+    want = {"wq": sc.d_model, "wk": sc.d_model, "wv": sc.d_model,
+            "wo": sc.n_heads * sc.hd}
+    for name, fan_in in want.items():
+        std = float(jnp.std(attn[name]))
+        assert abs(std * fan_in ** 0.5 - 1) < 0.1, (name, std)
+    assert abs(float(jnp.std(p["embed"])) - 1) < 0.1
+
+
 def test_train_reduces_loss():
     """A few SGD steps on the structured synthetic corpus reduce loss."""
     sc = reduce_for_smoke(get_arch("stablelm-1.6b"))

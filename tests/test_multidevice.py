@@ -4,24 +4,13 @@ device count at first import, so these cannot run in the pytest process).
 Covers: sharded-vs-local MoE equivalence, mesh solver collective patterns
 (the paper's O(L) vs O(L^2) bytes), elastic trainer resharding, and a
 miniature dry-run (lower+compile with shardings on a 4x2 mesh)."""
-import jax
 import pytest
 
 from util_subproc import run_with_devices
 
 pytestmark = pytest.mark.slow
 
-# every test here builds its mesh through repro.launch.mesh.make_mesh,
-# which requires explicit axis types (jax.sharding.AxisType) — absent
-# from the installed jax (known environment limitation)
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="installed jax predates jax.sharding.AxisType "
-           "(known environment limitation; launch.mesh builds "
-           "explicit-axis meshes)")
 
-
-@needs_axis_type
 def test_moe_sharded_matches_local():
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
@@ -39,7 +28,7 @@ defs = moe_param_defs(cfg, dist)
 params = init_params(defs, jax.random.PRNGKey(0), jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model)) * 0.5
 
-with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
+with jax.set_mesh(mesh):
     y_sh = jax.jit(lambda x, p: moe_block(x, p, cfg, dist))(x, params)
 r = replication_factor(cfg.moe, dist)
 y_loc = _moe_single(x, params, cfg.moe, r)
@@ -49,7 +38,7 @@ assert d < 5e-2, d
 
 # decode path (seq=1)
 x1 = x[:, :1]
-with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
+with jax.set_mesh(mesh):
     y1 = jax.jit(lambda x, p: moe_block(x, p, cfg, dist))(x1, params)
 y1l = _moe_single(x1, params, cfg.moe, r)
 d1 = float(jnp.max(jnp.abs(np.asarray(y1) - np.asarray(y1l))))
@@ -60,7 +49,6 @@ print("OK")
     assert "OK" in out
 
 
-@needs_axis_type
 def test_mesh_solvers_converge_and_byte_pattern():
     out = run_with_devices("""
 import re, jax, jax.numpy as jnp
@@ -107,7 +95,6 @@ print("OK")
     assert "OK" in out
 
 
-@needs_axis_type
 def test_elastic_trainer_reshard():
     out = run_with_devices("""
 import shutil
@@ -137,7 +124,6 @@ print("OK")
     assert "OK" in out
 
 
-@needs_axis_type
 def test_tiny_dryrun_all_step_kinds():
     """lower+compile with shardings for train/prefill/decode on a 4x2
     mesh — the in-repo miniature of the 512-device production dry-run."""
@@ -175,7 +161,6 @@ print("OK")
     assert "OK" in out
 
 
-@needs_axis_type
 def test_sp_attention_matches_reference():
     """zero3_sp sequence-parallel attention == unsharded reference
     (values AND grads), including the causal per-shard offset."""

@@ -262,7 +262,7 @@ def test_endpoint_redeploys_after_crash(tmp_path):
     answers a predict."""
     wd = str(tmp_path / "w")
     c1 = DLaaSCore(workdir=wd)
-    eid = c1.deploy_endpoint(arch="stablelm-1.6b", user="bob",
+    eid = c1.deploy_endpoint(arch="stablelm-1.6b-smoke", user="bob",
                              idempotency_key="ep-1")["endpoint_id"]
     assert wait_until(
         lambda: c1.endpoint_status(eid)["state"] == "READY", timeout=60)
@@ -278,7 +278,7 @@ def test_endpoint_redeploys_after_crash(tmp_path):
     # same weights (fresh-init arch endpoints re-seed identically)
     assert out2["tokens"] == out1["tokens"]
     # replaying the deploy returns the original endpoint, not a second
-    assert c2.deploy_endpoint(arch="stablelm-1.6b", user="bob",
+    assert c2.deploy_endpoint(arch="stablelm-1.6b-smoke", user="bob",
                               idempotency_key="ep-1")[
         "endpoint_id"] == eid
     assert len(c2.endpoints) == 1

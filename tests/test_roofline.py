@@ -1,5 +1,7 @@
 """Roofline HLO analyzer: dot FLOPs, while trip counts, collective
 formulas, group parsing — validated against analytically-known modules."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,26 +17,6 @@ def _hlo_of(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _dot_flops_are_exact():
-    """Probe whether the analyzer can recover exact dot FLOPs from this
-    XLA's HLO text.  Newer XLA prints dot operands with type annotations
-    the operand-shape lookup cannot resolve, so the contracted dimension
-    falls back to 1 and FLOP counts are under-reported (known
-    environment limitation)."""
-    a = jax.ShapeDtypeStruct((8, 16), jnp.float32)
-    b = jax.ShapeDtypeStruct((16, 4), jnp.float32)
-    txt = _hlo_of(lambda x, y: x @ y, a, b)
-    return analyze_hlo_text(txt)["flops_per_device"] == 2 * 8 * 16 * 4
-
-
-needs_exact_dot_flops = pytest.mark.skipif(
-    not _dot_flops_are_exact(),
-    reason="this XLA emits typed dot operands the analyzer's "
-           "operand-shape lookup cannot resolve, so contracted-dim "
-           "FLOPs are under-counted (known environment limitation)")
-
-
-@needs_exact_dot_flops
 def test_dot_flops_exact():
     a = jax.ShapeDtypeStruct((64, 128), jnp.float32)
     b = jax.ShapeDtypeStruct((128, 32), jnp.float32)
@@ -43,7 +25,6 @@ def test_dot_flops_exact():
     assert got == 2 * 64 * 128 * 32
 
 
-@needs_exact_dot_flops
 def test_scan_trip_count_multiplies():
     a = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     w = jax.ShapeDtypeStruct((24, 64, 64), jnp.float32)
@@ -59,7 +40,6 @@ def test_scan_trip_count_multiplies():
     assert abs(got - want) / want < 0.05, (got, want)
 
 
-@needs_exact_dot_flops
 def test_nested_scan_trip_counts():
     a = jax.ShapeDtypeStruct((32, 32), jnp.float32)
     w = jax.ShapeDtypeStruct((4, 3, 32, 32), jnp.float32)
@@ -76,6 +56,39 @@ def test_nested_scan_trip_counts():
     got = analyze_hlo_text(txt)["flops_per_device"]
     want = 12 * 2 * 32 ** 3
     assert abs(got - want) / want < 0.05, (got, want)
+
+
+def test_roofline_times_use_the_named_device_peaks():
+    a = jax.ShapeDtypeStruct((256, 256), jnp.float32)
+    txt = _hlo_of(lambda x: x @ x, a)
+    tpu = analyze_hlo_text(txt, device_kind="TPU v5 lite")
+    assert tpu["device_kind"] == "TPU v5 lite"
+    assert tpu["compute_s"] == pytest.approx(tpu["flops_per_device"]
+                                             / 197e12)
+    assert tpu["memory_s"] == pytest.approx(tpu["hbm_bytes_per_device"]
+                                            / 819e9)
+    # a kind with no published peaks gets counts, never v5e's ceiling
+    other = analyze_hlo_text(txt, device_kind="cpu")
+    assert other["flops_per_device"] == tpu["flops_per_device"]
+    assert other["compute_s"] is None and other["memory_s"] is None
+
+
+def test_job_perf_names_the_device_kind_it_ran_on():
+    from repro.analysis.perf import JobPerf
+    a = jax.ShapeDtypeStruct((64, 64), jnp.float32)
+    txt = _hlo_of(lambda x: x @ x, a)
+    perf = JobPerf("job-perf-test")
+    perf.start_async(lambda: txt)
+    for _ in range(600):
+        if perf.snapshot()["state"] != "running":
+            break
+        time.sleep(0.05)
+    snap = perf.snapshot(measured_per_s=10.0)
+    assert snap["state"] == "ready", snap
+    assert snap["device_kind"] == jax.devices()[0].device_kind
+    assert snap["peaks"] == "no peaks"          # the CPU is not in PEAKS
+    assert snap["flops_per_step_per_device"] == 2 * 64 ** 3
+    assert "pct_of_attainable" not in snap
 
 
 def test_group_info_parsing():

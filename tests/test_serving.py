@@ -10,20 +10,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.base import reduce_for_smoke
-from repro.configs.registry import get_arch
+from repro.configs.registry import resolve_arch
 from repro.platform.cluster import UserError
 from repro.serving.engine import (EndpointClosed, InferenceEngine,
                                   QueueFull)
 from util_poll import assert_holds_for, wait_until
 
-ARCH = "stablelm-1.6b"
+ARCH = "stablelm-1.6b-smoke"
 MAX_SEQ = 32
 
 
 @pytest.fixture(scope="module")
 def cfg():
-    return reduce_for_smoke(get_arch(ARCH))
+    return resolve_arch(ARCH)
 
 
 @pytest.fixture(scope="module")
@@ -158,6 +157,11 @@ def test_submit_validation(cfg, engine):
         engine.submit([cfg.vocab_size + 7])        # out-of-vocab token
 
 
+def test_encoder_decoder_arch_refused_with_user_error():
+    with pytest.raises(UserError, match="whisper-large-v3-smoke"):
+        InferenceEngine(resolve_arch("whisper-large-v3-smoke"))
+
+
 def test_release_frees_buffers_and_fails_queued(cfg):
     eng = InferenceEngine(cfg, capacity=1, max_seq=MAX_SEQ,
                           default_max_new=2, endpoint_id="ep-rel")
@@ -178,7 +182,7 @@ def test_release_frees_buffers_and_fails_queued(cfg):
 TRAIN_MANIFEST = ("name: serve-src\nlearners: 1\ngpus: 1\nsteps: 3\n"
                   "batch_docs: 2\ncheckpoint_every: 100\n"
                   "data:\n  n_docs: 32\n  seq_len: 16\n"
-                  "framework:\n  name: repro-lm\n  arch: stablelm-1.6b\n")
+                  "framework:\n  name: repro-lm\n  arch: stablelm-1.6b-smoke\n")
 
 
 @pytest.fixture(scope="module")
@@ -210,7 +214,7 @@ def test_endpoint_lifecycle_from_training(core):
 
     out = core.deploy_endpoint(from_training=tid, capacity=2, max_new=4)
     eid = out["endpoint_id"]
-    assert out["arch"] == "stablelm-1.6b"
+    assert out["arch"] == "stablelm-1.6b-smoke"
     _wait_state(core, eid, "READY")
 
     rng = np.random.RandomState(0)
@@ -257,7 +261,7 @@ def test_deploy_validation(core):
 def test_endpoint_pause_resume(core):
     """Endpoints share the training lifecycle hooks: pause gates the
     serve loop at a batch-step boundary, resume reopens it."""
-    out = core.deploy_endpoint(arch="stablelm-1.6b", capacity=1,
+    out = core.deploy_endpoint(arch="stablelm-1.6b-smoke", capacity=1,
                                max_new=2)
     eid = out["endpoint_id"]
     _wait_state(core, eid, "READY")
@@ -278,18 +282,18 @@ def test_endpoint_is_a_metered_job(core):
     what the quota can never fit."""
     from repro.platform.queue import QuotaExceeded
     core.register_tenant("svc-team", quota_gpus=1)
-    out = core.deploy_endpoint(arch="stablelm-1.6b", capacity=1,
+    out = core.deploy_endpoint(arch="stablelm-1.6b-smoke", capacity=1,
                                tenant="svc-team", gpus=1, max_new=2)
     eid = out["endpoint_id"]
     assert core.lcm.job_spec(eid).get("tenant") == "svc-team"
     with pytest.raises(QuotaExceeded):
-        core.deploy_endpoint(arch="stablelm-1.6b", tenant="svc-team",
+        core.deploy_endpoint(arch="stablelm-1.6b-smoke", tenant="svc-team",
                              gpus=2)
     _wait_state(core, eid, "READY")
     # a second endpoint fits the quota but must wait for the first:
     # it sits QUEUED — and stopping it must actually remove it from
     # the scheduler queue, not just flag the engine draining
-    held = core.deploy_endpoint(arch="stablelm-1.6b", capacity=1,
+    held = core.deploy_endpoint(arch="stablelm-1.6b-smoke", capacity=1,
                                 tenant="svc-team", gpus=1,
                                 max_new=2)["endpoint_id"]
     assert core.endpoint_status(held)["state"] == "DEPLOYING"

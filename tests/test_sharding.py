@@ -27,19 +27,6 @@ def dist(policy="fsdp_tp", pod=False):
     return Dist(mesh=FakeMesh(shape), policy=policy)
 
 
-# the spec engine emits single-axis FSDP entries as 1-tuples
-# (P(("data",), ...)); newer jax normalizes those to the bare axis name
-# so equality with the literals below holds, but the installed jax
-# (0.4.x) keeps the tuple and P(("data",)) != P("data") (known
-# environment limitation)
-needs_spec_normalization = pytest.mark.skipif(
-    P(("x",)) != P("x"),
-    reason="installed jax's PartitionSpec does not normalize singleton "
-           "axis tuples, so P(('data',)) != P('data') (known environment "
-           "limitation)")
-
-
-@needs_spec_normalization
 def test_tp_dims_take_model_axis():
     d = dist()
     assert spec_for(d, ("embed", "ff"), (1024, 4096)) == \
@@ -48,7 +35,6 @@ def test_tp_dims_take_model_axis():
         P("model", ("data",))
 
 
-@needs_spec_normalization
 def test_indivisible_dims_fall_back_to_replicated():
     d = dist()
     # whisper: 20 heads, vocab 51866 — neither divides 16
@@ -73,7 +59,6 @@ def test_policies():
         P(("pod", "data"), "model")
 
 
-@needs_spec_normalization
 def test_axis_used_once_per_spec():
     d = dist()
     # two fsdp dims: only the first takes the axis
@@ -88,7 +73,6 @@ def test_batch_resolution():
     assert d.resolve_batch(1).batch_axes is None
 
 
-@needs_spec_normalization
 def test_adafactor_state_specs_follow_factoring():
     d = dist()
     defs = {"w": ParamDef((1024, 4096), ("embed", "ff")),
