@@ -19,7 +19,10 @@ Span taxonomy (see docs/ARCHITECTURE.md for the full table):
     ``admission``, ``warm_compile``, sampled ``step``,
     ``checkpoint_publish``, serving ``prefill`` / ``request``;
   * point events (zero-duration): ``recovery``, ``relaunch``,
-    ``fault``, ``node_transition``, sampled ``decode``.
+    ``fault``, ``node_transition``.
+
+The serving engine's loop phases (``serve.*``) go to the profiler's trace
+instead (``serving/engine.py``), on the device's clock and in no ring.
 
 Spans live in a ring-buffered ``TraceStore`` (traces evict LRU, spans
 per trace evict oldest) so a long-lived service holds bounded memory no
@@ -43,7 +46,7 @@ CLUSTER_TRACE = "cluster"
 
 log = logging.getLogger("repro.trace")
 
-# sampled step spans: every Nth training step / decode batch gets a span
+# sampled step spans: every Nth training step gets a span
 # (all steps would swamp the ring for zero extra insight)
 TRACE_STEP_SAMPLE = int(os.environ.get("DLAAS_TRACE_STEP_SAMPLE", "8"))
 

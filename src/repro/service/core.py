@@ -1163,7 +1163,9 @@ class DLaaSCore:
             return {"endpoint_id": endpoint_id, "request_id": req.req_id,
                     "tokens": req.tokens,
                     "n_prompt": int(req.prompt.size),
-                    "latency_s": round(time.time() - t0, 4)}
+                    "latency_s": round(time.time() - t0, 4),
+                    "queue_s": round(req.queue_s, 4),
+                    "ttft_s": round(req.ttft_s, 4)}
         if req.status == "EXPIRED":
             raise DeadlineExceeded(
                 f"request {req.req_id} missed its deadline")

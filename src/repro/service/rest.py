@@ -18,7 +18,8 @@ Endpoints (v1):
                                          STOPPED|FAILED + request/latency/
                                          occupancy stats)
   POST   /v1/models/<id>/predict         {tokens: [..], max_new,
-                                          deadline_s} -> generated tokens
+                                          deadline_s} -> generated tokens,
+                                         latency_s, queue_s, ttft_s
                                          (429 queue full, 409 draining,
                                           504 deadline missed)
   DELETE /v1/models/<id>                 delete manifest — or drain+stop

@@ -33,11 +33,12 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.distributed.sharding import Dist
 from repro.models import make_model
-from repro.observability.trace import TRACE_STEP_SAMPLE, maybe_span
+from repro.observability.trace import maybe_span
 from repro.platform.cluster import UserError
 from repro.platform.metrics import MetricsService
 from repro.runtime.learner import _flat_io
@@ -48,6 +49,22 @@ job_log = logging.getLogger("repro.job")
 # inference keeps no activations for a backward pass; attention chunks
 # are the model defaults, clipped to the prompt length
 ENGINE_OPTS = {"remat": "none"}
+
+# names of the engine's jitted programs: XLA calls them jit_<name>, and the
+# profiler's trace and the benchmark find them by these names
+PREFILL_PROGRAM = "serve_prefill"
+DECODE_PROGRAM = "serve_decode"
+
+# The serve loop writes one profiler span per phase (TraceAnnotation: on the
+# profiler's clock beside the device's ops, and nowhere else; about 1 us when
+# no profiler runs). The phases are siblings that tile each iteration:
+#   serve.wait      nothing to run (no live slot, or the pause gate holds)
+#   serve.schedule  preemption check, queue expiry, picking the next batch
+#   serve.admit     one prefill batch: upload, prefill, first tokens, splices
+#   serve.dispatch  next-token upload, decode step and argmax dispatched
+#   serve.read      the step's tokens read on the host (waits for the device)
+#   serve.bookkeep  tokens appended, requests retired and settled, gauges
+# so each serve.read is one decode step.
 
 # request states
 R_QUEUED, R_RUNNING, R_DONE, R_REJECTED, R_EXPIRED, R_FAILED = (
@@ -77,10 +94,35 @@ class InferenceRequest:
     tokens: List[int] = field(default_factory=list)
     error: str = ""
     done: threading.Event = field(default_factory=threading.Event)
+    started_ts: Optional[float] = None      # taken from the queue into prefill
+    first_token_ts: Optional[float] = None  # first token read on the host
     finished_ts: Optional[float] = None
 
     def wait(self, timeout: Optional[float] = None) -> bool:
         return self.done.wait(timeout)
+
+    @property
+    def queue_s(self) -> Optional[float]:
+        """Seconds from submission to being taken into prefill."""
+        if self.started_ts is None:
+            return None
+        return self.started_ts - self.submitted
+
+    @property
+    def ttft_s(self) -> Optional[float]:
+        """Seconds from submission to the first token."""
+        if self.first_token_ts is None:
+            return None
+        return self.first_token_ts - self.submitted
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``, which jit gives its program (jit_<name>)
+    whatever the function is called."""
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 class InferenceEngine:
@@ -145,8 +187,7 @@ class InferenceEngine:
             maxlen=4096)
         self._decode_steps = 0
         self._occupied_slot_steps = 0
-        self._first_decode_t: Optional[float] = None
-        self._last_decode_t: Optional[float] = None
+        self._decode_s = 0.0        # host time inside decode steps
         # roofline estimate of the decode step (status.perf), analyzed
         # in the background once the jits are built
         from repro.analysis.perf import JobPerf
@@ -194,7 +235,7 @@ class InferenceEngine:
             self.params = unravel(jnp.asarray(flat_params))
         else:
             self.params = self.model.init(jax.random.PRNGKey(self.seed))
-        self._prefill = jax.jit(self.model.prefill)
+        self._prefill = jax.jit(_named(self.model.prefill, PREFILL_PROGRAM))
 
         def decode_one(params, cache, tok):
             # vmap strips the slot axis; model.decode wants batch dim 1
@@ -209,8 +250,8 @@ class InferenceEngine:
             return logits, new
 
         self._decode = jax.jit(
-            jax.vmap(decode_one, in_axes=(None, self._axes, 0),
-                     out_axes=(0, self._axes)),
+            _named(jax.vmap(decode_one, in_axes=(None, self._axes, 0),
+                            out_axes=(0, self._axes)), DECODE_PROGRAM),
             donate_argnums=(1,))
         self._splice = jax.jit(self._splice_fn, donate_argnums=(0,))
         with self._lock:
@@ -327,24 +368,35 @@ class InferenceEngine:
         served = 0
         try:
             while True:
-                if wd is not None:
-                    wd.maybe_preempt()
-                if control is not None:
-                    control.wait_while_paused(should_abort=should_abort)
-                self._expire_queued()
-                self._admit()
-                with self._lock:
-                    live = sum(1 for r in self._slots if r is not None)
-                    idle_exit = (self._draining and live == 0
-                                 and not self._queue)
-                if idle_exit:
+                with TraceAnnotation("serve.schedule"):
+                    if wd is not None:
+                        wd.maybe_preempt()
+                    paused = control is not None and control.paused
+                    if not paused:
+                        self._expire_queued()
+                        batch = self._take_batch()
+                        with self._lock:
+                            live = sum(1 for r in self._slots
+                                       if r is not None)
+                            idle_exit = (self._draining and live == 0
+                                         and not self._queue)
+                if paused:
+                    with TraceAnnotation("serve.wait"):
+                        control.wait_while_paused(should_abort=should_abort)
+                elif batch is not None:
+                    # admit every batch that fits before the next step
+                    self._admit(*batch)
+                elif idle_exit:
                     break
-                if live:
+                elif live:
                     served += self._decode_once()
                     if wd is not None and self._decode_steps % 32 == 0:
-                        wd.heartbeat(self._decode_steps, served=served)
-                elif self._wake.wait(timeout=0.02):
-                    self._wake.clear()
+                        with TraceAnnotation("serve.bookkeep"):
+                            wd.heartbeat(self._decode_steps, served=served)
+                else:
+                    with TraceAnnotation("serve.wait"):
+                        if self._wake.wait(timeout=0.02):
+                            self._wake.clear()
         except BaseException:
             # preemption or infra failure: put in-flight work back at
             # the head of the queue (newest first through appendleft,
@@ -357,6 +409,7 @@ class InferenceEngine:
                                 reverse=True):
                     r.tokens = []
                     r.status = R_QUEUED
+                    r.started_ts = r.first_token_ts = None
                     self._queue.appendleft(r)
                 self._ready.clear()
             raise
@@ -415,35 +468,46 @@ class InferenceEngine:
                 out[k] = jnp.pad(out[k], pads)
         return out
 
-    def _admit(self):
-        """Prefill queued requests into free slots. Equal-length queue
-        neighbours are prefilled together as one batch (continuous
-        batching's batched-prefill path); the per-request caches are
-        then spliced into their slots."""
-        while True:
-            with self._lock:
-                if not self._queue or self._cache is None:
-                    return
-                free = [s for s in range(self.capacity)
-                        if self._slots[s] is None]
-                if not free:
-                    return
-                batch = [self._queue.popleft()]
-                plen = batch[0].prompt.size
-                while (len(batch) < len(free) and self._queue
-                       and self._queue[0].prompt.size == plen):
-                    batch.append(self._queue.popleft())
-                depth = len(self._queue)
-            self._gauge("queue_depth", depth)
+    def _take_batch(self):
+        """Take the next prefill batch off the queue: the head request
+        and its equal-length queue neighbours (continuous batching's
+        batched-prefill path), at most one per free slot. Returns
+        (batch, free slots), or None when nothing can be admitted."""
+        with self._lock:
+            if not self._queue or self._cache is None:
+                return None
+            free = [s for s in range(self.capacity)
+                    if self._slots[s] is None]
+            if not free:
+                return None
+            batch = [self._queue.popleft()]
+            plen = batch[0].prompt.size
+            while (len(batch) < len(free) and self._queue
+                   and self._queue[0].prompt.size == plen):
+                batch.append(self._queue.popleft())
+            depth = len(self._queue)
+            now = time.time()
+            for req in batch:
+                req.started_ts = now
+        self._gauge("queue_depth", depth)
+        return batch, free
+
+    def _admit(self, batch: List[InferenceRequest], free: List[int]):
+        """Prefill one batch and splice each request's cache into its
+        free slot."""
+        plen = int(batch[0].prompt.size)
+        with TraceAnnotation("serve.admit", n=len(batch), plen=plen):
             toks = jnp.asarray(np.stack([r.prompt for r in batch]))
+            # the span ends with the first tokens on the host, that is
+            # once the device has finished the prefill
             with maybe_span(self.tracer, self.endpoint_id, "prefill",
-                            n=len(batch), plen=int(plen)):
+                            n=len(batch), plen=plen):
                 logits, c1 = self._prefill(self.params,
                                            {"tokens": toks})
-            c1 = self._pad_prefill(c1)
-            first = np.asarray(
-                jnp.argmax(logits[:, -1, :], axis=-1)).astype(np.int32)
-            now = time.time()
+                c1 = self._pad_prefill(c1)
+                first = np.asarray(
+                    jnp.argmax(logits[:, -1, :], axis=-1)).astype(np.int32)
+                now = time.time()
             for i, req in enumerate(batch):
                 slot = free[i]
                 one = {k: jax.lax.slice_in_dim(v, i, i + 1,
@@ -456,38 +520,39 @@ class InferenceEngine:
                                            jnp.asarray(slot, jnp.int32))
                 with self._lock:
                     req.status = R_RUNNING
+                    req.first_token_ts = now
                     req.tokens.append(int(first[i]))
                     self._slots[slot] = req
                     self._next_tok[slot] = first[i]
                     self._maybe_retire(slot, req, now)
 
     def _decode_once(self) -> int:
-        toks = jnp.asarray(self._next_tok.reshape(self.capacity, 1, 1))
-        logits, self._cache = self._decode(self.params, self._cache, toks)
-        nxt = np.asarray(
-            jnp.argmax(logits[:, 0, -1, :], axis=-1)).astype(np.int32)
-        now = time.time()
-        live = 0
-        with self._lock:
-            for s in range(self.capacity):
-                r = self._slots[s]
-                if r is None:
-                    continue
-                live += 1
-                r.tokens.append(int(nxt[s]))
-                self._next_tok[s] = nxt[s]
-                self._maybe_retire(s, r, now)
-            self._decode_steps += 1
-            self._occupied_slot_steps += live
-            if self._first_decode_t is None:
-                self._first_decode_t = now
-            self._last_decode_t = now
-        self._gauge("batch_occupancy", live / self.capacity,
-                    step=self._decode_steps)
-        if (self.tracer is not None
-                and self._decode_steps % TRACE_STEP_SAMPLE == 0):
-            self.tracer.event(self.endpoint_id, "decode",
-                              step=self._decode_steps, live=live)
+        with TraceAnnotation("serve.dispatch"):
+            t0 = time.perf_counter()
+            toks = jnp.asarray(self._next_tok.reshape(self.capacity, 1, 1))
+            logits, self._cache = self._decode(self.params, self._cache,
+                                               toks)
+            nxt = jnp.argmax(logits[:, 0, -1, :], axis=-1)
+        with TraceAnnotation("serve.read"):
+            nxt = np.asarray(nxt).astype(np.int32)
+            step_s = time.perf_counter() - t0
+        with TraceAnnotation("serve.bookkeep"):
+            now = time.time()
+            live = 0
+            with self._lock:
+                for s in range(self.capacity):
+                    r = self._slots[s]
+                    if r is None:
+                        continue
+                    live += 1
+                    r.tokens.append(int(nxt[s]))
+                    self._next_tok[s] = nxt[s]
+                    self._maybe_retire(s, r, now)
+                self._decode_steps += 1
+                self._decode_s += step_s
+                self._occupied_slot_steps += live
+            self._gauge("batch_occupancy", live / self.capacity,
+                        step=self._decode_steps)
         return live
 
     def _maybe_retire(self, slot: int, req: InferenceRequest, now: float):
@@ -544,7 +609,8 @@ class InferenceEngine:
             self.tracer.end(span,
                             status=("ok" if status == R_DONE
                                     else "error"),
-                            result=status, tokens=len(req.tokens))
+                            result=status, tokens=len(req.tokens),
+                            queue_s=req.queue_s, ttft_s=req.ttft_s)
         job_log.debug("request %s %s tokens=%d latency=%.4fs",
                       req.req_id, status, len(req.tokens), lat,
                       extra={"job_id": self.endpoint_id})
@@ -601,14 +667,13 @@ class InferenceEngine:
 
     # ---- observability ----------------------------------------------------
     def decode_rate(self) -> Optional[float]:
-        """Measured decode steps/s over the serve so far (the measured
-        term of the status.perf roofline fraction)."""
+        """Decode steps/s over the host time spent inside decode steps,
+        from dispatch to the tokens read, so time the engine sits idle
+        is not counted (the measured term of the status.perf roofline
+        fraction)."""
         with self._lock:
-            steps = self._decode_steps
-            t0, t1 = self._first_decode_t, self._last_decode_t
-        if steps >= 2 and t0 is not None and t1 is not None and t1 > t0:
-            return (steps - 1) / (t1 - t0)
-        return None
+            steps, secs = self._decode_steps, self._decode_s
+        return steps / secs if steps and secs > 0 else None
 
     def stats(self) -> Dict:
         """Counters + latency percentiles + occupancy — what endpoint

@@ -111,6 +111,84 @@ def test_eos_retires_early(cfg):
     assert r.tokens == free_run.tokens[:2]
 
 
+def test_request_timestamps_and_prefill_span(cfg):
+    """Each request carries its queue wait and time to first token; the
+    tracer's prefill span ends once the first tokens are on the host;
+    no per-step decode event crowds the trace."""
+    from repro.observability.trace import Tracer
+    tracer = Tracer()
+    eng = InferenceEngine(cfg, capacity=1, max_seq=MAX_SEQ,
+                          endpoint_id="ep-ts", tracer=tracer)
+    eng.start(None)
+    rng = np.random.RandomState(5)
+    reqs = [eng.submit(rng.randint(0, cfg.vocab_size, size=6)
+                       .astype(np.int32), max_new=3) for _ in range(3)]
+    _serve(eng, reqs)
+    for r in reqs:
+        assert r.status == "DONE"
+        assert 0 <= r.queue_s <= r.ttft_s <= r.finished_ts - r.submitted
+    # one slot: the third request waits for the first two
+    assert reqs[2].queue_s > reqs[0].queue_s
+    spans = tracer.store.spans(tracer.trace_of("ep-ts"))
+    prefills = sorted((s for s in spans if s.name == "prefill"),
+                      key=lambda s: s.start)
+    assert len(prefills) == len(reqs)
+    for sp, r in zip(prefills, reqs):
+        assert sp.end >= r.first_token_ts
+    by_req = {s.attrs["req_id"]: s for s in spans if s.name == "request"}
+    for r in reqs:
+        assert by_req[r.req_id].attrs["queue_s"] == r.queue_s
+        assert by_req[r.req_id].attrs["ttft_s"] == r.ttft_s
+    assert not [s for s in spans if s.name == "decode"]
+
+
+def test_decode_rate_excludes_idle_time(cfg):
+    """decode_rate() divides the steps by the time spent in them, not by
+    the time the engine sat idle between them."""
+    eng = InferenceEngine(cfg, capacity=1, max_seq=MAX_SEQ,
+                          endpoint_id="ep-rate")
+    eng.start(None)
+    assert eng.decode_rate() is None
+    rng = np.random.RandomState(6)
+
+    def serve_one():
+        r = eng.submit(rng.randint(0, cfg.vocab_size, size=6)
+                       .astype(np.int32), max_new=6)
+        assert r.wait(180), r.status
+
+    t = threading.Thread(target=eng.run, daemon=True)
+    t.start()
+    serve_one()                            # compiles every program used
+    steps0 = eng.stats()["decode_steps"]
+    decode_s0 = steps0 / eng.decode_rate()
+    idle_s = 1.0
+    t0 = time.perf_counter()
+    serve_one()
+    time.sleep(idle_s)                     # no live slot: the engine idles
+    serve_one()
+    eng.drain()
+    t.join(20)
+    assert not t.is_alive()
+    busy_s = time.perf_counter() - t0 - idle_s
+    steps = eng.stats()["decode_steps"]
+    assert steps == 3 * steps0
+    # host time inside the later steps: none of the idle second in it
+    assert 0 < steps / eng.decode_rate() - decode_s0 <= busy_s
+
+
+def test_program_names(cfg, engine):
+    """The engine names its jitted programs itself, so the benchmark's
+    trace readers find decode and prefill after any refactor."""
+    from repro.serving.engine import DECODE_PROGRAM, PREFILL_PROGRAM
+    assert "decode" in DECODE_PROGRAM and "prefill" in PREFILL_PROGRAM
+    toks = jnp.zeros((engine.capacity, 1, 1), jnp.int32)
+    text = engine._decode.lower(engine.params, engine._cache, toks).as_text()
+    assert f"module @jit_{DECODE_PROGRAM} " in text
+    text = engine._prefill.lower(
+        engine.params, {"tokens": jnp.zeros((1, 8), jnp.int32)}).as_text()
+    assert f"module @jit_{PREFILL_PROGRAM} " in text
+
+
 # ---------------------------------------------------------------------------
 # admission queue: overflow + deadlines
 # ---------------------------------------------------------------------------
@@ -222,6 +300,7 @@ def test_endpoint_lifecycle_from_training(core):
            for _ in range(3)]
     for r in res:
         assert len(r["tokens"]) == 4
+        assert 0 <= r["queue_s"] <= r["ttft_s"] <= r["latency_s"]
     # the endpoint serves the *trained* weights, deterministically:
     # the same prompt through a second from-training endpoint matches
     again = core.predict(eid, np.arange(5) + 1, max_new=3)["tokens"]
