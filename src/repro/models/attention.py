@@ -9,10 +9,11 @@ Layouts (see DESIGN.md §5):
     repeated by the caller) so the head dim shards cleanly over "model"
     for ANY kv count; the repeated k/v is itself head-sharded so the
     per-device footprint matches q.
-  * decode: q is one token; k/v stay in compact (B, S, KV, hd) cache form,
-    queries folded to (KV, group). The cache's sequence dim is sharded for
-    long contexts and the softmax reductions over S become SPMD partial-
-    softmax combines (the TPU flash-decoding analogue).
+  * decode: q is one token; k/v stay in compact (B, S, KV*hd) cache rows,
+    each query head spread block-diagonally over a row. The cache's
+    sequence dim is sharded for long contexts and the softmax reductions
+    over S become SPMD partial-softmax combines (the TPU flash-decoding
+    analogue).
 """
 from __future__ import annotations
 
@@ -108,26 +109,53 @@ def repeat_kv(k, n_heads: int):
     return jnp.repeat(k, n_heads // kv, axis=2)
 
 
-def decode_attention(q, k_cache, v_cache, length):
-    """Single-step attention against a compact cache.
+def decode_attention(q, k_cache, v_cache, length, k_new=None, v_new=None):
+    """Single-step attention against a compact cache, read as it is stored.
 
-    q (B,1,H,hd); k_cache/v_cache (B,S,KV,hd); length: scalar valid length
-    (entries at positions >= length are masked). Sequence-dim sharding of
-    the cache turns the softmax reductions into SPMD partial combines.
+    q (B,1,H,hd); k_cache/v_cache (B,S,KV*hd): one row per position, its KV
+    heads side by side as the projection makes them. length: valid length,
+    a scalar or one per batch row (entries at positions >= length are
+    masked). k_new/v_new (B,1,KV*hd), when given, is the step's own row:
+    attended after the valid rows, without being written into the cache.
+
+    Each query head is spread over a whole row, zero outside its KV head's
+    hd columns, so both contractions run over rows in their stored layout
+    (no per-head relayout of the cache) and the zero blocks add exact
+    zeros. Sequence-dim sharding of the cache turns the softmax reductions
+    into SPMD partial combines.
     """
     B, _, H, hd = q.shape
-    _, S, KV, _ = k_cache.shape
+    _, S, width = k_cache.shape
+    KV = width // hd
     g = H // KV
-    qf = q.reshape(B, 1, KV, g, hd)
+    f32 = jnp.float32
     scale = hd ** -0.5
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qf, k_cache,
-                   preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(S)
-    s = jnp.where(pos[None, None, None, None, :] < length, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(v_cache.dtype), v_cache,
-                     preferred_element_type=jnp.float32)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, 1, H, hd).astype(q.dtype)
+    eye = jnp.eye(KV, dtype=q.dtype)
+    qf = q.reshape(B, KV, g, hd)
+    q_rows = (qf[:, :, :, None, :] * eye[:, None, :, None]).reshape(
+        B, H, width)
+    s = jnp.einsum("bhc,bsc->bhs", q_rows, k_cache,
+                   preferred_element_type=f32) * scale
+    valid = jnp.arange(S) < jnp.reshape(length, (-1, 1, 1))
+    s = jnp.where(valid, s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if k_new is not None:
+        s_new = jnp.einsum("bkgd,bkd->bkg", qf, k_new.reshape(B, KV, hd),
+                           preferred_element_type=f32).reshape(B, H, 1) * scale
+        m = jnp.maximum(m, s_new)
+    p = jnp.exp(s - m)
+    total = jnp.sum(p, axis=-1, keepdims=True)
+    if k_new is not None:
+        p_new = jnp.exp(s_new - m)
+        total = total + p_new
+    rows = jnp.einsum("bhs,bsc->bhc", (p / total).astype(v_cache.dtype),
+                      v_cache, preferred_element_type=f32)
+    out = jnp.sum(rows.reshape(B, KV, g, KV, hd)
+                  * eye.astype(f32)[:, None, :, None], axis=3)
+    if v_new is not None:
+        out = out + (p_new / total).reshape(B, KV, g, 1) \
+            * v_new.reshape(B, KV, 1, hd).astype(f32)
+    return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
 def sp_flash_attention(q, k, v, dist, *, causal: bool,

@@ -19,9 +19,10 @@ from repro.models.attention import (decode_attention, flash_attention_ref,
                                     repeat_kv)
 from repro.models.layers import (ParamDef, chunked_xent, embed_tokens,
                                  last_token_logits, sinusoid_positions)
-from repro.models.transformer import (_cache_dtype, attn_param_defs, mlp_param_defs,
+from repro.models.transformer import (_cache_dtype, _cache_rows,
+                                      attn_param_defs, mlp_param_defs,
                                       norm_apply, norm_param_defs, _remat,
-                                      _heads_axis, _opt, cache_update)
+                                      _heads_axis, _opt)
 
 
 def encdec_param_defs(cfg: ArchConfig, dist: Dist) -> dict:
@@ -173,6 +174,12 @@ def encdec_prefill(params, batch, cfg: ArchConfig, dist: Dist, opts=None):
     return logits, cache
 
 
+def cache_update(cache, new, pos):
+    """Write new (B,1,KV,hd) at position pos along seq dim."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        cache, new.astype(cache.dtype), pos, axis=1)
+
+
 def encdec_decode(params, cache, batch, cfg: ArchConfig, dist: Dist,
                   opts=None):
     vs = dim_shardable(dist, cfg.vocab_size, "vocab")
@@ -190,11 +197,13 @@ def encdec_decode(params, cache, batch, cfg: ArchConfig, dist: Dist,
         vn = jnp.einsum("bsd,dhk->bshk", x, bp["self_attn"]["wv"])
         kc = cache_update(kc, kn, pos)
         vc = cache_update(vc, vn, pos)
-        a = decode_attention(q, kc, vc, pos + 1)
+        a = decode_attention(q, _cache_rows(kc, cfg), _cache_rows(vc, cfg),
+                             pos + 1)
         hh = hh + jnp.einsum("bshk,hkd->bsd", a, bp["self_attn"]["wo"])
         x = norm_apply(hh, bp["ln2"], cfg)
         q = jnp.einsum("bsd,dhk->bshk", x, bp["cross_attn"]["wq"])
-        a = decode_attention(q, ck, cv, ck.shape[1])
+        a = decode_attention(q, _cache_rows(ck, cfg), _cache_rows(cv, cfg),
+                             ck.shape[1])
         hh = hh + jnp.einsum("bshk,hkd->bsd", a, bp["cross_attn"]["wo"])
         x = norm_apply(hh, bp["ln3"], cfg)
         m = bp["mlp"]

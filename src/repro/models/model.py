@@ -151,11 +151,14 @@ class Model:
                 "conv": jax.ShapeDtypeStruct(
                     (L, B, k - 1, d_in + 2 * gn), jnp.dtype(c.dtype)),
                 "pos": pos}
+        # decoder KV caches: one row per position, the KV heads side by
+        # side (attention reads the rows as stored; models/attention.py)
+        rows = (B, S, c.n_kv_heads * c.hd)
         if c.family == "hybrid":
             per = c.attn_period
             np_ = c.n_layers // per
             d_in, nheads, gn, k = mam.mamba_dims(c)
-            kv = (np_, B, S, c.n_kv_heads, c.hd)
+            kv = (np_,) + rows
             return {
                 "k": jax.ShapeDtypeStruct(kv, bf16),
                 "v": jax.ShapeDtypeStruct(kv, bf16),
@@ -166,8 +169,7 @@ class Model:
                     (np_, per - 1, B, k - 1, d_in + 2 * gn),
                     jnp.dtype(c.dtype)),
                 "pos": pos}
-        L = c.n_layers
-        kv = (L, B, S, c.n_kv_heads, c.hd)
+        kv = (c.n_layers,) + rows
         return {"k": jax.ShapeDtypeStruct(kv, bf16),
                 "v": jax.ShapeDtypeStruct(kv, bf16),
                 "pos": pos}
@@ -191,9 +193,9 @@ class Model:
             if key == "pos":
                 out[key] = P()
             elif key in ("k", "v", "cross_k", "cross_v"):
-                nd = spec.ndim
-                # (L, B, S, KV, hd)
-                out[key] = P(None, bt, seq_ax, None, None)
+                # (L, B, S, KV*hd), or (L, B, S, KV, hd) for encdec
+                out[key] = P(None, bt, seq_ax,
+                             *(None,) * (spec.ndim - 3))
             elif key == "ssm":
                 lead = (None,) * (spec.ndim - 4)
                 out[key] = P(*lead, bt, heads_ax, None, None)

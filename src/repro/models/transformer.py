@@ -209,8 +209,7 @@ def attn_train(h, p, cfg: ArchConfig, dist: Dist, positions, opts,
                                  k_chunk=_opt(opts, "k_chunk"))
         out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
         out = dist.constrain(out, P(bt, "model", None))
-        cd = _cache_dtype(cfg)
-        return out, (k.astype(cd), v.astype(cd))
+        return out, (_cache_rows(k, cfg), _cache_rows(v, cfg))
     if dist.has_mesh:
         q = dist.constrain(q, P(bt, None, ha, None))
         k = dist.constrain(k, P(bt, None, None, None))
@@ -226,30 +225,47 @@ def attn_train(h, p, cfg: ArchConfig, dist: Dist, positions, opts,
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     if dist.has_mesh:
         out = dist.constrain(out, P(bt, None, None))
-    cd = _cache_dtype(cfg)
-    return out, (k.astype(cd), v.astype(cd))
+    return out, (_cache_rows(k, cfg), _cache_rows(v, cfg))
 
 
-def cache_update(cache, new, pos):
-    """Write new (B,1,KV,hd) at position pos along seq dim."""
-    return jax.lax.dynamic_update_slice_in_dim(
-        cache, new.astype(cache.dtype), pos, axis=1)
+def _cache_rows(x, cfg: ArchConfig):
+    """(B,S,KV,hd) -> the cache's rows (B,S,KV*hd) in the cache dtype."""
+    return x.reshape(x.shape[:2] + (-1,)).astype(_cache_dtype(cfg))
+
+
+def write_rows(cache, rows, pos):
+    """Write one new row per layer and batch row into the cache, in place.
+
+    cache (L,B,S,W); rows (L,B,W); pos: the write position, a scalar or
+    one per batch row. Each write is a dynamic_update_slice of the donated
+    buffer, so nothing but the rows moves."""
+    rows = rows.astype(cache.dtype)[:, :, None]
+    if jnp.ndim(pos) == 0:
+        return jax.lax.dynamic_update_slice(cache, rows, (0, 0, pos, 0))
+    for b in range(cache.shape[1]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[:, b:b + 1], (0, b, pos[b], 0))
+    return cache
 
 
 def attn_decode(h, p, cfg: ArchConfig, dist: Dist, pos, kc, vc):
-    """h (B,1,D); kc/vc (B,S,KV,hd). Returns (out, kc, vc)."""
+    """h (B,1,D); kc/vc (B,S,KV*hd), read only; pos: the token's position,
+    a scalar or one per batch row. Returns (out, k_row, v_row): the
+    token's rows (B,KV*hd) in the cache dtype, for the caller to write at
+    pos."""
     bsz = h.shape[0]
-    positions = jnp.full((bsz, 1), pos, jnp.int32)
+    positions = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)),
+                                 (bsz, 1)).astype(jnp.int32)
     if cfg.mrope:
         positions = jnp.broadcast_to(positions, (3,) + positions.shape)
     q, k, v = _qkv(h, p, cfg, dist, positions)
-    kc = cache_update(kc, k, pos)
-    vc = cache_update(vc, v, pos)
-    out = decode_attention(q, kc, vc, pos + 1)
+    # rounded to the cache dtype first: the numbers later steps read back
+    k, v = _cache_rows(k, cfg), _cache_rows(v, cfg)
+    out = decode_attention(q, kc, vc, pos, k, v)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     if dist.has_mesh:
         out = dist.constrain(out, P(dist.batch_axes, None, None))
-    return out, kc, vc
+    return out, k[:, 0], v[:, 0]
 
 
 def ffn_apply(h, bp, cfg: ArchConfig, dist: Dist):
@@ -352,15 +368,18 @@ def lm_prefill(params, batch, cfg: ArchConfig, dist: Dist, opts=None):
         cache = {"ssm": caches[0], "conv": caches[1],
                  "pos": jnp.int32(seq)}
     else:
-        k, v = caches                     # (L,B,S,KV,hd)
+        k, v = caches                     # (L,B,S,KV*hd)
         cache = {"k": k, "v": v, "pos": jnp.int32(seq)}
     return logits, cache
 
 
 def lm_decode(params, cache, batch, cfg: ArchConfig, dist: Dist, opts=None):
-    """One decode step. batch: token (B,1) [or embeds], optional positions.
+    """One decode step. batch: token (B,1) [or embeds]. cache["pos"] is the
+    write position: a scalar shared by the batch, or one per batch row.
 
-    Returns (logits (B,1,V), new cache)."""
+    The KV cache goes through the layer scan read-only; each layer returns
+    only its new rows, written in place after the scan. Returns
+    (logits (B,1,V), new cache)."""
     if cfg.family == "hybrid":
         return _hybrid_decode(params, cache, batch, cfg, dist, opts)
     if "embeds" in batch:
@@ -384,14 +403,15 @@ def lm_decode(params, cache, batch, cfg: ArchConfig, dist: Dist, opts=None):
         def body(hh, xs):
             bp, kc, vc = xs
             x = norm_apply(hh, bp["ln1"], cfg)
-            a, kc, vc = attn_decode(x, bp["attn"], cfg, dist, pos, kc, vc)
+            a, k, v = attn_decode(x, bp["attn"], cfg, dist, pos, kc, vc)
             hh = hh + a
             x = norm_apply(hh, bp["ln2"], cfg)
             hh = hh + ffn_apply(x, bp, cfg, dist)
-            return hh, (kc, vc)
+            return hh, (k, v)
         h, (k, v) = jax.lax.scan(
             body, h, (params["blocks"], cache["k"], cache["v"]))
-        new_cache = {"k": k, "v": v, "pos": pos + 1}
+        new_cache = {"k": write_rows(cache["k"], k, pos),
+                     "v": write_rows(cache["v"], v, pos), "pos": pos + 1}
 
     h = norm_apply(h, params["final_norm"], cfg)
     vs = dim_shardable(dist, cfg.vocab_size, "vocab")
@@ -493,7 +513,7 @@ def _hybrid_decode(params, cache, batch, cfg, dist, opts):
             return hh + gated_mlp(x, sub["wg"], sub["wu"], sub["wd"], dist)
 
         x = norm_apply(hh, bp["attn_ln"], cfg)
-        a, kc, vc = attn_decode(x, bp["attn"], cfg, dist, pos, kc, vc)
+        a, k, v = attn_decode(x, bp["attn"], cfg, dist, pos, kc, vc)
         hh = ffn_at(hh + a, 0)
         new_states, new_tails = [], []
         for j in range(1, per):
@@ -505,11 +525,13 @@ def _hybrid_decode(params, cache, batch, cfg, dist, opts):
             hh = ffn_at(hh + out, j)
             new_states.append(st)
             new_tails.append(tl)
-        return hh, (kc, vc, jnp.stack(new_states), jnp.stack(new_tails))
+        return hh, (k, v, jnp.stack(new_states), jnp.stack(new_tails))
 
     h, (k, v, ssm, conv) = jax.lax.scan(
         body, h, (params["blocks"], cache["k"], cache["v"],
                   cache["ssm"], cache["conv"]))
     h = norm_apply(h, params["final_norm"], cfg)
     logits = last_token_logits(h, params["head"], dist, vs)
-    return logits, {"k": k, "v": v, "ssm": ssm, "conv": conv, "pos": pos + 1}
+    return logits, {"k": write_rows(cache["k"], k, pos),
+                    "v": write_rows(cache["v"], v, pos),
+                    "ssm": ssm, "conv": conv, "pos": pos + 1}

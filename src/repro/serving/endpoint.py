@@ -216,6 +216,7 @@ class ModelEndpoint:
             "max_queue": self.engine.max_queue,
             "stats": (self.stats_final if self.stats_final is not None
                       else self.engine.stats()),
-            # roofline estimate of the decode step + live measured rate
-            "perf": self.engine.perf.snapshot(self.engine.decode_rate()),
+            # roofline estimate of the decode step + live measured rate,
+            # and whether the compiled step updates its cache in place
+            "perf": self.engine.perf_status(),
         }

@@ -14,16 +14,20 @@ The engine promotes the serving pattern that used to live in
     full (REST maps ``QueueFull`` to HTTP 429) and every request may
     carry a deadline, enforced both while queued and while decoding.
 
-Decode is ``jit(vmap(model.decode))`` over the slot axis: each slot is
+Decode is ``jit(model.decode)`` over the whole slot-batched cache, each
+row masked and positioned at its own slot's ``pos``: each slot is
 mathematically an independent batch-1 decode, which is what makes a
 mid-flight join token-identical to running the request alone
-(tests/test_serving.py asserts exactly that). Greedy (argmax) sampling
-keeps the engine deterministic.
+(tests/test_serving.py asserts exactly that). The step reads the cache
+once and writes only each slot's new KV rows, in place in the donated
+buffer; ``stats()`` reports whether the compiled program did so. Greedy
+(argmax) sampling keeps the engine deterministic.
 """
 from __future__ import annotations
 
 import collections
 import logging
+import re
 import threading
 import time
 import uuid
@@ -116,6 +120,19 @@ class InferenceRequest:
         return self.first_token_ts - self.submitted
 
 
+def decode_in_place(compiled, cache) -> tuple:
+    """(temporary bytes, every cache leaf updated in place) of a compiled
+    decode program ``(params, cache, tokens) -> (logits, cache)``: the
+    cache leaves are outputs 1..n, each in place where XLA aliased it to
+    its donated input."""
+    text = compiled.as_text()
+    header = text[:text.find("\n")]
+    aliased = {int(i) for i in re.findall(r"\{(\d+)\}: \(\d+, \{\}", header)}
+    n = len(jax.tree.leaves(cache))
+    return (int(compiled.memory_analysis().temp_size_in_bytes),
+            set(range(1, n + 1)) <= aliased)
+
+
 def _named(fn, name: str):
     """``fn`` under ``name``, which jit gives its program (jit_<name>)
     whatever the function is called."""
@@ -188,6 +205,11 @@ class InferenceEngine:
         self._decode_steps = 0
         self._occupied_slot_steps = 0
         self._decode_s = 0.0        # host time inside decode steps
+        # from the decode program's background compile (status.perf):
+        # its temporary bytes, and whether every cache leaf is updated in
+        # place (the donated input aliased to the output)
+        self._decode_temp_bytes: Optional[int] = None
+        self._decode_cache_aliased: Optional[bool] = None
         # roofline estimate of the decode step (status.perf), analyzed
         # in the background once the jits are built
         from repro.analysis.perf import JobPerf
@@ -236,23 +258,7 @@ class InferenceEngine:
         else:
             self.params = self.model.init(jax.random.PRNGKey(self.seed))
         self._prefill = jax.jit(_named(self.model.prefill, PREFILL_PROGRAM))
-
-        def decode_one(params, cache, tok):
-            # vmap strips the slot axis; model.decode wants batch dim 1
-            cache = {k: (v if k == "pos"
-                         else jnp.expand_dims(v, self._axes[k]))
-                     for k, v in cache.items()}
-            logits, new = self.model.decode(params, cache,
-                                            {"tokens": tok})
-            new = {k: (v if k == "pos"
-                       else jnp.squeeze(v, self._axes[k]))
-                   for k, v in new.items()}
-            return logits, new
-
-        self._decode = jax.jit(
-            _named(jax.vmap(decode_one, in_axes=(None, self._axes, 0),
-                            out_axes=(0, self._axes)), DECODE_PROGRAM),
-            donate_argnums=(1,))
+        self._decode = self.decode_program()
         self._splice = jax.jit(self._splice_fn, donate_argnums=(0,))
         with self._lock:
             self._cache = self._empty_cache()
@@ -262,11 +268,36 @@ class InferenceEngine:
         # decode step; ShapeDtypeStructs stay valid), lower lazily
         sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
         p0 = jax.tree.map(sds, self.params)
-        c0 = jax.tree.map(sds, self._cache)
-        t0 = jax.ShapeDtypeStruct((self.capacity, 1, 1), jnp.int32)
+        c0 = self.cache_shapes()
+        t0 = jax.ShapeDtypeStruct((self.capacity, 1), jnp.int32)
         dec = self._decode
-        self.perf.start_async(
-            lambda: dec.lower(p0, c0, t0).compile().as_text())
+
+        def analyze():
+            compiled = dec.lower(p0, c0, t0).compile()
+            temp, aliased = decode_in_place(compiled, c0)
+            with self._lock:
+                self._decode_temp_bytes = temp
+                self._decode_cache_aliased = aliased
+            return compiled.as_text()
+
+        self.perf.start_async(analyze)
+
+    def decode_program(self):
+        """The jitted decode step ``(params, cache, tokens (capacity, 1)) ->
+        (logits, cache)``: one token for every slot, the slot cache
+        donated and updated in place."""
+        def step(params, cache, tokens):
+            return self.model.decode(params, cache, {"tokens": tokens})
+        return jax.jit(_named(step, DECODE_PROGRAM), donate_argnums=(1,))
+
+    def cache_shapes(self) -> Dict[str, jax.ShapeDtypeStruct]:
+        """Shapes of the slot cache: the model's cache at capacity x
+        max_seq, with one write position per slot."""
+        out = dict(self.model.cache_specs(self.capacity, self.max_seq))
+        # per-slot write position (the training decode shares one
+        # scalar; serving slots run at different depths)
+        out["pos"] = jax.ShapeDtypeStruct((self.capacity,), jnp.int32)
+        return out
 
     @property
     def ready(self) -> bool:
@@ -416,8 +447,8 @@ class InferenceEngine:
 
     # ---- internals --------------------------------------------------------
     def _cache_axes(self) -> Dict[str, int]:
-        """Slot (batch) axis per cache leaf — the vmap/in-place-update
-        axis map. Derived from the family cache layouts in
+        """Slot (batch) axis per cache leaf, where a prefilled request's
+        cache is spliced in. Derived from the family cache layouts in
         models/model.py:cache_specs."""
         axes = {}
         for k, v in self.model.cache_specs(1, 8).items():
@@ -434,16 +465,8 @@ class InferenceEngine:
         return axes
 
     def _empty_cache(self):
-        out = {}
-        for k, s in self.model.cache_specs(self.capacity,
-                                           self.max_seq).items():
-            if k == "pos":
-                # per-slot write position (the training decode shares
-                # one scalar; serving slots run at different depths)
-                out[k] = jnp.zeros((self.capacity,), jnp.int32)
-            else:
-                out[k] = jnp.zeros(s.shape, s.dtype)
-        return out
+        return {k: jnp.zeros(s.shape, s.dtype)
+                for k, s in self.cache_shapes().items()}
 
     def _splice_fn(self, cache, one, slot):
         """Write one prefilled request cache (batch dim 1, seq padded to
@@ -529,10 +552,10 @@ class InferenceEngine:
     def _decode_once(self) -> int:
         with TraceAnnotation("serve.dispatch"):
             t0 = time.perf_counter()
-            toks = jnp.asarray(self._next_tok.reshape(self.capacity, 1, 1))
+            toks = jnp.asarray(self._next_tok.reshape(self.capacity, 1))
             logits, self._cache = self._decode(self.params, self._cache,
                                                toks)
-            nxt = jnp.argmax(logits[:, 0, -1, :], axis=-1)
+            nxt = jnp.argmax(logits[:, -1, :], axis=-1)
         with TraceAnnotation("serve.read"):
             nxt = np.asarray(nxt).astype(np.int32)
             step_s = time.perf_counter() - t0
@@ -675,6 +698,14 @@ class InferenceEngine:
             steps, secs = self._decode_steps, self._decode_s
         return steps / secs if steps and secs > 0 else None
 
+    def perf_status(self) -> Dict:
+        """``status.perf``: the decode step's roofline estimate folded
+        with the measured rate, and its in-place figures."""
+        with self._lock:
+            mem = {"decode_temp_bytes": self._decode_temp_bytes,
+                   "decode_cache_aliased": self._decode_cache_aliased}
+        return dict(self.perf.snapshot(self.decode_rate()), **mem)
+
     def stats(self) -> Dict:
         """Counters + latency percentiles + occupancy — what endpoint
         status exposes and the serving benchmark samples."""
@@ -699,6 +730,8 @@ class InferenceEngine:
                 "occupied_slot_steps": occ,
                 "mean_batch_occupancy": round(
                     occ / (steps * self.capacity), 4) if steps else 0.0,
+                "decode_temp_bytes": self._decode_temp_bytes,
+                "decode_cache_aliased": self._decode_cache_aliased,
             }
         if self.metrics is not None:
             p50 = self.metrics.percentile(self.endpoint_id, "latency_s", 50)

@@ -1,8 +1,12 @@
-"""The main path's Pallas kernels compiled at real widths for a described
-TPU v5e (no chip needed: the TPU compiler runs here against a topology
-description). Each test asserts the Mosaic kernel is in the compiled
-program. The topology is described inside a fixture, never at import,
-so every test worker collects the same tests."""
+"""The main path's Pallas kernels and serving decode step compiled at
+real widths for a described TPU v5e (no chip needed: the TPU compiler
+runs here against a topology description). The kernel tests assert the
+Mosaic kernel is in the compiled program; the decode step's test, that
+it updates its cache in place. The topology is described inside a
+fixture, never at import, so every test worker collects the same
+tests."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +22,7 @@ from repro.kernels.quantize import dequantize, quantize_ef
 from repro.kernels.ssd_scan import ssd_scan_fwd
 from repro.models import make_model
 from repro.service.manifest import DEFAULT_PS_SHARDS
+from repro.serving.engine import InferenceEngine, decode_in_place
 
 N_LEARNERS = 2
 
@@ -38,23 +43,26 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def compile_tpu(one_chip):
-    """``compile_tpu(fn, *shapes)`` -> compiled text, with the persistent
-    cache off: a program compiled for a described chip is written to the
-    cache but cannot be read back without one."""
+def no_persistent_cache():
+    """The persistent cache off: a program compiled for a described chip
+    is written to the cache but cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
+
+@pytest.fixture
+def compile_tpu(one_chip, no_persistent_cache):
+    """``compile_tpu(fn, *shapes)`` -> compiled text."""
     def run(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in shapes]
         return jax.jit(fn).lower(*args).compile().as_text()
-
-    yield run
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    return run
 
 
 def _flat_size(arch):
@@ -118,3 +126,51 @@ def test_ssd_scan_compiles_at_mamba2_widths(compile_tpu):
         ((bh, s, ssm.d_state), jnp.float32),
         ((bh, s, ssm.d_state), jnp.float32))
     assert "tpu_custom_call" in txt
+
+
+def _materialized(hlo_text):
+    """(opcode, dtype, dims without unit dims) of every op outside fused
+    computations: the buffers the program writes."""
+    fused = set(re.findall(r"calls=%([\w.-]+)", hlo_text))
+    ops, comp = [], None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        op = re.match(r"\s+(?:ROOT )?%[\w.-]+ = (\w+)\[([\d,]*)\]\S* "
+                      r"([\w-]+)\(", line)
+        if op and comp not in fused:
+            dims = tuple(int(d) for d in op.group(2).split(",")
+                         if d and int(d) != 1)
+            ops.append((op.group(3), op.group(1), dims))
+    return ops
+
+
+def test_serve_decode_updates_the_kv_cache_in_place(one_chip,
+                                                    no_persistent_cache):
+    """The engine's own decode program at stablelm-1.6b's published widths
+    (8 slots of 1280 positions): a few MB of temporaries, every cache
+    leaf aliased to its donated input, and nothing written in the shape
+    of a whole cache leaf or of one layer's slab of it but the in-place
+    row writes (dynamic-update-slice)."""
+    eng = InferenceEngine(resolve_arch("stablelm-1.6b"), capacity=8,
+                          max_seq=1280)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    cache = on_chip(eng.cache_shapes())
+    compiled = eng.decode_program().lower(
+        on_chip(eng.model.abstract_params()), cache,
+        jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)).compile()
+    temp, aliased = decode_in_place(compiled, cache)
+    assert temp < 64 * 2 ** 20, temp
+    assert aliased
+    leaf = tuple(d for d in cache["k"].shape if d != 1)
+    shapes = {leaf, leaf[1:]}
+    cache_sized = [op for op, dt, dims in _materialized(compiled.as_text())
+                   if dt == "bf16" and dims in shapes
+                   and op not in ("parameter", "get-tuple-element", "tuple",
+                                  "bitcast", "while")]
+    assert cache_sized and set(cache_sized) == {"dynamic-update-slice"}, \
+        cache_sized

@@ -45,16 +45,18 @@ def _serve(eng, reqs, timeout=180.0):
 
 
 def _sequential_reference(model, params, prompt, max_new):
-    """Greedy B=1 decode with the plain (non-vmapped) model functions —
-    the oracle a mid-flight-joined request must match token for token."""
+    """Greedy B=1 decode with the plain model functions and a scalar
+    position — the oracle a mid-flight-joined request must match token
+    for token."""
     prefill = jax.jit(model.prefill)
     decode = jax.jit(model.decode)
     logits, cache = prefill(params, {"tokens": jnp.asarray(prompt)[None]})
-    cache = dict(cache)
-    for k in ("k", "v"):
-        pads = [(0, 0)] * cache[k].ndim
-        pads[2] = (0, MAX_SEQ - cache[k].shape[2])
-        cache[k] = jnp.pad(cache[k], pads)
+    # each leaf padded out to the shape the cache specs give it at
+    # MAX_SEQ: a KV cache along its sequence axis, a state not at all
+    specs = model.cache_specs(1, MAX_SEQ)
+    cache = {k: v if k == "pos" else jnp.pad(
+        v, [(0, want - have) for have, want in zip(v.shape, specs[k].shape)])
+        for k, v in cache.items()}
     toks = [int(jnp.argmax(logits[0, -1]))]
     while len(toks) < max_new:
         logits, cache = decode(
@@ -69,10 +71,15 @@ def _sequential_reference(model, params, prompt, max_new):
 # ---------------------------------------------------------------------------
 
 
-def test_midflight_join_token_identical(cfg, engine):
+@pytest.mark.parametrize("arch", [ARCH, "mamba2-1.3b-smoke"])
+def test_midflight_join_token_identical(arch):
     """5 requests over 2 slots with staggered lengths: 3 of them join
     mid-flight into freed slots. Every output must be token-identical
     to decoding that request alone (same seed, greedy)."""
+    cfg = resolve_arch(arch)
+    engine = InferenceEngine(cfg, capacity=2, max_seq=MAX_SEQ,
+                             default_max_new=6, endpoint_id="ep-join")
+    engine.start(None)
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, cfg.vocab_size, size=8).astype(np.int32)
                for _ in range(5)]
@@ -88,6 +95,51 @@ def test_midflight_join_token_identical(cfg, engine):
         assert len(r.tokens) == m
         ref = _sequential_reference(engine.model, engine.params, p, m)
         assert r.tokens == ref, (r.tokens, ref)
+
+
+def test_decode_step_writes_only_each_slots_new_row(cfg):
+    """One decode step over slots at different depths changes, in every
+    layer, exactly row pos[b] of slot b's K and V, live or free, and bit
+    for bit nothing else; each live slot's token is the oracle's."""
+    eng = InferenceEngine(cfg, capacity=3, max_seq=MAX_SEQ,
+                          endpoint_id="ep-rows")
+    eng.start(None)
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (5, 11)]
+    reqs = [eng.submit(p, max_new=4) for p in prompts]
+    while (batch := eng._take_batch()) is not None:   # slot 2 stays free
+        eng._admit(*batch)
+    before = jax.tree.map(np.asarray, eng._cache)
+    pos = before["pos"]
+    assert list(pos) == [5, 11, 0]
+    eng._decode_once()
+    after = jax.tree.map(np.asarray, eng._cache)
+    np.testing.assert_array_equal(after["pos"], pos + 1)
+    for key in ("k", "v"):
+        changed = np.any(before[key] != after[key], axis=-1)  # (L, slot, S)
+        want = np.zeros_like(changed)
+        want[:, np.arange(eng.capacity), pos] = True
+        np.testing.assert_array_equal(changed, want)
+    for p, r in zip(prompts, reqs):
+        assert r.tokens == _sequential_reference(eng.model, eng.params, p, 2)
+
+
+def test_stats_say_the_decode_step_runs_in_place(cfg):
+    """stats() and status.perf carry the decode program's temporary bytes
+    and whether every cache leaf is aliased to its donated input, from
+    the background compile that start() schedules."""
+    eng = InferenceEngine(cfg, capacity=2, max_seq=MAX_SEQ,
+                          endpoint_id="ep-inplace")
+    assert eng.stats()["decode_cache_aliased"] is None
+    eng.start(None)
+    assert wait_until(lambda: eng.perf.state == "ready", timeout=120), \
+        eng.perf.error
+    st, perf = eng.stats(), eng.perf_status()
+    assert st["decode_cache_aliased"] is True
+    assert 0 < st["decode_temp_bytes"] < 2 ** 20
+    assert perf["decode_cache_aliased"] is True
+    assert perf["decode_temp_bytes"] == st["decode_temp_bytes"]
 
 
 def test_eos_retires_early(cfg):
@@ -181,7 +233,7 @@ def test_program_names(cfg, engine):
     trace readers find decode and prefill after any refactor."""
     from repro.serving.engine import DECODE_PROGRAM, PREFILL_PROGRAM
     assert "decode" in DECODE_PROGRAM and "prefill" in PREFILL_PROGRAM
-    toks = jnp.zeros((engine.capacity, 1, 1), jnp.int32)
+    toks = jnp.zeros((engine.capacity, 1), jnp.int32)
     text = engine._decode.lower(engine.params, engine._cache, toks).as_text()
     assert f"module @jit_{DECODE_PROGRAM} " in text
     text = engine._prefill.lower(
