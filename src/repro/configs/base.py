@@ -24,6 +24,9 @@ class MoEConfig:
     capacity_factor: float = 1.25
     # Which FFN sites are MoE. 'all' = every layer, 'alternate' = every other.
     layout: str = "all"
+    # width of a shared SwiGLU expert that every token passes beside its
+    # routed experts (0: none)
+    d_ff_shared: int = 0
 
 
 @dataclass(frozen=True)
@@ -53,8 +56,24 @@ class ArchConfig:
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
-    # hybrid: one attention layer every `attn_period` layers (rest mamba).
+    # hybrid: one attention layer every `attn_period` layers (rest mamba),
+    # at index `attn_index` of each period.
     attn_period: int = 0
+    attn_index: int = 0
+    # NoPE: attention without position embedding (no RoPE on q/k)
+    nope: bool = False
+    # attention score scale; 0 -> head_dim ** -0.5
+    attn_scale: float = 0.0
+    # scalar multipliers: on the token embeddings, on each sublayer's
+    # output before it joins the residual, and the divisor of the logits
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # output head tied to the embedding table (no head of its own)
+    tie_embeddings: bool = False
+    # chips that share each expert layer: this one holds experts
+    # [0, n_experts / expert_shards) and computes only their part
+    expert_shards: int = 1
     # enc-dec (whisper): encoder layers == n_layers, decoder layers too.
     encdec: bool = False
     # modality frontend stub: none | audio | vision. Stub frontends mean
@@ -74,6 +93,20 @@ class ArchConfig:
     @property
     def is_moe(self) -> bool:
         return self.moe is not None
+
+    @property
+    def held_experts(self) -> int:
+        """Experts of each MoE layer held on this chip."""
+        return self.moe.n_experts // self.expert_shards
+
+    def is_attn_layer(self, i: int) -> bool:
+        """Hybrid: whether layer ``i`` is attention (the rest are mamba)."""
+        return i % self.attn_period == self.attn_index
+
+    def is_moe_layer(self, i: int) -> bool:
+        return self.moe is not None and (
+            self.moe.layout == "all"
+            or (self.moe.layout == "alternate" and i % 2 == 1))
 
     def n_params(self) -> int:
         """Total parameter count (embedding + blocks + head)."""
@@ -97,7 +130,8 @@ def _ffn_params(c: ArchConfig, moe_site: bool, active_only: bool) -> int:
     if moe_site and c.moe is not None:
         e = c.moe.top_k if active_only else c.moe.n_experts
         router = c.d_model * c.moe.n_experts
-        return e * 3 * c.d_model * c.moe.d_ff_expert + router
+        shared = 3 * c.d_model * c.moe.d_ff_shared
+        return e * 3 * c.d_model * c.moe.d_ff_expert + router + shared
     return 3 * c.d_model * c.d_ff  # gated MLP (w_gate, w_up, w_down)
 
 
@@ -116,7 +150,7 @@ def _mamba_params(c: ArchConfig) -> int:
 
 def _count_params(c: ArchConfig, active_only: bool) -> int:
     emb = c.vocab_size * c.d_model
-    head = c.vocab_size * c.d_model  # untied output head
+    head = 0 if c.tie_embeddings else c.vocab_size * c.d_model
     total = emb + head
     n_layers = c.n_layers
     if c.encdec:
@@ -131,13 +165,13 @@ def _count_params(c: ArchConfig, active_only: bool) -> int:
         if c.family == "ssm":
             total += _mamba_params(c) + 2 * c.d_model
             continue
-        if c.family == "hybrid" and c.attn_period and (i % c.attn_period != 0):
+        if (c.family == "hybrid" and c.attn_period
+                and not c.is_attn_layer(i)):
             mixer = _mamba_params(c)
         else:
             mixer = _attn_params(c)
-        moe_site = c.moe is not None and (
-            c.moe.layout == "all" or (c.moe.layout == "alternate" and i % 2 == 1))
-        total += mixer + _ffn_params(c, moe_site, active_only) + 2 * c.d_model
+        total += (mixer + _ffn_params(c, c.is_moe_layer(i), active_only)
+                  + 2 * c.d_model)
     return total
 
 
@@ -191,7 +225,8 @@ def reduce_for_smoke(c: ArchConfig) -> ArchConfig:
     if c.moe is not None:
         moe = MoEConfig(n_experts=4, top_k=min(2, c.moe.top_k),
                         d_ff_expert=64, capacity_factor=c.moe.capacity_factor,
-                        layout=c.moe.layout)
+                        layout=c.moe.layout,
+                        d_ff_shared=64 if c.moe.d_ff_shared else 0)
     ssm = None
     if c.ssm is not None:
         ssm = SSMConfig(d_state=16, head_dim=16, expand=2, chunk_size=32,

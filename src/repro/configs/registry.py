@@ -12,13 +12,13 @@ from repro.configs.base import ArchConfig, reduce_for_smoke
 from repro.configs import (
     kimi_k2_1t_a32b, grok_1_314b, stablelm_1_6b, minitron_8b, qwen1_5_110b,
     granite_20b, mamba2_1_3b, whisper_large_v3, jamba_1_5_large_398b,
-    qwen2_vl_2b,
+    qwen2_vl_2b, granite_4_0_h_small,
 )
 
 _MODULES = (
     kimi_k2_1t_a32b, grok_1_314b, stablelm_1_6b, minitron_8b, qwen1_5_110b,
     granite_20b, mamba2_1_3b, whisper_large_v3, jamba_1_5_large_398b,
-    qwen2_vl_2b,
+    qwen2_vl_2b, granite_4_0_h_small,
 )
 
 REGISTRY: Dict[str, ArchConfig] = {m.CONFIG.arch_id: m.CONFIG for m in _MODULES}

@@ -17,6 +17,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm as _gmm
 
 from repro.kernels import autotune
 from repro.kernels import flash_attention as _fa
@@ -58,6 +59,25 @@ def flash_attention(q, k, v, *, causal: bool = True,
         block_k = block_k or tk
     return _flash_attention_jit(q, k, v, causal=causal,
                                 block_q=block_q, block_k=block_k)
+
+
+def _block(size: int, most: int) -> int:
+    """``most`` where it tiles ``size`` evenly, else the whole of it."""
+    return most if size % most == 0 else size
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """lhs (m, k), rhs (G, k, n), group_sizes (G,) -> (m, n) in lhs's
+    dtype: each group's rows, in order, times its own rhs[g], as
+    ``jax.lax.ragged_dot`` computes them, but reading only the weights of
+    groups that have rows (megablox's grouped matmul, every row in one
+    m-tile: meant for the few rows of a decode step). Rows past the
+    groups are left unwritten: the caller masks them."""
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    return _gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+                tiling=(m, _block(k, 2048), _block(n, 2048)),
+                interpret=not _on_tpu())
 
 
 @partial(jax.jit, static_argnames=("chunk",))

@@ -63,9 +63,14 @@ class Model:
         return tf.lm_prefill(params, batch, self.cfg, self.dist, self.opts)
 
     def decode(self, params, cache, batch):
+        return self.decode_counted(params, cache, batch)[:2]
+
+    def decode_counted(self, params, cache, batch):
+        """``decode`` and the step's MoE counts: (rows computed, held
+        experts touched) summed over layers, or None without MoE."""
         if self.cfg.family == "encdec":
             return ed.encdec_decode(params, cache, batch, self.cfg,
-                                    self.dist, self.opts)
+                                    self.dist, self.opts) + (None,)
         return tf.lm_decode(params, cache, batch, self.cfg, self.dist,
                             self.opts)
 

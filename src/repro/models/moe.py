@@ -1,6 +1,15 @@
 """Mixture-of-Experts with sort-based expert-parallel dispatch.
 
-TPU adaptation notes (see DESIGN.md §5):
+One chip (no expert mesh axis): dropless. The router scores every expert;
+the (token, expert) rows whose expert this chip holds (the first
+``n_experts / expert_shards``) are sorted by expert and computed as one
+grouped product over the held experts' weights (``ragged_dot``; in a
+decode step the grouped matmul kernel, which reads only the experts with
+rows), with no capacity: nothing is dropped however uneven the routing.
+Rows routed to experts held elsewhere are left out: with
+``expert_shards`` > 1 the layer returns this chip's part of the result.
+
+TPU adaptation notes for the mesh paths (see DESIGN.md §5):
   * experts are sharded over the "model" mesh axis (EP). When the expert
     count is below the axis size, experts are *replicated* R = axis/E times
     ("virtual experts", DeepSeek-EP style hot-expert replication); the
@@ -25,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, MoEConfig
 from repro.distributed.sharding import Dist
+from repro.kernels.ops import grouped_matmul
 from repro.models.layers import ParamDef
 
 
@@ -42,7 +52,7 @@ def replication_factor(moe: MoEConfig, dist: Dist) -> int:
 def moe_param_defs(cfg: ArchConfig, dist: Dist, scan_dims=()) -> dict:
     moe = cfg.moe
     r = replication_factor(moe, dist)
-    ev = moe.n_experts * r
+    ev = cfg.held_experts * r
     lead = tuple(scan_dims)
     ldim = tuple("layers" for _ in lead)
     d, fe = cfg.d_model, moe.d_ff_expert
@@ -112,21 +122,54 @@ def _combine(y_slots, tok_of_slot, gate_of_slot, n_tokens: int):
 
 
 # ---------------------------------------------------------------------------
-# Local (single shard) path — also the smoke/CPU path
+# Local (single shard) path — dropless; also the smoke/CPU path
 # ---------------------------------------------------------------------------
 
 
-def _moe_single(x, params, moe: MoEConfig, r: int):
+def _moe_single(x, params, moe: MoEConfig, held: int, layer=None):
+    """x (B,S,D) -> (out (B,S,D), counts (2,) int32): the rows computed
+    and the held experts that had at least one. ``layer``: see
+    ``moe_block``.
+
+    Rows are grouped by expert without a sort: each row's place is its
+    expert's offset plus its rank among that expert's rows (a running
+    count), rows for experts held elsewhere in a last group past the
+    ones computed. The grouped products read the expert weights as they
+    are stored: a stack of layers is one group list in which the other
+    layers' groups are empty, so no layer's slice is copied out."""
     b, s, d = x.shape
-    ev = moe.n_experts * r
     x2d = x.reshape(-1, d)
-    cap = _capacity(x2d.shape[0], moe.top_k, ev, moe.capacity_factor)
-    ei, gi, ti = _route(x2d, params["router"], moe, r)
-    buf, tos, gos = _fill_buffers(x2d, ei, gi, ti, ev, cap)
-    y = _expert_mlp(buf.reshape(ev, cap, d), params["wg"], params["wu"],
-                    params["wd"]).reshape(ev * cap, d)
-    out = _combine(y, tos, gos, x2d.shape[0])
-    return out.reshape(b, s, d).astype(x.dtype)
+    t, k = x2d.shape[0], moe.top_k
+    with jax.named_scope("moe.route"):
+        ei, gi, ti = _route(x2d, params["router"], moe, 1)
+        mine = ei < held
+        group = jax.nn.one_hot(jnp.minimum(ei, held), held + 1,
+                               dtype=jnp.int32)                 # (t*k, E+1)
+        sizes = jnp.sum(group, axis=0)
+        rank = jnp.sum((jnp.cumsum(group, axis=0) - group) * group, axis=1)
+        dest = (jnp.cumsum(sizes) - sizes)[jnp.minimum(ei, held)] + rank
+        src = jnp.zeros_like(ti).at[dest].set(ti)          # token of each place
+        sizes = sizes[:held]
+        groups = sizes
+        if layer is not None:
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros((params["wg"].shape[0],), jnp.int32), sizes,
+                (layer * held,))
+    with jax.named_scope("moe.experts"):
+        # a decode step's few rows touch some of the held experts: read
+        # only theirs
+        dot = grouped_matmul if s == 1 else jax.lax.ragged_dot
+        rows = x2d[src]
+        h = jax.nn.silu(dot(rows, params["wg"], groups)) \
+            * dot(rows, params["wu"], groups)
+        y = dot(h, params["wd"], groups)
+        # back in (token, choice) order; places past the groups hold rows
+        # no expert here computed
+        y = jnp.where(mine[:, None], y[dest], 0).reshape(t, k, d)
+        out = jnp.einsum("tkd,tk->td", y, gi.reshape(t, k),
+                         preferred_element_type=jnp.float32)
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return out.reshape(b, s, d).astype(x.dtype), counts
 
 
 # ---------------------------------------------------------------------------
@@ -140,12 +183,28 @@ def _gather_fsdp(w, dist_axes):
     return w
 
 
-def moe_block(x, params, cfg: ArchConfig, dist: Dist):
-    """x (B, S, D) -> (B, S, D). Chooses the dispatch strategy by shape."""
+def moe_block(x, params, cfg: ArchConfig, dist: Dist, layer=None):
+    """x (B, S, D) -> (out (B, S, D), counts (2,) int32). Chooses the
+    dispatch strategy by shape. ``counts`` (rows computed, held experts
+    with a row) are counted on one chip; the mesh paths give zeros.
+
+    params: this layer's ``router`` (D, E) and expert weights ``wg``,
+    ``wu``, ``wd`` (E_held, ...); or, with ``layer`` (an int, may be
+    traced), the expert weights of a stack of layers, flattened to
+    (layers * E_held, ...), of which this is layer ``layer``."""
     moe = cfg.moe
-    r = replication_factor(moe, dist)
     if not dist.has_mesh or dist.expert_axis is None:
-        return _moe_single(x, params, moe, r)
+        return _moe_single(x, params, moe, cfg.held_experts, layer)
+    if cfg.expert_shards != 1:
+        raise ValueError("expert_shards describes one chip's share; a mesh "
+                         "spreads the whole expert layer")
+    r = replication_factor(moe, dist)
+    if layer is not None:
+        ev = moe.n_experts * r
+        params = dict(params, **{
+            k: jax.lax.dynamic_slice_in_dim(params[k], layer * ev, ev)
+            for k in ("wg", "wu", "wd")})
+    none = jnp.zeros((2,), jnp.int32)
 
     b, s, d = x.shape
     ms = dist.model_size
@@ -194,7 +253,7 @@ def moe_block(x, params, cfg: ArchConfig, dist: Dist):
                       wspec_g, wspec_g, wspec_d),
             out_specs=P(bt, "model", None), check_vma=False)
         return fn(x, params["router"], params["wg"], params["wu"],
-                  params["wd"])
+                  params["wd"]), none
 
     # ---- decode: tokens replicated over "model", local experts + psum ----
     def body_dec(xl, rw, wg, wu, wd):
@@ -224,4 +283,5 @@ def moe_block(x, params, cfg: ArchConfig, dist: Dist):
         in_specs=(P(bt, None, None), P(None, None),
                   wspec_g, wspec_g, wspec_d),
         out_specs=P(bt, None, None), check_vma=False)
-    return fn(x, params["router"], params["wg"], params["wu"], params["wd"])
+    return fn(x, params["router"], params["wg"], params["wu"],
+              params["wd"]), none

@@ -1,13 +1,16 @@
-"""Decoder-only LM (dense / MoE / VLM) and the Jamba-style hybrid.
+"""Decoder-only LM (dense / MoE / VLM) and the hybrid (Jamba, Granite 4.0-H).
 
 All models are functional: ``*_param_defs`` build ParamDef trees (abstract,
 for dry-run + sharding), ``lm_loss`` / ``lm_prefill`` / ``lm_decode`` are
 pure functions. Layers are scanned (stacked leading dim) so HLO size is
 independent of depth; the hybrid scans over periods of ``attn_period``
-layers (1 attention + N-1 mamba, per Jamba).
+layers (1 attention at ``attn_index`` + N-1 mamba), each layer followed by
+its FFN: MoE (plus a shared expert where configured) at every layer or at
+odd ones, a gated MLP at the rest.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -81,8 +84,8 @@ def attn_param_defs(cfg: ArchConfig, scan_dims=()) -> dict:
     return defs
 
 
-def mlp_param_defs(cfg: ArchConfig, scan_dims=()) -> dict:
-    d, f = cfg.d_model, cfg.d_ff
+def mlp_param_defs(cfg: ArchConfig, scan_dims=(), d_ff: int = 0) -> dict:
+    d, f = cfg.d_model, d_ff or cfg.d_ff
     ld = tuple("layers" for _ in scan_dims)
     return {
         "wg": ParamDef(scan_dims + (d, f), ld + ("embed", "ff")),
@@ -127,24 +130,31 @@ def decoder_param_defs(cfg: ArchConfig, dist: Dist) -> dict:
 def _hybrid_param_defs(cfg: ArchConfig, dist: Dist) -> dict:
     per = cfg.attn_period                      # 8 for jamba
     np_ = cfg.n_layers // per                  # periods (9)
-    n_moe = per // 2                           # odd local indices
-    n_mlp = per - n_moe
+    n_moe = sum(cfg.is_moe_layer(j) for j in range(per))
     block = {
         "attn": attn_param_defs(cfg, (np_,)),
         "attn_ln": norm_param_defs(cfg, (np_,)),
         "mamba": mam.mamba_param_defs(cfg, (np_, per - 1)),
         "mamba_ln": norm_param_defs(cfg, (np_, per - 1)),
         "ffn_ln": norm_param_defs(cfg, (np_, per)),
-        "moe": moe_param_defs(cfg, dist, (np_, n_moe)),
-        "mlp": mlp_param_defs(cfg, (np_, n_mlp)),
     }
-    return {
+    if n_moe:
+        block["moe"] = moe_param_defs(cfg, dist, (np_, n_moe))
+        if cfg.moe.d_ff_shared:
+            block["shared"] = mlp_param_defs(cfg, (np_, n_moe),
+                                             cfg.moe.d_ff_shared)
+    if n_moe < per:
+        block["mlp"] = mlp_param_defs(cfg, (np_, per - n_moe))
+    defs = {
         "embed": ParamDef((cfg.vocab_size, cfg.d_model), ("vocab", "embed"),
                           fan_in=1),
         "blocks": block,
         "final_norm": norm_param_defs(cfg),
-        "head": ParamDef((cfg.d_model, cfg.vocab_size), ("embed", "vocab")),
     }
+    if not cfg.tie_embeddings:
+        defs["head"] = ParamDef((cfg.d_model, cfg.vocab_size),
+                                ("embed", "vocab"))
+    return defs
 
 
 # ---------------------------------------------------------------------------
@@ -184,9 +194,14 @@ def _qkv(h, p, cfg: ArchConfig, dist: Dist, positions):
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    sections = cfg.mrope_sections if cfg.mrope else None
-    q = apply_rope(q, positions, cfg.rope_theta, sections)
-    k = apply_rope(k, positions, cfg.rope_theta, sections)
+    if not cfg.nope:
+        sections = cfg.mrope_sections if cfg.mrope else None
+        q = apply_rope(q, positions, cfg.rope_theta, sections)
+        k = apply_rope(k, positions, cfg.rope_theta, sections)
+    if cfg.attn_scale:
+        # attention scales scores by head_dim ** -0.5: the query carries
+        # the rest of the configured scale
+        q = q * (cfg.attn_scale * cfg.hd ** 0.5)
     return q, k, v
 
 
@@ -269,10 +284,12 @@ def attn_decode(h, p, cfg: ArchConfig, dist: Dist, pos, kc, vc):
 
 
 def ffn_apply(h, bp, cfg: ArchConfig, dist: Dist):
+    """-> (out, counts): the MoE layer's counts (``moe_block``), or None
+    for a gated MLP."""
     if "moe" in bp:
         return moe_block(h, bp["moe"], cfg, dist)
     return gated_mlp(h, bp["mlp"]["wg"], bp["mlp"]["wu"], bp["mlp"]["wd"],
-                     dist)
+                     dist), None
 
 
 # ---------------------------------------------------------------------------
@@ -308,7 +325,8 @@ def _stack_forward(h, params, cfg: ArchConfig, dist: Dist, positions, opts,
         if dist.has_mesh:
             hh = dist.constrain(hh, _res_spec(cfg, dist, hh.shape[1]))
         x = norm_apply(hh, bp["ln2"], cfg)
-        hh = hh + ffn_apply(x, bp, cfg, dist)
+        f, _ = ffn_apply(x, bp, cfg, dist)
+        hh = hh + f
         if dist.has_mesh:
             hh = dist.constrain(hh, _res_spec(cfg, dist, hh.shape[1]))
         ys = (k, v) if collect_cache else None
@@ -379,7 +397,9 @@ def lm_decode(params, cache, batch, cfg: ArchConfig, dist: Dist, opts=None):
 
     The KV cache goes through the layer scan read-only; each layer returns
     only its new rows, written in place after the scan. Returns
-    (logits (B,1,V), new cache)."""
+    (logits (B,1,V), new cache, counts): counts is the MoE layers' (rows
+    computed, held experts touched) summed over layers, (2,) int32, or
+    None without MoE."""
     if cfg.family == "hybrid":
         return _hybrid_decode(params, cache, batch, cfg, dist, opts)
     if "embeds" in batch:
@@ -388,6 +408,7 @@ def lm_decode(params, cache, batch, cfg: ArchConfig, dist: Dist, opts=None):
         vs = dim_shardable(dist, cfg.vocab_size, "vocab")
         h = embed_tokens(batch["tokens"], params["embed"], dist, vs)
     pos = cache["pos"]
+    counts = None
 
     if cfg.family == "ssm":
         def body(hh, xs):
@@ -406,132 +427,254 @@ def lm_decode(params, cache, batch, cfg: ArchConfig, dist: Dist, opts=None):
             a, k, v = attn_decode(x, bp["attn"], cfg, dist, pos, kc, vc)
             hh = hh + a
             x = norm_apply(hh, bp["ln2"], cfg)
-            hh = hh + ffn_apply(x, bp, cfg, dist)
-            return hh, (k, v)
-        h, (k, v) = jax.lax.scan(
+            f, c = ffn_apply(x, bp, cfg, dist)
+            hh = hh + f
+            return hh, ((k, v) if c is None else (k, v, c))
+        h, ys = jax.lax.scan(
             body, h, (params["blocks"], cache["k"], cache["v"]))
+        k, v = ys[:2]
+        if len(ys) > 2:
+            counts = jnp.sum(ys[2], axis=0)
         new_cache = {"k": write_rows(cache["k"], k, pos),
                      "v": write_rows(cache["v"], v, pos), "pos": pos + 1}
 
     h = norm_apply(h, params["final_norm"], cfg)
     vs = dim_shardable(dist, cfg.vocab_size, "vocab")
     logits = last_token_logits(h, params["head"], dist, vs)
-    return logits, new_cache
+    return logits, new_cache, counts
 
 
 # ---------------------------------------------------------------------------
-# Hybrid (Jamba): periods of [attn, mamba x (per-1)], alternating MoE FFN
+# Hybrid: periods of attention at attn_index and mamba elsewhere, each layer
+# followed by its FFN. Consecutive layers of one kind (mixer and FFN) run as
+# one scan, so a period compiles about one layer of each kind.
 # ---------------------------------------------------------------------------
 
 
-def _hybrid_period(hh, bp, cfg, dist, positions, opts, collect):
-    """One period: layer j==0 attention, j>0 mamba; FFN after each mixer
-    (MoE at odd local j)."""
-    per = cfg.attn_period
-    ys_attn = None
-    ys_mamba = []
+def _at(tree, i):
+    return jax.tree.map(lambda a: a[i], tree)
 
-    def ffn_at(hh, j):
-        x = norm_apply(hh, jax.tree.map(lambda a: a[j], bp["ffn_ln"]), cfg)
-        if j % 2 == 1:
-            sub = jax.tree.map(lambda a: a[(j - 1) // 2], bp["moe"])
-            return hh + moe_block(x, sub, cfg, dist)
-        sub = jax.tree.map(lambda a: a[j // 2], bp["mlp"])
-        return hh + gated_mlp(x, sub["wg"], sub["wu"], sub["wd"], dist)
 
-    # j = 0: attention
-    x = norm_apply(hh, bp["attn_ln"], cfg)
-    a, kv = attn_train(x, bp["attn"], cfg, dist, positions, opts)
-    hh = ffn_at(hh + a, 0)
-    if collect:
-        ys_attn = kv
-    # j = 1..per-1: mamba
-    for j in range(1, per):
-        mp = jax.tree.map(lambda a: a[j - 1], bp["mamba"])
-        ln = jax.tree.map(lambda a: a[j - 1], bp["mamba_ln"])
-        x = norm_apply(hh, ln, cfg)
-        out, state, tail = mam.mamba_block(x, mp, cfg, dist)
-        hh = ffn_at(hh + out, j)
-        if collect:
-            ys_mamba.append((state, tail))
-    if collect:
-        states = jnp.stack([s for s, _ in ys_mamba])
-        tails = jnp.stack([t for _, t in ys_mamba])
-        return hh, (ys_attn[0], ys_attn[1], states, tails)
-    return hh, None
+def _times(x, m: float):
+    """x * m, or x itself where m is 1 (the program stays as it was)."""
+    return x if m == 1.0 else x * m
+
+
+def _split_experts(blocks):
+    """(blocks without the routed experts' weights, those weights with
+    their layers flattened into the expert axis, or None): the scans
+    slice the rest, and the grouped products read the experts as stored
+    (``moe_block``'s ``layer``)."""
+    if "moe" not in blocks:
+        return blocks, None
+    moe = dict(blocks["moe"])
+    experts = {k: moe.pop(k) for k in ("wg", "wu", "wd")}
+    experts = {k: w.reshape((-1,) + w.shape[-2:]) for k, w in experts.items()}
+    return dict(blocks, moe=moe), experts
+
+
+@dataclass(frozen=True)
+class _Mixers:
+    """How a path runs the mixers of a hybrid period.
+
+    attn(x, p) -> (out, kv); mamba(x, p, st) -> (out, st'): one layer,
+    where st is that mamba layer's state as ``read(extra, m)`` gives it
+    and ``write(extra, m, st')`` puts back -> (extra, kept), ``kept``
+    stacked over the period's mamba layers (None keeps nothing).
+    ``extra`` is the path's own carry through the layers."""
+    attn: Any
+    mamba: Any
+    read: Any
+    write: Any
+
+
+def _runs(cfg: ArchConfig):
+    """The period's layers as runs [first, end) of one kind: (first, end,
+    attention?, MoE FFN?)."""
+    runs = []
+    for j in range(cfg.attn_period):
+        kind = (cfg.is_attn_layer(j), cfg.is_moe_layer(j))
+        if runs and runs[-1][2:] == kind and not kind[0]:
+            runs[-1] = (runs[-1][0], j + 1) + kind
+        else:
+            runs.append((j, j + 1) + kind)
+    return runs
+
+
+def _hybrid_period(hh, extra, bp, q, experts, cfg: ArchConfig, dist: Dist,
+                   mix: _Mixers):
+    """Period ``q``. ``bp``: its blocks without the routed experts, which
+    come stacked for every period in ``experts`` (``_split_experts``).
+    Each mixer's and each FFN's output joins the residual times
+    ``residual_multiplier``. Returns (hh, extra, the attention layer's
+    kv, the mamba layers' kept states stacked, MoE counts summed over the
+    period or None)."""
+    per, a, rm = cfg.attn_period, cfg.attn_index, cfg.residual_multiplier
+    moe_at = [cfg.is_moe_layer(j) for j in range(per)]
+    n_moe = sum(moe_at)
+
+    def layer(hh, extra, j, j0, attn, moe):
+        # j: this layer (an int, or traced inside a run from j0); the
+        # indices into the stacks move with j inside a run
+        i_ffn = sum(moe_at[:j0]) if moe else j0 - sum(moe_at[:j0])
+        i_ffn += j - j0
+        kv = kept = counts = None
+        if attn:
+            with jax.named_scope("attention"):
+                x = norm_apply(hh, bp["attn_ln"], cfg)
+                out, kv = mix.attn(x, bp["attn"])
+        else:
+            m = j - (j0 > a)
+            with jax.named_scope("ssd"):
+                x = norm_apply(hh, _at(bp["mamba_ln"], m), cfg)
+                out, st = mix.mamba(x, _at(bp["mamba"], m), mix.read(extra, m))
+            extra, kept = mix.write(extra, m, st)
+        hh = hh + _times(out, rm)
+        x = norm_apply(hh, _at(bp["ffn_ln"], j), cfg)
+        if moe:
+            out, counts = moe_block(x, dict(experts, **_at(bp["moe"], i_ffn)),
+                                    cfg, dist, layer=q * n_moe + i_ffn)
+            if "shared" in bp:
+                with jax.named_scope("moe.shared"):
+                    sh = _at(bp["shared"], i_ffn)
+                    out = out + gated_mlp(x, sh["wg"], sh["wu"], sh["wd"],
+                                          dist)
+        else:
+            sub = _at(bp["mlp"], i_ffn)
+            out = gated_mlp(x, sub["wg"], sub["wu"], sub["wd"], dist)
+        return hh + _times(out, rm), extra, (kv, kept, counts)
+
+    kv, kept, counts = None, [], []
+    for j0, j1, attn, moe in _runs(cfg):
+        if j1 - j0 == 1:
+            hh, extra, (k, st, c) = layer(hh, extra, j0, j0, attn, moe)
+            st = jax.tree.map(lambda t: t[None], st)
+        else:
+            def body(carry, j, j0=j0, attn=attn, moe=moe):
+                hh, extra, ys = layer(*carry, j, j0, attn, moe)
+                return (hh, extra), ys
+            (hh, extra), (k, st, c) = jax.lax.scan(
+                body, (hh, extra), jnp.arange(j0, j1, dtype=jnp.int32))
+            c = None if c is None else jnp.sum(c, axis=0)
+        kv = k if attn else kv
+        if not attn and st is not None:
+            kept.append(st)
+        if c is not None:
+            counts.append(c)
+    kept = (jax.tree.map(lambda *t: jnp.concatenate(t), *kept)
+            if kept else None)
+    return hh, extra, kv, kept, (sum(counts) if counts else None)
+
+
+def _hybrid_embed(params, batch, cfg, dist):
+    h, positions = _inputs_to_h(params, batch, cfg, dist)
+    return _times(h, cfg.embedding_multiplier), positions
+
+
+def _hybrid_head(h, params, cfg, dist):
+    """Final norm and the output head (the embedding table where tied),
+    the logits divided by ``logits_scaling``: (normed h, head (D, V))."""
+    h = norm_apply(h, params["final_norm"], cfg)
+    w = params["embed"].T if cfg.tie_embeddings else params["head"]
+    return _times(h, 1.0 / cfg.logits_scaling), w
+
+
+def _hybrid_full_seq(params, batch, cfg, dist, opts, keep: bool):
+    """The stack over whole sequences (loss and prefill): (h, kv, states)
+    with each period's attention kv and mamba (state, tail) when ``keep``."""
+    h, positions = _hybrid_embed(params, batch, cfg, dist)
+    mix = _Mixers(
+        attn=lambda x, p: attn_train(x, p, cfg, dist, positions, opts),
+        mamba=lambda x, p, st: (lambda o, s, t: (o, (s, t)))(
+            *mam.mamba_block(x, p, cfg, dist)),
+        read=lambda extra, m: None,
+        write=lambda extra, m, st: (extra, st if keep else None))
+    blocks, experts = _split_experts(params["blocks"])
+    n = jax.tree.leaves(blocks)[0].shape[0]
+
+    def body(hh, xs):
+        bp, q = xs
+        hh, _, kv, kept, _ = _hybrid_period(hh, None, bp, q, experts, cfg,
+                                            dist, mix)
+        return hh, ((kv, kept) if keep else None)
+
+    return jax.lax.scan(_remat(body, opts), h,
+                        (blocks, jnp.arange(n, dtype=jnp.int32)))
 
 
 def _hybrid_loss(params, batch, cfg, dist, opts):
-    h, positions = _inputs_to_h(params, batch, cfg, dist)
-
-    def body(hh, bp):
-        hh, _ = _hybrid_period(hh, bp, cfg, dist, positions, opts, False)
-        return hh, None
-
-    h, _ = jax.lax.scan(_remat(body, opts), h, params["blocks"])
-    h = norm_apply(h, params["final_norm"], cfg)
+    h, _ = _hybrid_full_seq(params, batch, cfg, dist, opts, keep=False)
+    h, w = _hybrid_head(h, params, cfg, dist)
     vs = dim_shardable(dist, cfg.vocab_size, "vocab")
-    return chunked_xent(h, params["head"], batch["labels"], dist,
+    return chunked_xent(h, w, batch["labels"], dist,
                         chunk=min(_opt(opts, "xent_chunk"), h.shape[1]),
                         vocab_sharded=vs)
 
 
 def _hybrid_prefill(params, batch, cfg, dist, opts):
-    h, positions = _inputs_to_h(params, batch, cfg, dist)
-    seq = h.shape[1]
-
-    def body(hh, bp):
-        hh, ys = _hybrid_period(hh, bp, cfg, dist, positions, opts, True)
-        return hh, ys
-
-    h, (k, v, states, tails) = jax.lax.scan(
-        _remat(body, opts), h, params["blocks"])
-    h = norm_apply(h, params["final_norm"], cfg)
+    seq = batch["tokens"].shape[1] if "tokens" in batch \
+        else batch["embeds"].shape[1]
+    h, ((k, v), (states, tails)) = _hybrid_full_seq(params, batch, cfg, dist,
+                                                    opts, keep=True)
+    h, w = _hybrid_head(h, params, cfg, dist)
     vs = dim_shardable(dist, cfg.vocab_size, "vocab")
-    logits = last_token_logits(h[:, -1:], params["head"], dist, vs)
+    logits = last_token_logits(h[:, -1:], w, dist, vs)
     cache = {"k": k, "v": v, "ssm": states, "conv": tails,
              "pos": jnp.int32(seq)}
     return logits, cache
 
 
+def _slot(cache, q, m):
+    """Layer (period q, mamba layer m) of a stacked state cache."""
+    lead = (q, m) + (0,) * (cache.ndim - 2)
+    return jax.lax.dynamic_slice(cache, lead, (1, 1) + cache.shape[2:])[0, 0]
+
+
+def _put(cache, q, m, x):
+    lead = (q, m) + (0,) * (cache.ndim - 2)
+    return jax.lax.dynamic_update_slice(cache, x[None, None].astype(cache.dtype),
+                                        lead)
+
+
 def _hybrid_decode(params, cache, batch, cfg, dist, opts):
+    """The SSM and conv states ride through the scans in their carry, each
+    layer's read and written in place; the KV cache is read-only in the
+    scans and takes its new rows after them."""
     vs = dim_shardable(dist, cfg.vocab_size, "vocab")
-    h = embed_tokens(batch["tokens"], params["embed"], dist, vs)
+    h = _times(embed_tokens(batch["tokens"], params["embed"], dist, vs),
+               cfg.embedding_multiplier)
     pos = cache["pos"]
-    per = cfg.attn_period
+    blocks, experts = _split_experts(params["blocks"])
+    n = jax.tree.leaves(blocks)[0].shape[0]
 
-    def body(hh, xs):
-        bp, kc, vc, states, tails = xs
+    def body(carry, xs):
+        hh, ssm, conv = carry
+        bp, kc, vc, q = xs
 
-        def ffn_at(hh, j):
-            x = norm_apply(hh, jax.tree.map(lambda a: a[j], bp["ffn_ln"]),
-                           cfg)
-            if j % 2 == 1:
-                sub = jax.tree.map(lambda a: a[(j - 1) // 2], bp["moe"])
-                return hh + moe_block(x, sub, cfg, dist)
-            sub = jax.tree.map(lambda a: a[j // 2], bp["mlp"])
-            return hh + gated_mlp(x, sub["wg"], sub["wu"], sub["wd"], dist)
+        def attn(x, p):
+            a, k, v = attn_decode(x, p, cfg, dist, pos, kc, vc)
+            return a, (k, v)
 
-        x = norm_apply(hh, bp["attn_ln"], cfg)
-        a, k, v = attn_decode(x, bp["attn"], cfg, dist, pos, kc, vc)
-        hh = ffn_at(hh + a, 0)
-        new_states, new_tails = [], []
-        for j in range(1, per):
-            mp = jax.tree.map(lambda a: a[j - 1], bp["mamba"])
-            ln = jax.tree.map(lambda a: a[j - 1], bp["mamba_ln"])
-            x = norm_apply(hh, ln, cfg)
-            out, st, tl = mam.mamba_decode(
-                x, mp, cfg, dist, states[j - 1], tails[j - 1])
-            hh = ffn_at(hh + out, j)
-            new_states.append(st)
-            new_tails.append(tl)
-        return hh, (k, v, jnp.stack(new_states), jnp.stack(new_tails))
+        def mamba(x, p, st):
+            out, s, t = mam.mamba_decode(x, p, cfg, dist, *st)
+            return out, (s, t)
 
-    h, (k, v, ssm, conv) = jax.lax.scan(
-        body, h, (params["blocks"], cache["k"], cache["v"],
-                  cache["ssm"], cache["conv"]))
-    h = norm_apply(h, params["final_norm"], cfg)
-    logits = last_token_logits(h, params["head"], dist, vs)
+        mix = _Mixers(
+            attn=attn, mamba=mamba,
+            read=lambda e, m: (_slot(e[0], q, m), _slot(e[1], q, m)),
+            write=lambda e, m, st: ((_put(e[0], q, m, st[0]),
+                                     _put(e[1], q, m, st[1])), None))
+        hh, (ssm, conv), (k, v), _, counts = _hybrid_period(
+            hh, (ssm, conv), bp, q, experts, cfg, dist, mix)
+        return (hh, ssm, conv), ((k, v) if counts is None else (k, v, counts))
+
+    (h, ssm, conv), ys = jax.lax.scan(
+        body, (h, cache["ssm"], cache["conv"]),
+        (blocks, cache["k"], cache["v"], jnp.arange(n, dtype=jnp.int32)))
+    k, v = ys[:2]
+    counts = jnp.sum(ys[2], axis=0) if len(ys) > 2 else None
+    h, w = _hybrid_head(h, params, cfg, dist)
+    logits = last_token_logits(h, w, dist, vs)
     return logits, {"k": write_rows(cache["k"], k, pos),
                     "v": write_rows(cache["v"], v, pos),
-                    "ssm": ssm, "conv": conv, "pos": pos + 1}
+                    "ssm": ssm, "conv": conv, "pos": pos + 1}, counts

@@ -28,7 +28,8 @@ from repro.platform.cluster import (App, Resources, Scheduler, RUNNING,
                                     PREEMPTED as TASK_PREEMPTED,
                                     STAGING as TASK_STAGING)
 from repro.platform.watchdog import JOB_DONE, JOB_FAILED
-from repro.platform.zookeeper import NoNodeError, ZooKeeper, zk_retry
+from repro.platform.zookeeper import (NodeExistsError, NoNodeError, ZooKeeper,
+                                      zk_retry)
 
 # job states (PREEMPTED is non-terminal: the scheduler requeues the
 # job's tasks and they resume from the last checkpoint — bounding the
@@ -185,10 +186,13 @@ class LifecycleManager:
         data = json.dumps(value).encode()
 
         def write():
-            if self.zk.exists(path):
-                self.zk.set(path, data)
-            else:
-                self.zk.create(path, data, makepath=True)
+            if not self.zk.exists(path):
+                try:
+                    self.zk.create(path, data, makepath=True)
+                    return
+                except NodeExistsError:
+                    pass    # another thread (tick vs status) made it first
+            self.zk.set(path, data)
         # monitor()/submit run on the tick thread: a brief quorum outage
         # (kill_replica chaos) must not crash the control loop
         zk_retry(write)

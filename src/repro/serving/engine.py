@@ -20,8 +20,9 @@ mathematically an independent batch-1 decode, which is what makes a
 mid-flight join token-identical to running the request alone
 (tests/test_serving.py asserts exactly that). The step reads the cache
 once and writes only each slot's new KV rows, in place in the donated
-buffer; ``stats()`` reports whether the compiled program did so. Greedy
-(argmax) sampling keeps the engine deterministic.
+buffer; ``stats()`` reports whether the compiled program did so. For MoE
+models the step also returns its routing counts, read with its tokens.
+Greedy (argmax) sampling keeps the engine deterministic.
 """
 from __future__ import annotations
 
@@ -205,6 +206,10 @@ class InferenceEngine:
         self._decode_steps = 0
         self._occupied_slot_steps = 0
         self._decode_s = 0.0        # host time inside decode steps
+        # MoE models, over decode steps and layers: (token, held expert)
+        # rows computed, and held experts with at least one row
+        self._moe_rows = 0
+        self._moe_experts_touched = 0
         # from the decode program's background compile (status.perf):
         # its temporary bytes, and whether every cache leaf is updated in
         # place (the donated input aliased to the output)
@@ -284,10 +289,12 @@ class InferenceEngine:
 
     def decode_program(self):
         """The jitted decode step ``(params, cache, tokens (capacity, 1)) ->
-        (logits, cache)``: one token for every slot, the slot cache
-        donated and updated in place."""
+        (logits, cache, counts)``: one token for every slot, the slot
+        cache donated and updated in place; counts are the step's MoE
+        counts (``Model.decode_counted``), None without MoE."""
         def step(params, cache, tokens):
-            return self.model.decode(params, cache, {"tokens": tokens})
+            return self.model.decode_counted(params, cache,
+                                             {"tokens": tokens})
         return jax.jit(_named(step, DECODE_PROGRAM), donate_argnums=(1,))
 
     def cache_shapes(self) -> Dict[str, jax.ShapeDtypeStruct]:
@@ -553,11 +560,12 @@ class InferenceEngine:
         with TraceAnnotation("serve.dispatch"):
             t0 = time.perf_counter()
             toks = jnp.asarray(self._next_tok.reshape(self.capacity, 1))
-            logits, self._cache = self._decode(self.params, self._cache,
-                                               toks)
+            logits, self._cache, counts = self._decode(
+                self.params, self._cache, toks)
             nxt = jnp.argmax(logits[:, -1, :], axis=-1)
         with TraceAnnotation("serve.read"):
-            nxt = np.asarray(nxt).astype(np.int32)
+            nxt, counts = jax.device_get((nxt, counts))
+            nxt = nxt.astype(np.int32)
             step_s = time.perf_counter() - t0
         with TraceAnnotation("serve.bookkeep"):
             now = time.time()
@@ -574,6 +582,9 @@ class InferenceEngine:
                 self._decode_steps += 1
                 self._decode_s += step_s
                 self._occupied_slot_steps += live
+                if counts is not None:
+                    self._moe_rows += int(counts[0])
+                    self._moe_experts_touched += int(counts[1])
             self._gauge("batch_occupancy", live / self.capacity,
                         step=self._decode_steps)
         return live
@@ -703,8 +714,16 @@ class InferenceEngine:
         with the measured rate, and its in-place figures."""
         with self._lock:
             mem = {"decode_temp_bytes": self._decode_temp_bytes,
-                   "decode_cache_aliased": self._decode_cache_aliased}
+                   "decode_cache_aliased": self._decode_cache_aliased,
+                   **self._moe_counts()}
         return dict(self.perf.snapshot(self.decode_rate()), **mem)
+
+    def _moe_counts(self) -> Dict:
+        """The MoE counters, for MoE models (caller holds the lock)."""
+        if not self.cfg.is_moe:
+            return {}
+        return {"moe_rows": self._moe_rows,
+                "moe_experts_touched": self._moe_experts_touched}
 
     def stats(self) -> Dict:
         """Counters + latency percentiles + occupancy — what endpoint
@@ -732,6 +751,7 @@ class InferenceEngine:
                     occ / (steps * self.capacity), 4) if steps else 0.0,
                 "decode_temp_bytes": self._decode_temp_bytes,
                 "decode_cache_aliased": self._decode_cache_aliased,
+                **self._moe_counts(),
             }
         if self.metrics is not None:
             p50 = self.metrics.percentile(self.endpoint_id, "latency_s", 50)

@@ -23,6 +23,18 @@ os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
 
 
 @pytest.fixture(autouse=True)
+def _registry_restored():
+    """Each test starts from the published registry: a cut configuration
+    the benchmark registers (under its configuration's name, which may be
+    the published id) stays inside the test that registered it."""
+    from repro.configs.registry import REGISTRY
+    saved = dict(REGISTRY)
+    yield
+    REGISTRY.clear()
+    REGISTRY.update(saved)
+
+
+@pytest.fixture(autouse=True)
 def _deterministic_seed(request):
     """Seed the global RNGs per test so runs are reproducible regardless
     of test ordering or -k selection.  Each test gets its own stable

@@ -5,6 +5,7 @@ Mosaic kernel is in the compiled program; the decode step's test, that
 it updates its cache in place. The topology is described inside a
 fixture, never at import, so every test worker collects the same
 tests."""
+import dataclasses
 import re
 
 import jax
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.registry import resolve_arch
 from repro.core.software_ps import ShardLayout
 from repro.kernels import autotune
+from repro.kernels import ops as kernel_ops
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.ps_aggregate import ps_aggregate
 from repro.kernels.quantize import dequantize, quantize_ef
@@ -128,9 +130,11 @@ def test_ssd_scan_compiles_at_mamba2_widths(compile_tpu):
     assert "tpu_custom_call" in txt
 
 
-def _materialized(hlo_text):
+def _materialized(hlo_text, hbm_only=False):
     """(opcode, dtype, dims without unit dims) of every op outside fused
-    computations: the buffers the program writes."""
+    computations: the buffers the program writes. ``hbm_only`` leaves out
+    buffers placed in the chip's faster memory (``S(1)`` layouts: the
+    compiler's prefetches of what an op reads next)."""
     fused = set(re.findall(r"calls=%([\w.-]+)", hlo_text))
     ops, comp = [], None
     for line in hlo_text.splitlines():
@@ -138,12 +142,12 @@ def _materialized(hlo_text):
         if head:
             comp = head.group(1)
             continue
-        op = re.match(r"\s+(?:ROOT )?%[\w.-]+ = (\w+)\[([\d,]*)\]\S* "
+        op = re.match(r"\s+(?:ROOT )?%[\w.-]+ = (\w+)\[([\d,]*)\](\S*) "
                       r"([\w-]+)\(", line)
-        if op and comp not in fused:
+        if op and comp not in fused and not (hbm_only and "S(" in op.group(3)):
             dims = tuple(int(d) for d in op.group(2).split(",")
                          if d and int(d) != 1)
-            ops.append((op.group(3), op.group(1), dims))
+            ops.append((op.group(4), op.group(1), dims))
     return ops
 
 
@@ -174,3 +178,40 @@ def test_serve_decode_updates_the_kv_cache_in_place(one_chip,
                                   "bitcast", "while")]
     assert cache_sized and set(cache_sized) == {"dynamic-update-slice"}, \
         cache_sized
+
+
+def test_granite_decode_step_at_the_cell_cut(one_chip, no_persistent_cache,
+                                             monkeypatch):
+    """The engine's decode program for granite-4.0-h-small as the
+    benchmark cuts it for one chip (one period of 10 layers, 18 of each
+    layer's 72 experts held) at 8 slots of 1280 positions: every cache
+    leaf aliased to its donated input, temporaries far under one copy of
+    the SSM state (0.30 GB), the expert weights read where they are
+    stored by the grouped matmul kernel, and no buffer in HBM the shape
+    of the KV cache written but by the in-place row writes."""
+    # the program is traced here, where the default backend is the CPU:
+    # take the kernels' TPU branch, as on the chip
+    monkeypatch.setattr(kernel_ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(resolve_arch("granite-4.0-h-small"),
+                              n_layers=10, expert_shards=4)
+    eng = InferenceEngine(cfg, capacity=8, max_seq=1280)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    cache = on_chip(eng.cache_shapes())
+    compiled = eng.decode_program().lower(
+        on_chip(eng.model.abstract_params()), cache,
+        jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)).compile()
+    temp, aliased = decode_in_place(compiled, cache)
+    assert aliased
+    assert temp < 64 * 2 ** 20, temp
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    ops = _materialized(text, hbm_only=True)
+    kv = tuple(d for d in cache["k"].shape if d != 1)
+    experts = tuple(d for d in eng.model.abstract_params()[
+        "blocks"]["moe"]["wg"].shape[2:])
+    written = [op for op, dt, dims in ops if dims in (kv, experts)
+               and op not in ("parameter", "get-tuple-element", "tuple",
+                              "bitcast", "while")]
+    assert written and set(written) == {"dynamic-update-slice"}, written

@@ -28,6 +28,8 @@ ASSIGNED = {
                                  vocab_size=65536),
     "qwen2-vl-2b": dict(n_layers=28, d_model=1536, n_heads=12,
                         n_kv_heads=2, d_ff=8960, vocab_size=151936),
+    "granite-4.0-h-small": dict(n_layers=40, d_model=4096, n_heads=32,
+                                n_kv_heads=8, d_ff=0, vocab_size=100352),
 }
 
 
@@ -53,6 +55,27 @@ def test_moe_specs():
     assert get_arch("mamba2-1.3b").ssm.d_state == 128
 
 
+def test_granite_4_0_h_small_spec():
+    """The published config.json: 72 experts top-10 of width 768 with a
+    shared expert of 1536, attention at index 5 of each period of 10
+    (layers 5, 15, 25, 35), NoPE, the four multipliers, tied embeddings."""
+    g = get_arch("granite-4.0-h-small")
+    assert (g.moe.n_experts, g.moe.top_k, g.moe.d_ff_expert,
+            g.moe.d_ff_shared) == (72, 10, 768, 1536)
+    assert g.moe.layout == "all" and g.expert_shards == 1
+    assert g.held_experts == 72
+    assert (g.attn_period, g.attn_index) == (10, 5)
+    assert [i for i in range(g.n_layers) if g.is_attn_layer(i)] == \
+        [5, 15, 25, 35]
+    assert g.nope and g.tie_embeddings
+    assert (g.attn_scale, g.embedding_multiplier, g.residual_multiplier,
+            g.logits_scaling) == (1 / 128, 12.0, 0.22, 16.0)
+    s = g.ssm
+    assert (s.d_state, s.head_dim, s.expand, s.n_groups, s.conv_kernel,
+            s.chunk_size) == (128, 64, 2, 1, 4, 256)
+    assert s.expand * g.d_model // s.head_dim == 128     # mamba_n_heads
+
+
 def test_param_counts_plausible():
     # within the right order of magnitude of the advertised sizes
     assert 0.8e12 < get_arch("kimi-k2-1t-a32b").n_params() < 1.3e12
@@ -65,6 +88,11 @@ def test_param_counts_plausible():
     assert 1.4e10 < get_arch("granite-20b").n_params() < 3.0e10
     assert 0.9e9 < get_arch("mamba2-1.3b").n_params() < 2.0e9
     assert 3.0e11 < get_arch("jamba-1.5-large-398b").n_params() < 5.0e11
+    # 32B-A9B: the shared expert and MoE at every layer counted, the
+    # head tied to the embedding table
+    g = get_arch("granite-4.0-h-small")
+    assert 3.0e10 < g.n_params() < 3.4e10
+    assert 8e9 < g.n_active_params() < 1e10
     # MoE active << total
     k = get_arch("kimi-k2-1t-a32b")
     assert k.n_active_params() < 0.08 * k.n_params()

@@ -73,6 +73,28 @@ def test_ps_aggregate(solver, nl, f):
     np.testing.assert_allclose(vk, vr, atol=1e-6)
 
 
+@pytest.mark.parametrize("sizes", [
+    [0, 3, 0, 0, 2, 1, 0, 0, 0, 0, 0, 0],     # some groups empty
+    [0] * 12,                                  # no row in any group
+    [1] * 12,                                  # every group one row
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_matmul_matches_ragged_dot(sizes, dtype):
+    """Rows inside the groups equal ``ragged_dot``'s; rows past them are
+    left to the caller."""
+    rows = _rand(0, (16, 256), dtype)
+    w = _rand(1, (12, 256, 128), dtype)
+    g = jnp.asarray(sizes, jnp.int32)
+    n = sum(sizes)
+    got = ops.grouped_matmul(rows, w, g)
+    want = jax.lax.ragged_dot(rows, w, g)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got[:n], np.float32),
+                               np.asarray(want[:n], np.float32),
+                               atol=tol, rtol=tol)
+
+
 @pytest.mark.parametrize("solver", ["sgd", "momentum", "adam",
                                     "easgd_center", "average"])
 def test_ps_aggregate_np_matches_ref_over_rounds(solver):

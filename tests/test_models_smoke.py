@@ -54,7 +54,8 @@ def test_smoke_train_prefill_decode(arch_id):
 
 
 @pytest.mark.parametrize("arch_id", ["stablelm-1.6b", "granite-20b",
-                                     "kimi-k2-1t-a32b", "mamba2-1.3b"])
+                                     "kimi-k2-1t-a32b", "mamba2-1.3b",
+                                     "granite-4.0-h-small"])
 def test_decode_matches_prefill(arch_id):
     """Prefill over t+1 tokens must give the same last-position logits as
     prefill over t tokens followed by one decode step of token t."""

@@ -18,7 +18,7 @@ from repro.configs.base import reduce_for_smoke
 from repro.configs.registry import get_arch
 from repro.distributed.sharding import Dist
 from repro.launch.mesh import make_mesh
-from repro.models.moe import moe_block, moe_param_defs, _moe_single, replication_factor
+from repro.models.moe import moe_block, moe_param_defs, _moe_single
 from repro.models.layers import init_params
 
 cfg = reduce_for_smoke(get_arch("kimi-k2-1t-a32b"))  # 4 experts top-2
@@ -29,9 +29,8 @@ params = init_params(defs, jax.random.PRNGKey(0), jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model)) * 0.5
 
 with jax.set_mesh(mesh):
-    y_sh = jax.jit(lambda x, p: moe_block(x, p, cfg, dist))(x, params)
-r = replication_factor(cfg.moe, dist)
-y_loc = _moe_single(x, params, cfg.moe, r)
+    y_sh, _ = jax.jit(lambda x, p: moe_block(x, p, cfg, dist))(x, params)
+y_loc, _ = _moe_single(x, params, cfg.moe, cfg.held_experts)
 d = float(jnp.max(jnp.abs(np.asarray(y_sh) - np.asarray(y_loc))))
 print("moe diff:", d)
 assert d < 5e-2, d
@@ -39,8 +38,8 @@ assert d < 5e-2, d
 # decode path (seq=1)
 x1 = x[:, :1]
 with jax.set_mesh(mesh):
-    y1 = jax.jit(lambda x, p: moe_block(x, p, cfg, dist))(x1, params)
-y1l = _moe_single(x1, params, cfg.moe, r)
+    y1, _ = jax.jit(lambda x, p: moe_block(x, p, cfg, dist))(x1, params)
+y1l, _ = _moe_single(x1, params, cfg.moe, cfg.held_experts)
 d1 = float(jnp.max(jnp.abs(np.asarray(y1) - np.asarray(y1l))))
 print("moe decode diff:", d1)
 assert d1 < 5e-2, d1

@@ -154,6 +154,26 @@ def test_lcm_full_lifecycle():
     assert lcm.member_statuses("j1") == {}
 
 
+def test_lcm_state_write_survives_a_racing_create():
+    """Two threads writing a job's key (the tick thread's monitor and a
+    status call's) can both find it missing; the second create then
+    finds it made and sets it instead of failing."""
+    zk = ZooKeeper()
+    lcm = LifecycleManager(zk, Scheduler(mk_cluster()))
+    exists = zk.exists
+    path = "/dlaas/jobs/j1/progress"
+
+    def racing_exists(p):
+        if p == path and not exists(p):
+            zk.create(p, b"{}", makepath=True)    # the other thread's write
+            return False
+        return exists(p)
+
+    zk.exists = racing_exists
+    lcm._set("j1", "progress", {"alive": 1})
+    assert zk.get(path)[0] == b'{"alive": 1}'
+
+
 def test_lcm_detects_crash_via_ephemeral():
     zk = ZooKeeper()
     s = Scheduler(mk_cluster())

@@ -432,3 +432,108 @@ def test_endpoint_is_a_metered_job(core):
     _wait_state(core, held, "STOPPED", timeout=30)
     core.stop_endpoint(eid)
     _wait_state(core, eid, "STOPPED")
+
+
+# ---------------------------------------------------------------------------
+# hybrid (granite-4.0-h-small): KV cache and SSM state side by side, MoE
+# ---------------------------------------------------------------------------
+
+GRANITE = "granite-4.0-h-small-smoke"
+
+
+def test_granite_endpoint_serves_through_core(core, monkeypatch):
+    """A hybrid endpoint deploys and serves through DLaaSCore with its
+    cache of KV rows and SSM state; status carries the MoE counters and
+    the decode step's in-place figures. (That the step on the chip holds
+    no copy of the KV cache is tests/test_chip_compile.py's: the CPU
+    backend schedules this program with copies of it.)"""
+    # a loaded test host can trip the SLO engine, whose remediation adds
+    # decode slots and so changes the rows each step computes
+    monkeypatch.setattr(core.health, "remediate", False)
+    out = core.deploy_endpoint(arch=GRANITE, capacity=2, max_new=4,
+                               max_seq=256)
+    eid = out["endpoint_id"]
+    _wait_state(core, eid, "READY")
+    rng = np.random.RandomState(2)
+    res = [core.predict(eid, rng.randint(0, 256, size=n), max_new=4)
+           for n in (5, 17, 9)]
+    assert [len(r["tokens"]) for r in res] == [4, 4, 4]
+    eng = core.endpoints[eid].engine
+    assert set(eng.cache_shapes()) == {"k", "v", "ssm", "conv", "pos"}
+    assert wait_until(lambda: eng.perf.state == "ready", timeout=120), \
+        eng.perf.error
+    st = core.endpoint_status(eid)
+    stats, perf = st["stats"], st["perf"]
+    assert stats["decode_cache_aliased"] is True
+    assert stats["decode_temp_bytes"] > 0
+    # every expert is held here: each decode step routes each of the
+    # 2 slots to top_k experts in each of the 10 layers
+    cfg = resolve_arch(GRANITE)
+    assert stats["moe_rows"] == (stats["decode_steps"] * 2 * cfg.moe.top_k
+                                 * cfg.n_layers) > 0
+    assert 0 < stats["moe_experts_touched"] <= stats["moe_rows"]
+    assert (perf["moe_rows"], perf["moe_experts_touched"]) == (
+        stats["moe_rows"], stats["moe_experts_touched"])
+    core.stop_endpoint(eid)
+    _wait_state(core, eid, "STOPPED")
+
+
+def _reference_conf(a):
+    """The float32 reference's configuration for arch ``a``."""
+    s, m = a.ssm, a.moe
+    return dict(
+        hidden_size=a.d_model, num_hidden_layers=a.n_layers,
+        layer_types=["attention" if a.is_attn_layer(i) else "mamba"
+                     for i in range(a.n_layers)],
+        num_attention_heads=a.n_heads, num_key_value_heads=a.n_kv_heads,
+        vocab_size=a.vocab_size, rms_norm_eps=a.norm_eps,
+        mamba_d_state=s.d_state, mamba_d_head=s.head_dim,
+        mamba_expand=s.expand, mamba_chunk_size=s.chunk_size,
+        mamba_n_groups=s.n_groups, mamba_d_conv=s.conv_kernel,
+        intermediate_size=m.d_ff_expert, shared_intermediate_size=m.d_ff_shared,
+        num_experts_per_tok=m.top_k, published_num_local_experts=m.n_experts,
+        num_local_experts=a.held_experts, attention_multiplier=a.attn_scale,
+        embedding_multiplier=a.embedding_multiplier,
+        residual_multiplier=a.residual_multiplier,
+        logits_scaling=a.logits_scaling, tie_word_embeddings=a.tie_embeddings,
+        dtype=a.dtype)
+
+
+def test_moe_counters_match_a_host_recount():
+    """Two slots decoding in lockstep with half the experts held: the
+    engine's moe_rows and moe_experts_touched equal the routing recounted
+    on the host from the float32 reference's router inputs."""
+    import dataclasses
+    import sys
+    from pathlib import Path
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench.reference import hybrid
+
+    a = dataclasses.replace(resolve_arch(GRANITE), expert_shards=2)
+    conf = _reference_conf(a)
+    eng = InferenceEngine(a, capacity=2, max_seq=MAX_SEQ,
+                          endpoint_id="ep-moe-count")
+    eng.start(None)
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, a.vocab_size, size=8).astype(np.int32)
+               for _ in range(2)]
+    reqs = _serve(eng, [eng.submit(p, max_new=5) for p in prompts])
+    stats = eng.stats()
+    assert stats["decode_steps"] == 4           # one prefill batch, lockstep
+    picks = []                                  # per request: (T, layer, k)
+    for r in reqs:
+        seq = np.concatenate([r.prompt, r.tokens[:-1]])[None]
+        layers = {}
+        hybrid.layers(eng.params, jnp.asarray(seq), conf,
+                      on_layer=lambda i, x, p: layers.__setitem__(
+                          i, np.asarray(hybrid.gates(x, p["moe"]["router"],
+                                                     conf)[1][0])))
+        picks.append(np.stack([layers[i] for i in range(a.n_layers)], 1))
+    rows = touched = 0
+    for t in range(8, 8 + 4):                   # the decode steps' inputs
+        for i in range(a.n_layers):
+            held = [e for p in picks for e in p[t, i] if e < a.held_experts]
+            rows += len(held)
+            touched += len(set(held))
+    assert (stats["moe_rows"], stats["moe_experts_touched"]) == (rows, touched)
+    assert 0 < touched < rows
