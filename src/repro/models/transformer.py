@@ -263,6 +263,23 @@ def write_rows(cache, rows, pos):
     return cache
 
 
+def _slot(cache, *idx):
+    """The layer at leading indices idx of a stacked state cache: (layer,)
+    for a plain stack, (period q, mamba layer m) for the hybrid's."""
+    n = len(idx)
+    lead = idx + (0,) * (cache.ndim - n)
+    return jax.lax.dynamic_slice(cache, lead,
+                                 (1,) * n + cache.shape[n:])[(0,) * n]
+
+
+def _put(cache, x, *idx):
+    """cache with the layer at leading indices idx set to x, in its dtype."""
+    n = len(idx)
+    lead = idx + (0,) * (cache.ndim - n)
+    return jax.lax.dynamic_update_slice(
+        cache, x[(None,) * n].astype(cache.dtype), lead)
+
+
 def attn_decode(h, p, cfg: ArchConfig, dist: Dist, pos, kc, vc):
     """h (B,1,D); kc/vc (B,S,KV*hd), read only; pos: the token's position,
     a scalar or one per batch row. Returns (out, k_row, v_row): the
@@ -396,10 +413,13 @@ def lm_decode(params, cache, batch, cfg: ArchConfig, dist: Dist, opts=None):
     write position: a scalar shared by the batch, or one per batch row.
 
     The KV cache goes through the layer scan read-only; each layer returns
-    only its new rows, written in place after the scan. Returns
-    (logits (B,1,V), new cache, counts): counts is the MoE layers' (rows
-    computed, held experts touched) summed over layers, (2,) int32, or
-    None without MoE."""
+    only its new rows, written in place after the scan. The SSM and conv
+    states ride in the scan's carry, each layer's read and written in
+    place, so the donated state cache is updated with no stacked copy.
+
+    Returns (logits (B,1,V), new cache, counts): counts is the MoE layers'
+    (rows computed, held experts touched) summed over layers, (2,) int32,
+    or None without MoE."""
     if cfg.family == "hybrid":
         return _hybrid_decode(params, cache, batch, cfg, dist, opts)
     if "embeds" in batch:
@@ -411,14 +431,17 @@ def lm_decode(params, cache, batch, cfg: ArchConfig, dist: Dist, opts=None):
     counts = None
 
     if cfg.family == "ssm":
-        def body(hh, xs):
-            bp, state, tail = xs
+        def body(carry, xs):
+            hh, ssm, conv = carry
+            bp, i = xs
             x = norm_apply(hh, bp["ln1"], cfg)
             out, state, tail = mam.mamba_decode(x, bp["mamba"], cfg, dist,
-                                                state, tail)
-            return hh + out, (state, tail)
-        h, (ssm, conv) = jax.lax.scan(
-            body, h, (params["blocks"], cache["ssm"], cache["conv"]))
+                                                _slot(ssm, i), _slot(conv, i))
+            return (hh + out, _put(ssm, state, i), _put(conv, tail, i)), None
+        n = cache["ssm"].shape[0]
+        (h, ssm, conv), _ = jax.lax.scan(
+            body, (h, cache["ssm"], cache["conv"]),
+            (params["blocks"], jnp.arange(n, dtype=jnp.int32)))
         new_cache = {"ssm": ssm, "conv": conv, "pos": pos + 1}
     else:
         def body(hh, xs):
@@ -624,18 +647,6 @@ def _hybrid_prefill(params, batch, cfg, dist, opts):
     return logits, cache
 
 
-def _slot(cache, q, m):
-    """Layer (period q, mamba layer m) of a stacked state cache."""
-    lead = (q, m) + (0,) * (cache.ndim - 2)
-    return jax.lax.dynamic_slice(cache, lead, (1, 1) + cache.shape[2:])[0, 0]
-
-
-def _put(cache, q, m, x):
-    lead = (q, m) + (0,) * (cache.ndim - 2)
-    return jax.lax.dynamic_update_slice(cache, x[None, None].astype(cache.dtype),
-                                        lead)
-
-
 def _hybrid_decode(params, cache, batch, cfg, dist, opts):
     """The SSM and conv states ride through the scans in their carry, each
     layer's read and written in place; the KV cache is read-only in the
@@ -662,8 +673,8 @@ def _hybrid_decode(params, cache, batch, cfg, dist, opts):
         mix = _Mixers(
             attn=attn, mamba=mamba,
             read=lambda e, m: (_slot(e[0], q, m), _slot(e[1], q, m)),
-            write=lambda e, m, st: ((_put(e[0], q, m, st[0]),
-                                     _put(e[1], q, m, st[1])), None))
+            write=lambda e, m, st: ((_put(e[0], st[0], q, m),
+                                     _put(e[1], st[1], q, m)), None))
         hh, (ssm, conv), (k, v), _, counts = _hybrid_period(
             hh, (ssm, conv), bp, q, experts, cfg, dist, mix)
         return (hh, ssm, conv), ((k, v) if counts is None else (k, v, counts))

@@ -180,6 +180,32 @@ def test_serve_decode_updates_the_kv_cache_in_place(one_chip,
         cache_sized
 
 
+def test_serve_decode_updates_the_ssm_state_in_place(one_chip,
+                                                     no_persistent_cache):
+    """The engine's own decode program at mamba2-1.3b's published widths
+    (16 slots, 1280 positions): every cache leaf aliased to its donated
+    input, temporaries far under one copy of the f32 SSM state (1.6 GB),
+    and no copy or custom call (an allocation) in HBM the shape of the
+    whole state leaf or of one layer's slab of it."""
+    eng = InferenceEngine(resolve_arch("mamba2-1.3b"), capacity=16,
+                          max_seq=1280)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    cache = on_chip(eng.cache_shapes())
+    compiled = eng.decode_program().lower(
+        on_chip(eng.model.abstract_params()), cache,
+        jax.ShapeDtypeStruct((16, 1), jnp.int32, sharding=one_chip)).compile()
+    temp, aliased = decode_in_place(compiled, cache)
+    assert aliased
+    assert temp < 64 * 2 ** 20, temp
+    leaf = tuple(d for d in cache["ssm"].shape if d != 1)
+    moved = [(op, dims) for op, dt, dims in _materialized(compiled.as_text(),
+                                                          hbm_only=True)
+             if dims in (leaf, leaf[1:]) and op in ("copy", "custom-call")]
+    assert not moved, moved
+
+
 def test_granite_decode_step_at_the_cell_cut(one_chip, no_persistent_cache,
                                              monkeypatch):
     """The engine's decode program for granite-4.0-h-small as the

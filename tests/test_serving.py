@@ -11,6 +11,10 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import resolve_arch
+from repro.distributed.sharding import dim_shardable
+from repro.models import mamba as mam
+from repro.models.layers import embed_tokens, last_token_logits
+from repro.models.transformer import norm_apply
 from repro.platform.cluster import UserError
 from repro.serving.engine import (EndpointClosed, InferenceEngine,
                                   QueueFull)
@@ -125,11 +129,71 @@ def test_decode_step_writes_only_each_slots_new_row(cfg):
         assert r.tokens == _sequential_reference(eng.model, eng.params, p, 2)
 
 
-def test_stats_say_the_decode_step_runs_in_place(cfg):
+def _layer_by_layer_decode(model, params, cache, tokens):
+    """One ssm decode step as a plain loop over the layers, each layer's
+    mamba_decode (jitted alone) on its own slice of the pre-step cache:
+    (next tokens, new ssm states, new conv tails stacked over layers)."""
+    cfg, dist = model.cfg, model.dist
+    vs = dim_shardable(dist, cfg.vocab_size, "vocab")
+
+    @jax.jit
+    def layer(h, bp, state, tail):
+        out, state, tail = mam.mamba_decode(norm_apply(h, bp["ln1"], cfg),
+                                            bp["mamba"], cfg, dist,
+                                            state, tail)
+        return h + out, state, tail
+
+    h = jax.jit(lambda t, w: embed_tokens(t, w, dist, vs))(
+        jnp.asarray(tokens), params["embed"])
+    states, tails = [], []
+    for i in range(cache["ssm"].shape[0]):
+        h, state, tail = layer(h, jax.tree.map(lambda a: a[i],
+                                               params["blocks"]),
+                               cache["ssm"][i], cache["conv"][i])
+        states.append(np.asarray(state))
+        tails.append(np.asarray(tail))
+    logits = jax.jit(lambda h, ln, w: last_token_logits(
+        norm_apply(h, ln, cfg), w, dist, vs))(
+        h, params["final_norm"], params["head"])
+    return (np.asarray(jnp.argmax(logits[:, -1], axis=-1)),
+            np.stack(states), np.stack(tails))
+
+
+def test_decode_step_updates_every_layers_ssm_state():
+    """One decode step of mamba2 over slots at different depths, one slot
+    free: every layer's ssm and conv state, and each live slot's token,
+    are bit for bit a plain loop over the layers on the pre-step cache."""
+    eng = InferenceEngine(resolve_arch("mamba2-1.3b-smoke"), capacity=3,
+                          max_seq=MAX_SEQ, endpoint_id="ep-states")
+    eng.start(None)
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(0, eng.cfg.vocab_size, size=n).astype(np.int32)
+               for n in (5, 11)]
+    reqs = [eng.submit(p, max_new=4) for p in prompts]
+    while (batch := eng._take_batch()) is not None:   # slot 2 stays free
+        eng._admit(*batch)
+    before = jax.tree.map(np.asarray, eng._cache)
+    assert list(before["pos"]) == [5, 11, 0]
+    toks, states, tails = _layer_by_layer_decode(
+        eng.model, eng.params, before,
+        eng._next_tok.reshape(eng.capacity, 1))
+    eng._decode_once()
+    after = jax.tree.map(np.asarray, eng._cache)
+    np.testing.assert_array_equal(after["pos"], before["pos"] + 1)
+    assert after["ssm"].dtype == np.float32
+    np.testing.assert_array_equal(after["ssm"], states)
+    np.testing.assert_array_equal(after["conv"], tails)
+    assert [r.tokens[-1] for r in reqs] == list(toks[:2])
+    for p, r in zip(prompts, reqs):
+        assert r.tokens == _sequential_reference(eng.model, eng.params, p, 2)
+
+
+@pytest.mark.parametrize("arch", [ARCH, "mamba2-1.3b-smoke"])
+def test_stats_say_the_decode_step_runs_in_place(arch):
     """stats() and status.perf carry the decode program's temporary bytes
-    and whether every cache leaf is aliased to its donated input, from
-    the background compile that start() schedules."""
-    eng = InferenceEngine(cfg, capacity=2, max_seq=MAX_SEQ,
+    and whether every cache leaf (KV rows or SSM state) is aliased to its
+    donated input, from the background compile that start() schedules."""
+    eng = InferenceEngine(resolve_arch(arch), capacity=2, max_seq=MAX_SEQ,
                           endpoint_id="ep-inplace")
     assert eng.stats()["decode_cache_aliased"] is None
     eng.start(None)
