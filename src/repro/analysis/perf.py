@@ -19,8 +19,10 @@ The machine model is the peak table in analysis/roofline.py, looked up
 by the ``device_kind`` of the device the job runs on; the payload names
 that kind. A kind with no published peaks (the CPU, for one) gets the
 FLOP and byte counts and ``"peaks": "no peaks"``, never another chip's
-ceiling. Disable with ``DLAAS_PERF=0`` (the payload then reports
-``{"state": "disabled"}``).
+ceiling. The counts are those of the program as compiled: every op's
+HBM bytes count, named scopes included, since no Pallas kernel runs
+under any of them. Disable with ``DLAAS_PERF=0`` (the payload then
+reports ``{"state": "disabled"}``).
 """
 from __future__ import annotations
 
@@ -29,9 +31,9 @@ import logging
 import os
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional
 
-from repro.analysis.roofline import KERNEL_SCOPES, analyze_hlo_text
+from repro.analysis.roofline import analyze_hlo_text
 
 log = logging.getLogger("repro.perf")
 
@@ -67,12 +69,10 @@ class JobPerf:
     """Roofline estimate of one job's hot program, computed once in the
     background and snapshotted into every status poll."""
 
-    def __init__(self, job_id: str, metrics=None, *, unit: str = "step",
-                 kernel_scopes: Tuple[str, ...] = KERNEL_SCOPES):
+    def __init__(self, job_id: str, metrics=None, *, unit: str = "step"):
         self.job_id = job_id
         self.metrics = metrics
         self.unit = unit
-        self.kernel_scopes = kernel_scopes
         self.state = "pending" if enabled() else "disabled"
         self.analysis: Optional[Dict] = None
         self.error: Optional[str] = None
@@ -110,8 +110,8 @@ class JobPerf:
                 with _lower_gate:
                     txt = lower_fn()
                 import jax
-                analysis = analyze_hlo_text(
-                    txt, self.kernel_scopes, jax.devices()[0].device_kind)
+                analysis = analyze_hlo_text(txt,
+                                            jax.devices()[0].device_kind)
                 with self._lock:
                     self.analysis = analysis
                     self.state = "ready"

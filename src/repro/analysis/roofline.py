@@ -18,23 +18,16 @@ keyed by the ``device_kind`` JAX reports; ICI is modelled at ~50 GB/s per
 link and DCN at 2.5 GB/s per chip for pod-crossing collectives
 (documented assumptions).
 
-Kernel-scope accounting: regions tagged with ``jax.named_scope`` that lower
-to single Pallas kernels on the TPU target (flash attention, SSD scan, PS
-aggregation, quantization) can be treated as fused: their internal ops
-contribute FLOPs but not HBM bytes (they live in VMEM on TPU); their
-boundary tensors are produced/consumed by untagged ops and therefore still
-counted exactly once. Pass ``kernel_scopes=(...)`` to enable — the delta
-between reference accounting and kernel accounting is the measured value of
-writing the Pallas kernels.
+Every op is counted as compiled: the program's ``jax.named_scope``
+regions are names, not kernels, so an op inside one moves its bytes
+like any other. (The model code's attention and SSD scan are jnp under
+the scopes ``flash_attention_ref`` and ``ssd_scan_ref``; no Pallas
+kernel runs in them.)
 """
 from __future__ import annotations
 
-import gzip
-import json
-import math
 import re
 from dataclasses import dataclass, field
-from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -44,7 +37,7 @@ import numpy as np
 PEAKS = {
     "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9},
 }
-# the chip the dry-run and the kernel autotuner model
+# the chip the kernel autotuner models
 TARGET_KIND = "TPU v5 lite"
 ICI_BW = 50e9                # bytes/s / link (we model 1 effective link)
 DCN_BW = 2.5e9               # bytes/s / chip for cross-pod traffic
@@ -386,8 +379,7 @@ def _collective_cost(comp: Computation, op: Op) -> Tuple[float, bool, str]:
 
 
 def comp_cost(comps: Dict[str, Computation], name: str,
-              in_fusion: bool, memo: Dict,
-              kernel_scopes: Tuple[str, ...] = ()) -> Cost:
+              in_fusion: bool, memo: Dict) -> Cost:
     key = (name, in_fusion)
     if key in memo:
         return memo[key]
@@ -397,60 +389,37 @@ def comp_cost(comps: Dict[str, Computation], name: str,
         memo[key] = c
         return c
 
-    def in_kernel(op: Op) -> bool:
-        return any(ks in op.line for ks in kernel_scopes)
-
     for op in comp.ops:
         oc = op.opcode
-        if kernel_scopes and in_kernel(op) and oc not in (
-                "while", "fusion", "call", "conditional"):
-            # fused on TPU: FLOPs count, HBM bytes don't
-            if oc == "dot":
-                c.flops += _dot_flops(comp, op)
-            continue
         if oc in ("parameter", "constant", "get-tuple-element", "tuple",
                   "bitcast", "iota", "after-all", "partition-id",
                   "replica-id"):
             continue
         if oc == "fusion":
             for sub in _called(op, "calls"):
-                c.add(comp_cost(comps, sub, True, memo, kernel_scopes))
+                c.add(comp_cost(comps, sub, True, memo))
             if not in_fusion:
-                io = _fusion_io_bytes(comps, comp, op)
-                if kernel_scopes:
-                    # XLA fuses across scope boundaries; attribute by the
-                    # tagged fraction of the fusion body's ops.
-                    subs = _called(op, "calls")
-                    body = comps.get(subs[0]) if subs else None
-                    if body is not None and body.ops:
-                        real = [b for b in body.ops
-                                if b.opcode != "parameter"]
-                        if real:
-                            tagged = sum(
-                                1 for b in real
-                                if any(ks in b.line for ks in kernel_scopes))
-                            io = io * (1.0 - tagged / len(real))
-                c.bytes += io
+                c.bytes += _fusion_io_bytes(comps, comp, op)
             continue
         if oc == "while":
             trip = _trip_count(op)
             for sub in _called(op, "body"):
-                c.add(comp_cost(comps, sub, in_fusion, memo, kernel_scopes), trip)
+                c.add(comp_cost(comps, sub, in_fusion, memo), trip)
             for sub in _called(op, "condition"):
-                c.add(comp_cost(comps, sub, in_fusion, memo, kernel_scopes), trip)
+                c.add(comp_cost(comps, sub, in_fusion, memo), trip)
             continue
         if oc == "conditional":
             subs = _called(op, "branch_computations") or \
                 (_called(op, "true_computation")
                  + _called(op, "false_computation"))
             if subs:
-                costs = [comp_cost(comps, s, in_fusion, memo, kernel_scopes) for s in subs]
+                costs = [comp_cost(comps, s, in_fusion, memo) for s in subs]
                 # one branch executes; take the max-flops branch
                 c.add(max(costs, key=lambda x: (x.flops, x.bytes)))
             continue
         if oc in ("call", "async-start", "custom-call"):
             for sub in _called(op, "to_apply") + _called(op, "calls"):
-                c.add(comp_cost(comps, sub, in_fusion, memo, kernel_scopes))
+                c.add(comp_cost(comps, sub, in_fusion, memo))
             if not in_fusion:
                 c.bytes += _operand_bytes(comp, comps, op) + op.out_bytes
             continue
@@ -497,13 +466,12 @@ def comp_cost(comps: Dict[str, Computation], name: str,
 # ---------------------------------------------------------------------------
 
 
-def analyze_hlo_text(txt: str, kernel_scopes: Tuple[str, ...] = (),
-                     device_kind: str = TARGET_KIND) -> Dict:
+def analyze_hlo_text(txt: str, device_kind: str = TARGET_KIND) -> Dict:
     """Per-device FLOPs, HBM and collective bytes of a compiled program,
     and the roofline times against ``device_kind``'s peaks. A kind with
     no entry in ``PEAKS`` gets the counts only (times are None)."""
     comps = parse_module(txt)
-    cost = comp_cost(comps, "__entry__", False, {}, kernel_scopes)
+    cost = comp_cost(comps, "__entry__", False, {})
     peaks = PEAKS.get(device_kind)
     return {
         "device_kind": device_kind,
@@ -518,138 +486,3 @@ def analyze_hlo_text(txt: str, kernel_scopes: Tuple[str, ...] = (),
                          if peaks else None),
     }
 
-
-def analyze_file(path: str, kernel_scopes: Tuple[str, ...] = ()) -> Dict:
-    p = Path(path)
-    txt = gzip.open(p, "rt").read() if p.suffix == ".gz" else p.read_text()
-    return analyze_hlo_text(txt, kernel_scopes)
-
-# scopes that lower to single Pallas kernels on the TPU target
-KERNEL_SCOPES = ("pallas_flash_attention", "pallas_ssd_scan",
-                 "pallas_ps_aggregate", "pallas_quantize")
-
-
-def model_flops(cfg, shape) -> float:
-    """Analytic 'useful' FLOPs per step (global, all chips).
-
-    train:   6·N_active·T + 12·L_attn·B·S²·H·hd·(causal 1/2)
-    prefill: 2·N_active·T +  4·L_attn·B·S²·H·hd·(1/2)
-    decode:  2·N_active·B +  4·L_attn·B·S_cache·H·hd
-    (SSM layers contribute their SSD term instead of S².)
-    """
-    n_act = cfg.n_active_params()
-    B, S = shape.global_batch, shape.seq_len
-    kind = shape.kind
-    # attention layer census
-    if cfg.family == "ssm":
-        l_attn = 0
-    elif cfg.family == "hybrid":
-        l_attn = cfg.n_layers // cfg.attn_period
-    elif cfg.family == "encdec":
-        l_attn = 3 * cfg.n_layers  # enc self + dec self + cross
-    else:
-        l_attn = cfg.n_layers
-    h_hd = (cfg.n_heads * cfg.hd) if cfg.n_heads else 0
-
-    def ssd_flops(tokens):
-        if cfg.ssm is None:
-            return 0.0
-        import repro.models.mamba as mam
-        d_in, nh, gn, _ = mam.mamba_dims(cfg)
-        q = cfg.ssm.chunk_size
-        n = cfg.ssm.d_state
-        p = cfg.ssm.head_dim
-        n_ssm = (cfg.n_layers if cfg.family == "ssm"
-                 else cfg.n_layers - cfg.n_layers // cfg.attn_period)
-        per_tok = 2 * q * gn + 2 * q * nh * p + 4 * nh * p * n
-        mult = {"train": 3.0, "prefill": 1.0, "decode": 1.0}[kind]
-        return mult * n_ssm * tokens * per_tok
-
-    if kind == "train":
-        t = B * S
-        if cfg.family == "encdec":
-            t = B * S  # enc half + dec half
-        return 6.0 * n_act * t + 12.0 * l_attn * B * S * S * h_hd * 0.5 \
-            + ssd_flops(t)
-    if kind == "prefill":
-        t = B * S
-        return 2.0 * n_act * t + 4.0 * l_attn * B * S * S * h_hd * 0.5 \
-            + ssd_flops(t)
-    # decode
-    return 2.0 * n_act * B + 4.0 * l_attn * B * S * h_hd + ssd_flops(B)
-
-
-def roofline_row(rec: Dict, hlo_analysis: Dict, cfg, shape,
-                 n_chips: int) -> Dict:
-    mf = model_flops(cfg, shape)
-    fpd = hlo_analysis["flops_per_device"]
-    terms = {
-        "compute_s": hlo_analysis["compute_s"],
-        "memory_s": hlo_analysis["memory_s"],
-        "collective_s": hlo_analysis["collective_s"],
-    }
-    dom = max(terms, key=terms.get)
-    bound_s = max(terms.values())
-    ideal_s = mf / n_chips / PEAKS[TARGET_KIND]["flops"]
-    return {
-        **{k: round(v, 6) for k, v in terms.items()},
-        "dominant": dom.replace("_s", ""),
-        "model_flops": mf,
-        "hlo_flops_per_device": fpd,
-        "useful_ratio": round(mf / n_chips / max(fpd, 1), 4),
-        "roofline_frac": round(ideal_s / max(bound_s, 1e-12), 4),
-        "ici_GB": round(hlo_analysis["ici_bytes_per_device"] / 1e9, 3),
-        "dcn_GB": round(hlo_analysis["dcn_bytes_per_device"] / 1e9, 3),
-        "hbm_GB": round(hlo_analysis["hbm_bytes_per_device"] / 1e9, 3),
-    }
-
-
-def breakdown(txt_or_path, kernel_scopes: Tuple[str, ...] = (),
-              top: int = 15) -> List[Dict]:
-    """Per-top-level-op cost attribution (×trip counts) — the 'profile'
-    used by the §Perf hypothesis loop."""
-    p = Path(str(txt_or_path))
-    if p.exists():
-        txt = gzip.open(p, "rt").read() if p.suffix == ".gz" \
-            else p.read_text()
-    else:
-        txt = str(txt_or_path)
-    comps = parse_module(txt)
-    entry = comps.get("__entry__")
-    rows = []
-    memo: Dict = {}
-    for op in entry.ops:
-        c = Cost()
-        oc = op.opcode
-        if oc in ("parameter", "constant", "get-tuple-element", "tuple",
-                  "bitcast", "iota"):
-            continue
-        if oc == "while":
-            trip = _trip_count(op)
-            for sub in _called(op, "body"):
-                c.add(comp_cost(comps, sub, False, memo, kernel_scopes),
-                      trip)
-        elif oc == "fusion":
-            for sub in _called(op, "calls"):
-                c.add(comp_cost(comps, sub, True, memo, kernel_scopes))
-            c.bytes += _fusion_io_bytes(comps, entry, op)
-        elif oc.replace("-start", "") in COLLECTIVES:
-            wire, crosses, kind = _collective_cost(entry, op)
-            c.coll[kind] = wire
-            c.ici_bytes, c.dcn_bytes = (0, wire) if crosses else (wire, 0)
-            c.bytes += _operand_bytes(entry, comps, op) + op.out_bytes
-        elif oc == "dot":
-            c.flops += _dot_flops(entry, op)
-            c.bytes += _operand_bytes(entry, comps, op) + op.out_bytes
-        else:
-            c.bytes += _operand_bytes(entry, comps, op) + op.out_bytes
-        m = re.search(r'op_name="([^"]+)"', op.line)
-        rows.append({
-            "op": op.name, "opcode": oc,
-            "where": (m.group(1)[-70:] if m else ""),
-            "flops": c.flops, "GB": round(c.bytes / 1e9, 2),
-            "ici_GB": round(c.ici_bytes / 1e9, 2),
-            "dcn_GB": round(c.dcn_bytes / 1e9, 2),
-        })
-    rows.sort(key=lambda r: -r["GB"])
-    return rows[:top]

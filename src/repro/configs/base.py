@@ -2,8 +2,8 @@
 
 Every assigned architecture is a frozen ``ArchConfig``; the four assigned
 input shapes are ``ShapeSpec``s. ``reduce_for_smoke`` derives a tiny
-same-family config for CPU smoke tests; the full configs are only ever
-lowered via the dry-run (ShapeDtypeStruct, no allocation).
+same-family config for CPU smoke tests; on the CPU the full configs are
+only lowered abstractly (ShapeDtypeStruct, no allocation).
 """
 from __future__ import annotations
 

@@ -1,9 +1,9 @@
 """Step builders: train / prefill / decode with full sharding annotations.
 
-These are the functions the dry-run lowers and the trainer executes. The
-train step here is the pjit-native path (grad psum over the batch axes is
-inserted by SPMD; optimizer state shards per opt_state_specs — the paper's
-PS partition scheme as a resident layout). The explicit parameter-server
+These are the functions the trainer executes (and the multi-device tests
+lower on a host mesh). The train step here is the pjit-native path (grad
+psum over the batch axes is inserted by SPMD; optimizer state shards per
+opt_state_specs — the paper's PS partition scheme as a resident layout). The explicit parameter-server
 push/pull solvers (paper-faithful modes) live in core/solvers.py and wrap
 the same loss function.
 """
@@ -123,7 +123,7 @@ def build_decode_step(model: Model):
 
 
 # ---------------------------------------------------------------------------
-# Jit wrappers with shardings (used by dryrun + trainer)
+# Jit wrappers with shardings (used by the trainer)
 # ---------------------------------------------------------------------------
 
 

@@ -1,6 +1,6 @@
 """Mesh construction. Functions only — importing this module never touches
-jax device state (required so smoke tests see 1 device while the dry-run
-forces 512 host devices)."""
+jax device state (so smoke tests see the real single device while
+multi-device tests build their meshes in subprocesses)."""
 from __future__ import annotations
 
 import jax
@@ -9,13 +9,6 @@ import jax
 def _mk(shape, axes):
     return jax.make_mesh(
         shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """Assignment-fixed production mesh: 16x16 per pod, 2 pods multi-pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
 
 
 def make_mesh(data: int, model: int, pod: int = 1):

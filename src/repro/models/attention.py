@@ -1,8 +1,8 @@
 """Attention: chunked flash reference (jnp, O(S) memory), decode attention.
 
-The Pallas TPU kernel (kernels/flash_attention.py) implements the same
-online-softmax tiling; on CPU (dry-run, smoke) the chunked jnp path below is
-lowered instead.
+The models run the chunked jnp path below on every backend (XLA compiles
+it for the TPU too). The Pallas kernel in kernels/flash_attention.py tiles
+the same online softmax, but no model calls it.
 
 Layouts (see DESIGN.md §5):
   * train/prefill: q/k/v all carry the full head count (GQA kv heads are
@@ -38,7 +38,7 @@ def flash_attention_ref(q, k, v, *, causal: bool,
     attention with q_offset, query position i attends to kv positions
     <= i + q_offset.
     """
-    with jax.named_scope("pallas_flash_attention"):
+    with jax.named_scope("flash_attention_ref"):
         sq, sk = q.shape[1], k.shape[1]
         q_chunk = min(q_chunk, sq)
         k_chunk = min(k_chunk, sk)
@@ -180,7 +180,7 @@ def sp_flash_attention(q, k, v, dist, *, causal: bool,
         kf = repeat_kv(kf, n_heads)
         vf = repeat_kv(vf, n_heads)
         off = jax.lax.axis_index("model") * ql.shape[1]
-        with jax.named_scope("pallas_flash_attention"):
+        with jax.named_scope("flash_attention_ref"):
             return _flash_vjp(ql, kf, vf, off.astype(jnp.int32), causal,
                               min(q_chunk, ql.shape[1]),
                               min(k_chunk, kf.shape[1]), kf.shape[1])
@@ -258,8 +258,8 @@ def _flash_vjp_fwd(q, k, v, q_offset, causal, q_chunk, k_chunk, sk_valid):
 def _flash_vjp_bwd(causal, q_chunk, k_chunk, sk_valid, res, do):
     """Flash backward: O(S) memory — per (q-block, kv-block) tile the P
     matrix is recomputed from (q, k, lse); only dq/dk/dv accumulate.
-    Runs inside the pallas scope: on TPU this is the bwd Pallas kernel."""
-    with jax.named_scope("pallas_flash_attention"):
+    Plain jnp, compiled by XLA like the forward."""
+    with jax.named_scope("flash_attention_ref"):
         q, k, v, out, lse, q_offset = res
         B, Sq, H, hd = q.shape
         _, Sk, _, _ = k.shape

@@ -41,7 +41,7 @@ def is_pdef(x) -> bool:
 
 
 def abstract_params(defs, dtype) -> dict:
-    """ShapeDtypeStruct tree for dry-run lowering (no allocation)."""
+    """ShapeDtypeStruct tree for abstract lowering (no allocation)."""
     return jax.tree.map(
         lambda d: jax.ShapeDtypeStruct(d.shape, jnp.dtype(dtype)),
         defs, is_leaf=is_pdef)

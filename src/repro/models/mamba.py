@@ -2,9 +2,9 @@
 
 Chunked-scan formulation: within a chunk the recurrence is computed as a
 masked quadratic "attention" term (MXU-friendly), between chunks a small
-state (B, H, P, N) is carried by a scan. The Pallas kernel
-(kernels/ssd_scan.py) tiles the same computation; this module is the pure
-jnp path used on CPU and as the kernel oracle.
+state (B, H, P, N) is carried by a scan. This pure jnp path is what the
+models run on every backend, and the oracle of the Pallas kernel in
+kernels/ssd_scan.py, which tiles the same computation but no model calls.
 
 Sharding: heads (d_inner) over "model" (TP); B/C (n_groups=1) replicated;
 the inter-chunk state is tiny. Decode carries (ssm_state, conv_tail).
@@ -73,7 +73,7 @@ def ssd_scan_ref(x, dt, a_log, b, c, chunk: int):
     x (B,S,H,P); dt (B,S,H) (post-softplus); a_log (H,) (A = -exp(a_log));
     b/c (B,S,G,N). Returns y (B,S,H,P) and final state (B,H,P,N).
     """
-    with jax.named_scope("pallas_ssd_scan"):
+    with jax.named_scope("ssd_scan_ref"):
         seq = x.shape[1]
         chunk = min(chunk, seq)
         pad = (-seq) % chunk

@@ -3,8 +3,10 @@
 ``Model`` dispatches to the family implementation and owns:
   * abstract/init parameter trees + their shardings,
   * ``loss`` / ``prefill`` / ``decode`` pure functions,
-  * ``input_specs`` / ``cache_specs`` — ShapeDtypeStruct stand-ins for the
-    dry-run (weak-type-correct, shardable, no allocation),
+  * ``input_specs`` / ``cache_specs`` — ShapeDtypeStruct stand-ins for
+    lowering (weak-type-correct, shardable, no allocation),
+  * ``cache_axes`` — each cache leaf's slot and sequence axes, declared
+    with its shape in ``_cache_layout``,
   * matching ``input_shardings`` / ``cache_shardings``.
 """
 from __future__ import annotations
@@ -132,52 +134,57 @@ class Model:
                 out[k] = P(bt, None)
         return out
 
-    # ---- cache specs ------------------------------------------------------
-    def cache_specs(self, B: int, S: int) -> Dict[str, Any]:
+    # ---- cache layout -----------------------------------------------------
+    def _cache_layout(self, B: int, S: int) -> Dict[str, Tuple[Any, Any, Any]]:
+        """The one declaration of each family's cache: leaf ->
+        (ShapeDtypeStruct, slot axis, sequence axis or None). The slot
+        axis holds one request's (batch row's) state; the sequence axis
+        one row per position. ``pos`` is a shared scalar with neither."""
         c = self.cfg
         from repro.models.transformer import _cache_dtype
-        bf16 = _cache_dtype(c)
-        f32 = jnp.float32
-        pos = jax.ShapeDtypeStruct((), jnp.int32)
-        if c.family == "encdec":
-            L = c.n_layers
-            kv = (L, B, S, c.n_kv_heads, c.hd)
-            return {"k": jax.ShapeDtypeStruct(kv, bf16),
-                    "v": jax.ShapeDtypeStruct(kv, bf16),
-                    "cross_k": jax.ShapeDtypeStruct(kv, bf16),
-                    "cross_v": jax.ShapeDtypeStruct(kv, bf16),
-                    "pos": pos}
-        if c.family == "ssm":
-            L = c.n_layers
+        sds = jax.ShapeDtypeStruct
+
+        def kv(lead, tail):
+            # one row per position (the KV heads side by side for the
+            # decoders: attention reads the rows as stored)
+            n = len(lead)
+            return (sds(lead + (B, S) + tail, _cache_dtype(c)), n, n + 1)
+
+        def state(lead):
+            # each Mamba layer's SSM state and causal-conv tail
             d_in, nheads, gn, k = mam.mamba_dims(c)
+            n = len(lead)
             return {
-                "ssm": jax.ShapeDtypeStruct(
-                    (L, B, nheads, c.ssm.head_dim, c.ssm.d_state), f32),
-                "conv": jax.ShapeDtypeStruct(
-                    (L, B, k - 1, d_in + 2 * gn), jnp.dtype(c.dtype)),
-                "pos": pos}
-        # decoder KV caches: one row per position, the KV heads side by
-        # side (attention reads the rows as stored; models/attention.py)
-        rows = (B, S, c.n_kv_heads * c.hd)
+                "ssm": (sds(lead + (B, nheads, c.ssm.head_dim,
+                                    c.ssm.d_state), jnp.float32), n, None),
+                "conv": (sds(lead + (B, k - 1, d_in + 2 * gn),
+                             jnp.dtype(c.dtype)), n, None)}
+
+        pos = {"pos": (sds((), jnp.int32), None, None)}
+        L = c.n_layers
+        if c.family == "encdec":
+            heads = kv((L,), (c.n_kv_heads, c.hd))
+            return {"k": heads, "v": heads, "cross_k": heads,
+                    "cross_v": heads, **pos}
+        if c.family == "ssm":
+            return {**state((L,)), **pos}
+        rows = (c.n_kv_heads * c.hd,)
         if c.family == "hybrid":
             per = c.attn_period
-            np_ = c.n_layers // per
-            d_in, nheads, gn, k = mam.mamba_dims(c)
-            kv = (np_,) + rows
-            return {
-                "k": jax.ShapeDtypeStruct(kv, bf16),
-                "v": jax.ShapeDtypeStruct(kv, bf16),
-                "ssm": jax.ShapeDtypeStruct(
-                    (np_, per - 1, B, nheads, c.ssm.head_dim, c.ssm.d_state),
-                    f32),
-                "conv": jax.ShapeDtypeStruct(
-                    (np_, per - 1, B, k - 1, d_in + 2 * gn),
-                    jnp.dtype(c.dtype)),
-                "pos": pos}
-        kv = (c.n_layers,) + rows
-        return {"k": jax.ShapeDtypeStruct(kv, bf16),
-                "v": jax.ShapeDtypeStruct(kv, bf16),
-                "pos": pos}
+            np_ = L // per
+            return {"k": kv((np_,), rows), "v": kv((np_,), rows),
+                    **state((np_, per - 1)), **pos}
+        return {"k": kv((L,), rows), "v": kv((L,), rows), **pos}
+
+    def cache_specs(self, B: int, S: int) -> Dict[str, Any]:
+        """ShapeDtypeStructs of a B-row cache of S positions."""
+        return {k: leaf[0] for k, leaf in self._cache_layout(B, S).items()}
+
+    def cache_axes(self) -> Dict[str, Tuple[Optional[int], Optional[int]]]:
+        """Each cache leaf's (slot axis, sequence axis or None): where one
+        request's cache sits in a batched one, and which axis grows
+        with the sequence."""
+        return {k: leaf[1:] for k, leaf in self._cache_layout(1, 8).items()}
 
     def cache_sharding_specs(self, B: int) -> Dict[str, Any]:
         """Cache PartitionSpecs. Batch over data axes when divisible, else
@@ -194,19 +201,15 @@ class Model:
             if nheads % d.model_size == 0 and d.tp_axis:
                 heads_ax = "model"
         out = {}
-        for key, spec in self.cache_specs(B, 8).items():
-            if key == "pos":
-                out[key] = P()
-            elif key in ("k", "v", "cross_k", "cross_v"):
-                # (L, B, S, KV*hd), or (L, B, S, KV, hd) for encdec
-                out[key] = P(None, bt, seq_ax,
-                             *(None,) * (spec.ndim - 3))
-            elif key == "ssm":
-                lead = (None,) * (spec.ndim - 4)
-                out[key] = P(*lead, bt, heads_ax, None, None)
-            elif key == "conv":
-                lead = (None,) * (spec.ndim - 3)
-                out[key] = P(*lead, bt, None, None)
+        for key, (spec, slot, seq) in self._cache_layout(B, 8).items():
+            dims = [None] * spec.ndim
+            if slot is not None:
+                dims[slot] = bt
+            if seq is not None:
+                dims[seq] = seq_ax
+            if key == "ssm":                   # (..., B, heads, P, N)
+                dims[slot + 1] = heads_ax
+            out[key] = P(*dims)
         return out
 
     def cache_shardings(self, B: int):

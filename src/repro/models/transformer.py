@@ -1,7 +1,7 @@
 """Decoder-only LM (dense / MoE / VLM) and the hybrid (Jamba, Granite 4.0-H).
 
 All models are functional: ``*_param_defs`` build ParamDef trees (abstract,
-for dry-run + sharding), ``lm_loss`` / ``lm_prefill`` / ``lm_decode`` are
+for lowering + sharding), ``lm_loss`` / ``lm_prefill`` / ``lm_decode`` are
 pure functions. Layers are scanned (stacked leading dim) so HLO size is
 independent of depth; the hybrid scans over periods of ``attn_period``
 layers (1 attention at ``attn_index`` + N-1 mamba), each layer followed by

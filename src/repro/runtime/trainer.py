@@ -107,7 +107,7 @@ class Trainer:
         if self.cfg.frontend != "none" or self.cfg.family == "encdec":
             raise NotImplementedError(
                 "Trainer synthesizes token batches; stub-frontend archs "
-                "train via the dry-run path")
+                "are not trainable here")
         return b
 
     # ---- loop -----------------------------------------------------------------

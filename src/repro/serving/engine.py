@@ -195,6 +195,8 @@ class InferenceEngine:
         self._shed_limit: Optional[int] = None
         self._pending_slots = 0
         self.params = None
+        # (slot axis, sequence axis or None) of each model cache leaf
+        self._layout = self.model.cache_axes()
         self._axes = self._cache_axes()
         self._flat_io = None                # (ravel, unravel, size)
         # accounting (guarded by _lock; mirrored into MetricsService).
@@ -454,21 +456,11 @@ class InferenceEngine:
 
     # ---- internals --------------------------------------------------------
     def _cache_axes(self) -> Dict[str, int]:
-        """Slot (batch) axis per cache leaf, where a prefilled request's
-        cache is spliced in. Derived from the family cache layouts in
-        models/model.py:cache_specs."""
-        axes = {}
-        for k, v in self.model.cache_specs(1, 8).items():
-            if k == "pos":
-                axes[k] = 0
-            elif k in ("k", "v", "cross_k", "cross_v"):
-                axes[k] = 1
-            elif k == "ssm":
-                axes[k] = 1 if v.ndim == 5 else 2      # hybrid: (np,per-1,B,…)
-            elif k == "conv":
-                axes[k] = 1 if v.ndim == 4 else 2
-            else:
-                raise ValueError(f"unknown cache leaf {k!r}")
+        """Slot axis per cache leaf, where a prefilled request's cache is
+        spliced in, as the model declares it (``Model.cache_axes``);
+        ``pos`` is the engine's own per-slot position vector."""
+        axes = {k: slot for k, (slot, _) in self._layout.items()}
+        axes["pos"] = 0
         return axes
 
     def _empty_cache(self):
@@ -480,21 +472,23 @@ class InferenceEngine:
         max_seq) into slot ``slot`` of the batched cache."""
         out = {}
         for k, v in cache.items():
-            if k == "pos":
-                out[k] = v.at[slot].set(one["pos"].astype(v.dtype))
+            if self._layout[k][0] is None:
+                # a leaf the model shares across rows (the write
+                # position); the engine keeps one per slot
+                out[k] = v.at[slot].set(one[k].astype(v.dtype))
             else:
                 out[k] = jax.lax.dynamic_update_slice_in_dim(
                     v, one[k].astype(v.dtype), slot, axis=self._axes[k])
         return out
 
     def _pad_prefill(self, cache):
-        """Pad a prefill cache's sequence dim out to max_seq (k/v caches
-        only; ssm/conv state has no sequence dim)."""
+        """Pad a prefill cache's sequence axes out to max_seq (leaves
+        without one, such as SSM state, are whole already)."""
         out = dict(cache)
-        for k in ("k", "v"):
-            if k in out:
+        for k, (_, seq) in self._layout.items():
+            if seq is not None:
                 pads = [(0, 0)] * out[k].ndim
-                pads[2] = (0, self.max_seq - out[k].shape[2])
+                pads[seq] = (0, self.max_seq - out[k].shape[seq])
                 out[k] = jnp.pad(out[k], pads)
         return out
 
@@ -540,9 +534,9 @@ class InferenceEngine:
                 now = time.time()
             for i, req in enumerate(batch):
                 slot = free[i]
-                one = {k: jax.lax.slice_in_dim(v, i, i + 1,
-                                               axis=self._axes[k])
-                       for k, v in c1.items() if k != "pos"}
+                one = {k: jax.lax.slice_in_dim(c1[k], i, i + 1, axis=ax)
+                       for k, (ax, _) in self._layout.items()
+                       if ax is not None}
                 # prefill emits one shared scalar pos; the slot cache
                 # tracks a per-slot position instead
                 one["pos"] = jnp.asarray(req.prompt.size, jnp.int32)

@@ -18,8 +18,8 @@ os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                                    "dlaas-test-jax-cache"))
 
 # NOTE: no XLA_FLAGS here — smoke tests and benches must see the real
-# single device; only launch/dryrun.py forces 512 host devices, and
-# multi-device tests spawn subprocesses (tests/util_subproc.py).
+# single device; multi-device tests force host devices in subprocesses
+# of their own (tests/util_subproc.py).
 
 
 @pytest.fixture(autouse=True)

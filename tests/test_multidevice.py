@@ -2,8 +2,8 @@
 device count at first import, so these cannot run in the pytest process).
 
 Covers: sharded-vs-local MoE equivalence, mesh solver collective patterns
-(the paper's O(L) vs O(L^2) bytes), elastic trainer resharding, and a
-miniature dry-run (lower+compile with shardings on a 4x2 mesh)."""
+(the paper's O(L) vs O(L^2) bytes), elastic trainer resharding, and
+lower+compile with shardings on a 4x2 mesh for every step kind."""
 import pytest
 
 from util_subproc import run_with_devices
@@ -125,7 +125,7 @@ print("OK")
 
 def test_tiny_dryrun_all_step_kinds():
     """lower+compile with shardings for train/prefill/decode on a 4x2
-    mesh — the in-repo miniature of the 512-device production dry-run."""
+    mesh, across the families (and so every cache layout's shardings)."""
     out = run_with_devices("""
 import jax
 from repro.configs.base import ShapeSpec, reduce_for_smoke

@@ -7,10 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.analysis.roofline import (Op, _group_info, analyze_hlo_text,
-                                     model_flops, parse_module)
-from repro.configs.base import SHAPES_BY_NAME
-from repro.configs.registry import get_arch
+from repro.analysis.perf import JobPerf
+from repro.analysis.roofline import Op, _group_info, analyze_hlo_text
 
 
 def _hlo_of(fn, *args):
@@ -73,17 +71,21 @@ def test_roofline_times_use_the_named_device_peaks():
     assert other["compute_s"] is None and other["memory_s"] is None
 
 
-def test_job_perf_names_the_device_kind_it_ran_on():
-    from repro.analysis.perf import JobPerf
-    a = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-    txt = _hlo_of(lambda x: x @ x, a)
+def _job_perf_of(txt):
+    """A JobPerf fed ``txt``, once its background analysis has ended."""
     perf = JobPerf("job-perf-test")
     perf.start_async(lambda: txt)
     for _ in range(600):
         if perf.snapshot()["state"] != "running":
             break
         time.sleep(0.05)
-    snap = perf.snapshot(measured_per_s=10.0)
+    return perf
+
+
+def test_job_perf_names_the_device_kind_it_ran_on():
+    a = jax.ShapeDtypeStruct((64, 64), jnp.float32)
+    txt = _hlo_of(lambda x: x @ x, a)
+    snap = _job_perf_of(txt).snapshot(measured_per_s=10.0)
     assert snap["state"] == "ready", snap
     assert snap["device_kind"] == jax.devices()[0].device_kind
     assert snap["peaks"] == "no peaks"          # the CPU is not in PEAKS
@@ -115,32 +117,35 @@ def test_memory_bytes_reasonable_for_elementwise():
     assert 4e6 <= got <= 2e7, got
 
 
-def test_model_flops_formulas():
-    cfg = get_arch("stablelm-1.6b")
-    tr = model_flops(cfg, SHAPES_BY_NAME["train_4k"])
-    # 6ND dominates: 6 * 1.64e9 * 1.05e6 ~ 1.03e16
-    assert 0.9e16 < tr < 1.4e16
-    pf = model_flops(cfg, SHAPES_BY_NAME["prefill_32k"])
-    dc = model_flops(cfg, SHAPES_BY_NAME["decode_32k"])
-    assert dc < pf < tr
-    moe = get_arch("kimi-k2-1t-a32b")
-    # active params ~32B -> train flops ~ 6*32e9*1.05e6 ~ 2e17
-    assert 1e17 < model_flops(moe, SHAPES_BY_NAME["train_4k"]) < 6e17
+def _flash_program():
+    from repro.models.attention import flash_attention_ref
+    qkv = jax.ShapeDtypeStruct((1, 64, 2, 16), jnp.float32)
+    return _hlo_of(lambda q, k, v: flash_attention_ref(
+        q, k, v, causal=True, q_chunk=32, k_chunk=32), qkv, qkv, qkv)
 
 
-def test_kernel_scope_accounting_reduces_bytes():
-    a = jax.ShapeDtypeStruct((4, 256, 64), jnp.float32)
+def _ssd_program():
+    from repro.models.mamba import ssd_scan_ref
+    f32 = jnp.float32
+    x = jax.ShapeDtypeStruct((1, 32, 2, 8), f32)
+    dt = jax.ShapeDtypeStruct((1, 32, 2), f32)
+    a_log = jax.ShapeDtypeStruct((2,), f32)
+    bc = jax.ShapeDtypeStruct((1, 32, 1, 4), f32)
+    return _hlo_of(lambda *args: ssd_scan_ref(*args, chunk=16),
+                   x, dt, a_log, bc, bc)
 
-    def fn(q):
-        from repro.kernels.flash_attention import flash_attention_fwd
-        with jax.named_scope("pallas_flash_attention"):
-            # emulate scope-internal traffic with plain ops
-            s = jnp.einsum("bqd,bkd->bqk", q, q)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum("bqk,bkd->bqd", p, q)
-    txt = _hlo_of(fn, a)
-    full = analyze_hlo_text(txt)["hbm_bytes_per_device"]
-    fused = analyze_hlo_text(
-        txt, kernel_scopes=("pallas_flash_attention",)
-    )["hbm_bytes_per_device"]
-    assert fused < full
+
+@pytest.mark.parametrize("scope,program", [
+    ("flash_attention_ref", _flash_program),
+    ("ssd_scan_ref", _ssd_program),
+])
+def test_scoped_jnp_code_is_counted(scope, program):
+    """Named scopes are names, not kernels: status.perf counts the bytes
+    of every op in them, as the plain analysis of the program does."""
+    txt = program()
+    assert f"/{scope}/" in txt              # the ops carry the scope name
+    plain = analyze_hlo_text(txt)["hbm_bytes_per_device"]
+    perf = _job_perf_of(txt)
+    assert perf.state == "ready", perf.snapshot()
+    assert plain > 0
+    assert perf.analysis["hbm_bytes_per_device"] == plain

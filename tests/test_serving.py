@@ -101,6 +101,44 @@ def test_midflight_join_token_identical(arch):
         assert r.tokens == ref, (r.tokens, ref)
 
 
+@pytest.mark.parametrize("arch", [
+    ARCH, "kimi-k2-1t-a32b-smoke", "mamba2-1.3b-smoke",
+    "granite-4.0-h-small-smoke"])
+def test_cache_axes_match_prefill(arch):
+    """The cache a batch-2 prefill of length-7 prompts returns is the
+    one cache_specs(2, 7) names, leaf for leaf and shape for shape; each
+    leaf's declared slot axis holds the 2 rows and its declared sequence
+    axis the 7 positions: the axes the engine splices and pads along.
+    Row i along the slot axis is prompt i's own batch-1 prefill cache."""
+    cfg = resolve_arch(arch)
+    model = InferenceEngine(cfg, capacity=2, max_seq=MAX_SEQ).model
+    params = model.init(jax.random.PRNGKey(0))
+    prefill = jax.jit(model.prefill)
+    toks = jnp.asarray(np.random.RandomState(3).randint(
+        0, cfg.vocab_size, size=(2, 7)), jnp.int32)
+    _, cache = prefill(params, {"tokens": toks})
+    specs, axes = model.cache_specs(2, 7), model.cache_axes()
+    assert set(cache) == set(specs) == set(axes)
+    for k, v in cache.items():
+        assert v.shape == specs[k].shape, (k, v.shape, specs[k].shape)
+        slot, seq = axes[k]
+        if slot is not None:
+            assert v.shape[slot] == 2, (k, v.shape, slot)
+        if seq is not None:
+            assert v.shape[seq] == 7, (k, v.shape, seq)
+    for i in range(2):
+        _, own = prefill(params, {"tokens": toks[i:i + 1]})
+        for k, (slot, _) in axes.items():
+            if slot is not None:
+                row = jax.lax.slice_in_dim(cache[k], i, i + 1, axis=slot)
+                np.testing.assert_array_equal(
+                    np.asarray(row), np.asarray(own[k]),
+                    err_msg=f"{arch} {k} row {i}")
+    # the engine keeps the model's slot axes, and one position per slot
+    assert InferenceEngine(resolve_arch(arch), capacity=2)._axes == {
+        **{k: slot for k, (slot, _) in axes.items()}, "pos": 0}
+
+
 def test_decode_step_writes_only_each_slots_new_row(cfg):
     """One decode step over slots at different depths changes, in every
     layer, exactly row pos[b] of slot b's K and V, live or free, and bit
